@@ -39,8 +39,10 @@ from .reference import (apply_shift, crc32c_slice8_tables, crc32c_table,
 
 Array = jax.Array
 
-_SLICE8 = jnp.asarray(crc32c_slice8_tables())  # (8, 256) uint32
-_T0 = jnp.asarray(crc32c_table())              # (256,) uint32
+# host tables; they become device constants inside the traced programs,
+# so importing this module creates no backend
+_SLICE8 = crc32c_slice8_tables()  # (8, 256) uint32
+_T0 = crc32c_table()              # (256,) uint32
 
 
 def _apply_bitmatrix32(cols: np.ndarray, x: Array) -> Array:
@@ -56,45 +58,67 @@ def _apply_bitmatrix32(cols: np.ndarray, x: Array) -> Array:
 
 
 def _crc32c_linear(blocks: Array) -> Array:
-    """Zero-init CRC register over each row of (B, L) uint8, L % 8 == 0."""
-    B, L = blocks.shape
-    n = L // 8
-    chunks = blocks.reshape(B, n, 8).astype(jnp.int32)
+    """Zero-init CRC register over each row of (..., L) uint8, L % 8 == 0.
+
+    Layout is sized for the TPU tiling: the chunk axis (long) stays
+    minor through the widening and the gathers — byte planes
+    (8, ..., n), not (..., n, 8) int32, whose minor dim of 8 padded
+    every tile 16x (3 GiB of scratch for 5.5 MiB of rows at 512 KiB
+    shards)."""
+    lead, n = blocks.shape[:-1], blocks.shape[-1] // 8
+    planes = jnp.moveaxis(blocks.reshape(lead + (n, 8)), -1, 0)
     # chunk CRC: XOR_i T[7-i][byte_i]  (slicing-by-8, zero-init)
-    c = jnp.zeros((B, n), dtype=jnp.uint32)
+    c = jnp.zeros(lead + (n,), dtype=jnp.uint32)
     for i in range(8):
-        c = c ^ jnp.take(_SLICE8[7 - i], chunks[:, :, i], axis=0)
+        c = c ^ jnp.take(jnp.asarray(_SLICE8[7 - i]),
+                         planes[i].astype(jnp.int32), axis=0)
     # log-depth combine; pad FRONT with zero chunks (zero-init register
     # stays 0 through a zero prefix, so the result is unchanged)
     span = 8
-    while c.shape[1] > 1:
-        m = c.shape[1]
+    while c.shape[-1] > 1:
+        m = c.shape[-1]
         if m % 2:
             c = jnp.concatenate(
-                [jnp.zeros((B, 1), dtype=jnp.uint32), c], axis=1)
+                [jnp.zeros(lead + (1,), dtype=jnp.uint32), c], axis=-1)
             m += 1
-        left, right = c[:, 0::2], c[:, 1::2]
+        pairs = c.reshape(lead + (m // 2, 2))
         cols = matrix_cols_u32(shift_matrix(span))
-        c = _apply_bitmatrix32(cols, left) ^ right
+        c = _apply_bitmatrix32(cols, pairs[..., 0]) ^ pairs[..., 1]
         span *= 2
-    return c[:, 0]
+    return c[..., 0]
 
 
 def _crc32c_zero_seed(blocks: Array) -> Array:
-    """Zero-seed CRC register over each row of (B, L) uint8, any L:
+    """Zero-seed CRC register over each row of (..., R, L) uint8, any L:
     parallel slicing + log-depth combine for the 8-aligned head, <=7
-    unrolled byte steps for the tail."""
-    block_len = blocks.shape[1]
+    unrolled byte steps for the tail.
+
+    Three things keep the TPU program small and its compile to seconds
+    at 512 KiB rows, whatever the caller's row count (sized by
+    compiling for a described v5e, tests/test_tpu_compile.py):
+    the rows are materialised first (fused into the gathers, the
+    recovery decode took the planes' layout and 1 GiB of scratch per
+    4 MiB object); leading dims are kept, never merged into R (the
+    relayout of (16, 11, L) to (176, L) alone compiled for 55 s); and R
+    is zero-padded to a multiple of 8, 16 at least (11 rows compiled
+    for 62 s, 8 for 9 s, 16 for 3 s)."""
+    n_rows, block_len = blocks.shape[-2:]
+    row_pad = max(16, n_rows + (-n_rows % 8)) - n_rows
+    if row_pad:
+        blocks = jnp.pad(blocks, [(0, 0)] * (blocks.ndim - 2)
+                         + [(0, row_pad), (0, 0)])
+    blocks = jax.lax.optimization_barrier(blocks)
     main = (block_len // 8) * 8
     if main:
-        reg = _crc32c_linear(blocks[:, :main])
+        reg = _crc32c_linear(blocks[..., :main])
     else:
-        reg = jnp.zeros((blocks.shape[0],), dtype=jnp.uint32)
+        reg = jnp.zeros(blocks.shape[:-1], dtype=jnp.uint32)
+    t0 = jnp.asarray(_T0)
     for t in range(main, block_len):
-        byte = blocks[:, t].astype(jnp.uint32)
+        byte = blocks[..., t].astype(jnp.uint32)
         reg = (reg >> np.uint32(8)) ^ jnp.take(
-            _T0, ((reg ^ byte) & np.uint32(0xFF)).astype(jnp.int32))
-    return reg
+            t0, ((reg ^ byte) & np.uint32(0xFF)).astype(jnp.int32))
+    return reg[..., :n_rows]
 
 
 @functools.lru_cache(maxsize=64)
@@ -103,8 +127,8 @@ def _crc32c_jit(block_len: int, init: int, xorout: int):
     const = apply_shift(init, block_len) ^ xorout if block_len else init ^ xorout
 
     def fn(blocks: Array) -> Array:
-        if blocks.dtype != jnp.uint8 or blocks.ndim != 2:
-            raise ValueError(f"blocks must be (B, {block_len}) uint8")
+        if blocks.dtype != jnp.uint8 or blocks.ndim < 2:
+            raise ValueError(f"blocks must be (..., B, {block_len}) uint8")
         return _crc32c_zero_seed(blocks) ^ np.uint32(const)
 
     return jax.jit(fn)
@@ -112,11 +136,12 @@ def _crc32c_jit(block_len: int, init: int, xorout: int):
 
 def crc32c_blocks(blocks, init: int = 0xFFFFFFFF,
                   xorout: int = 0xFFFFFFFF) -> Array:
-    """CRC-32C of each row of (B, L) uint8. Defaults = standard CRC-32C;
-    use init=seed, xorout=0 for the reference's raw ceph_crc32c(seed, ·)
-    convention (what BlueStore/HashInfo store, seed -1)."""
+    """CRC-32C of each row of (..., B, L) uint8, one value per row with
+    the leading dims kept. Defaults = standard CRC-32C; use init=seed,
+    xorout=0 for the reference's raw ceph_crc32c(seed, ·) convention
+    (what BlueStore/HashInfo store, seed -1)."""
     blocks = jnp.asarray(blocks, dtype=jnp.uint8)
-    return _crc32c_jit(int(blocks.shape[1]), init & 0xFFFFFFFF,
+    return _crc32c_jit(int(blocks.shape[-1]), init & 0xFFFFFFFF,
                        xorout & 0xFFFFFFFF)(blocks)
 
 

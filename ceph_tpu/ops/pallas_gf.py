@@ -1,13 +1,9 @@
 """Pallas VPU kernel for batched GF(2^8) matrix-apply (encode/decode).
 
-STATUS (r5): EXPERIMENT, not the production path. On-chip slope
-measurement (r4 BENCH_mid.json) put this kernel at 11.2 GB/s encode vs
-85.0 GB/s for the plain-XLA `mxu` bit-plane lowering — XLA's own MXU
-tiling beats this hand VPU schedule 8x. Kept oracle-pinned and
+STATUS: EXPERIMENT, not the production path. Kept oracle-pinned and
 selectable (`impl=pallas`) as the repo's worked example of a Pallas
-kernel and as a baseline for any future hand-kernel attempt; excluded
-from the default bench impl set (docs/BENCH_METHODOLOGY.md "Kernel
-findings").
+kernel; it has no measurement on the current chip, and ROADMAP C2
+decides whether it stays.
 
 The hand-scheduled replacement for the reference's CPU hot loop
 (ref: gf-complete gf_w8_split_4_8 SIMD region multiply called from
@@ -39,9 +35,10 @@ kernel emits exactly nnz(matrix bits) AND+XOR pairs plus 8 mask
 computations per shard.
 
 Grid: (batch, slab-tile). Bit-exact vs the numpy oracle
-(tests/test_rs_kernels.py) and vs the jnp `bitlinear`/`mxu` lowerings;
-on non-TPU backends the kernel runs in interpret mode so the whole
-suite stays hermetic on CPU.
+(tests/test_rs_kernels.py) and vs the jnp `bitlinear`/`mxu` lowerings.
+The kernel is compiled for the TPU unless the caller asks for the
+interpreter by name (`interpret=True`, which only tests do): a served
+path that interpreted silently would hide the device.
 """
 
 from __future__ import annotations
@@ -116,7 +113,8 @@ def _build(matrix_bytes: bytes, m: int, k: int, n_slabs: int,
 
 
 def apply_matrix_pallas(matrix: np.ndarray, data: Array,
-                        sublanes: int | None = None) -> Array:
+                        sublanes: int | None = None,
+                        interpret: bool = False) -> Array:
     """out = matrix (GF) @ data along the shard axis; matrix static.
 
     data: (B, k, L) uint8, L % 4 == 0 (CHUNK_ALIGNMENT guarantees it).
@@ -141,7 +139,6 @@ def apply_matrix_pallas(matrix: np.ndarray, data: Array,
     if pad:
         x32 = jnp.pad(x32, ((0, 0), (0, 0), (0, pad)))
     x32 = x32.reshape(B, k, n_slabs, _LANES)
-    interpret = jax.default_backend() != "tpu"
     out32 = _build(matrix.tobytes(), m, k, n_slabs, sub, interpret)(x32)
     out32 = out32.reshape(B, m, n_slabs * _LANES)
     if pad:

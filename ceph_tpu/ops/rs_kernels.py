@@ -39,8 +39,10 @@ from ..gf.tables import GF_EXP, GF_LOG, bit_powers, matrix_to_bitmatrix
 
 Array = jax.Array
 
-_LOG_T = jnp.asarray(GF_LOG.astype(np.int32))
-_EXP_T = jnp.asarray(GF_EXP[:512].astype(np.uint8))
+# host tables; jnp.asarray at the point of use makes them constants of
+# the traced program, so importing this module creates no backend
+_LOG_T = GF_LOG.astype(np.int32)
+_EXP_T = GF_EXP[:512].astype(np.uint8)
 
 
 def _check(data: Array, k: int) -> None:
@@ -103,14 +105,15 @@ def _apply_logexp_static(matrix: np.ndarray, data: Array) -> Array:
     _check(data, k)
     logs = GF_LOG[matrix].astype(np.int32)  # (m, k) host constants
     zero = matrix == 0
-    ld = jnp.take(_LOG_T, data.astype(jnp.int32))  # (B, k, L)
+    ld = jnp.take(jnp.asarray(_LOG_T), data.astype(jnp.int32))  # (B, k, L)
+    exp_t = jnp.asarray(_EXP_T)
     acc = None
     for i in range(m):
         row = None
         for j in range(k):
             if zero[i, j]:
                 continue
-            prod = jnp.take(_EXP_T, ld[:, j, :] + int(logs[i, j]))
+            prod = jnp.take(exp_t, ld[:, j, :] + int(logs[i, j]))
             prod = jnp.where(data[:, j, :] == 0, jnp.uint8(0), prod)
             row = prod if row is None else row ^ prod
         if row is None:
@@ -127,10 +130,11 @@ def apply_matrix_traced(matrix: Array, data: Array) -> Array:
     data:   (..., k, L) uint8.
     Returns (..., m, L).
     """
-    lm = jnp.take(_LOG_T, matrix.astype(jnp.int32))          # (..., m, k)
-    ld = jnp.take(_LOG_T, data.astype(jnp.int32))            # (..., k, L)
+    log_t = jnp.asarray(_LOG_T)
+    lm = jnp.take(log_t, matrix.astype(jnp.int32))           # (..., m, k)
+    ld = jnp.take(log_t, data.astype(jnp.int32))             # (..., k, L)
     s = lm[..., :, :, None] + ld[..., None, :, :]            # (..., m, k, L)
-    prod = jnp.take(_EXP_T, s)
+    prod = jnp.take(jnp.asarray(_EXP_T), s)
     nz = (matrix[..., :, :, None] != 0) & (data[..., None, :, :] != 0)
     prod = jnp.where(nz, prod, jnp.uint8(0))
     return jnp.bitwise_xor.reduce(prod, axis=-2)

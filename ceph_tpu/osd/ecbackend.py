@@ -271,15 +271,12 @@ class ECBackend(PGBackend):
         matrix = np.frombuffer(matrix_bytes,
                                dtype=np.uint8).reshape(m, k)
         enc = make_encoder(matrix, impl, bucket_batch=False)
-        n = m + k
 
         def fused(d):                # (bucket, k, sl) u8
             parity = enc(d)          # (bucket, m, sl)
             rows = jnp.concatenate([d, parity], axis=1)
-            crcs = crc32c_blocks(rows.reshape(bucket * n, sl),
-                                 init=0xFFFFFFFF,
-                                 xorout=0).reshape(bucket, n)
-            return parity, crcs
+            crcs = crc32c_blocks(rows, init=0xFFFFFFFF, xorout=0)
+            return parity, crcs      # (bucket, n) u32
         return jax.jit(fused)
 
     def _encode_shards_with_crcs(self, data_shards: np.ndarray,
@@ -837,14 +834,12 @@ class ECBackend(PGBackend):
         from ..ops.rs_kernels import make_encoder
         D = np.frombuffer(matrix_bytes, dtype=np.uint8).reshape(m, t)
         enc = make_encoder(D, impl, bucket_batch=False)
-        n = m + t
 
         def fused(d):                   # (bucket, t, wl) u8
             parity = enc(d)             # (bucket, m, wl)
             rows = jnp.concatenate([d, parity], axis=1)
-            crcs = crc32c_blocks(rows.reshape(bucket * n, wl),
-                                 init=0, xorout=0).reshape(bucket, n)
-            return parity, crcs
+            crcs = crc32c_blocks(rows, init=0, xorout=0)
+            return parity, crcs         # (bucket, t + m) u32
         return jax.jit(fused)
 
     def _delta_parity_crcs(self, touched: tuple, deltas: np.ndarray
@@ -1861,6 +1856,15 @@ _RECOVER_PROGRAMS_LOCK = _threading.Lock()
 RECOVERY_FETCH_BYTES = 8 << 20
 
 
+#: helper bytes one fused recovery launch stages (pow2 objects, one at
+#: least). The launch's device scratch is ~13x what it stages at 512 KiB
+#: rows (compiled for a described v5e: 1.7 GiB at 128 MiB staged,
+#: 6.2 GiB at the 512 MiB that osd_recovery_batch=128 objects of 4 MiB
+#: would stage), two launches are in flight per runner, and every
+#: daemon of a process shares the one chip's 16 GiB
+RECOVERY_STAGE_BYTES = 128 << 20
+
+
 @_functools.lru_cache(maxsize=64)
 def _host_encoder_handle(matrix_bytes: bytes, k: int, m: int):
     """Process-wide native RS encoder per coding matrix (the same
@@ -2050,20 +2054,16 @@ def _build_recover_program(dec_fn, verify: bool, host_crc: bool):
     from ..csum.kernels import crc32c_blocks
 
     def fused(stack, expfold):         # (B, H, rl) u8, (B,) u32
-        B, H, L = stack.shape
-        rebuilt = dec_fn(stack)        # (B, E, sl) — sl may exceed
-        E = rebuilt.shape[1]           # the staged rl (range plans
-        out_len = rebuilt.shape[2]     # ship sub-chunks, rebuild
-        #                                whole rows)
-        rcrc = crc32c_blocks(rebuilt.reshape(B * E, out_len),
-                             init=0xFFFFFFFF,
-                             xorout=0).reshape(B, E)
+        # rebuilt (B, E, sl): sl may exceed the staged rl (range plans
+        # ship sub-chunks, rebuild whole rows)
+        rebuilt = dec_fn(stack)
+        rcrc = crc32c_blocks(rebuilt, init=0xFFFFFFFF, xorout=0)
         if verify:
             fold = jnp.bitwise_xor.reduce(stack, axis=1)
             fcrc = crc32c_blocks(fold, init=0xFFFFFFFF, xorout=0)
             ok = fcrc == expfold
         else:
-            ok = jnp.ones((B,), dtype=bool)
+            ok = jnp.ones(stack.shape[:1], dtype=bool)
         return rebuilt, rcrc, ok
     return jax.jit(fused)
 
@@ -2196,9 +2196,13 @@ class RecoveryRunner:
                 groups[key].extend((plan, n) for n in names)
         for key in order:
             pairs = groups[key]
-            for i in range(0, len(pairs), self.batch):
-                sub = pairs[i:i + self.batch]
-                self._batches.append(("fused", sub[0][0], key[1], sub))
+            proto = pairs[0][0]
+            rl, _ranges = proto.row_ranges(key[1])
+            fit = RECOVERY_STAGE_BYTES // max(1, len(proto.helper) * rl)
+            per = min(self.batch, 1 << max(0, fit.bit_length() - 1))
+            for i in range(0, len(pairs), per):
+                sub = pairs[i:i + per]
+                self._batches.append(("fused", proto, key[1], sub))
         self._bi = 0
         self._pending: list = []
         self._stage_bufs: dict = {}

@@ -136,7 +136,6 @@ class OSDProcHandle:
             "mon_names": [m.name for m in c.mons] if c.mons else
             [f"mon.{r}" for r in range(3)],
             "osd_ids": list(range(c.n_osds)),
-            "jax_cache_dir": os.environ.get("BENCH_JAX_CACHE"),
             "verbose": bool(c.verbose),
         }
         if c.key_server is not None:
@@ -145,8 +144,20 @@ class OSDProcHandle:
         return cfg
 
     def _spawn(self) -> None:
-        env = dict(os.environ)
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        # a chip belongs to one process: children of a parent on an
+        # accelerator could only share it by falling back to the CPU,
+        # which would quietly serve all erasure coding from the host.
+        # ROADMAP B8 decides the multi-chip process layout; until then
+        # the children run where the parent runs, and that is the CPU.
+        import jax
+        if jax.default_backend() != "cpu":
+            raise RuntimeError(
+                f"osd_procs=True needs a CPU parent: this process runs "
+                f"JAX on {jax.default_backend()!r}, one chip belongs to "
+                f"one process, and OSD children would have to serve "
+                f"erasure coding from the host. Run the daemons "
+                f"in-process (osd_procs=False) on the chip.")
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
         self._proc = subprocess.Popen(
             [sys.executable, "-m", "ceph_tpu.osd.multiproc"],
             stdin=subprocess.PIPE, stdout=subprocess.PIPE,
@@ -374,7 +385,7 @@ def child_main() -> int:
     # shared persistent jit cache BEFORE any jax import path runs:
     # sibling children and the parent reuse each other's compiles
     from ..utils.jax_cache import enable_persistent_compile_cache
-    enable_persistent_compile_cache(cfg.get("jax_cache_dir"))
+    enable_persistent_compile_cache()
     from .standalone import MOSDBoot, OSDDaemon
     shim = _ChildCluster(cfg)
     daemon = OSDDaemon(cfg["osd_id"], shim)

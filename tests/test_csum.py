@@ -71,6 +71,23 @@ def test_crc32c_kernel_matches_oracle(length):
     np.testing.assert_array_equal(got, want)
 
 
+@pytest.mark.parametrize("length", [8, 4096, 65536, 524288 + 3])
+def test_crc32c_kernel_row_layouts_match_oracle(length):
+    """The layout the TPU needs (byte planes, rows padded to 16, leading
+    dims kept) at the served path's row counts: the 11 rows of one
+    k=8 m=3 object, and a (batch, rebuilt rows, L) recovery stack. The
+    longest length takes the tail path at a 512 KiB shard."""
+    rng = np.random.default_rng(length)
+    rows = rng.integers(0, 256, size=(11, length), dtype=np.uint8)
+    want = np.array([ceph_crc32c(0xFFFFFFFF, row.tobytes()) for row in rows],
+                    dtype=np.uint32)
+    got = np.asarray(crc32c_blocks(rows, init=0xFFFFFFFF, xorout=0))
+    np.testing.assert_array_equal(got, want)
+    stack = rows[:6].reshape(3, 2, length)
+    got = np.asarray(crc32c_blocks(stack, init=0xFFFFFFFF, xorout=0))
+    np.testing.assert_array_equal(got, want[:6].reshape(3, 2))
+
+
 @pytest.mark.parametrize("length", [0, 1, 3, 4, 15, 16, 17, 31, 32, 33, 100,
                                     4096])
 def test_xxh_kernels_match_oracle(length):

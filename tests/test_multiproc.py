@@ -149,3 +149,15 @@ def test_multiproc_memstore_kill_rebuilds_from_survivors(tmp_path):
             assert bytes(cl.read(n)) == v, n
     finally:
         c.shutdown()
+
+
+def test_multiproc_refuses_a_parent_on_an_accelerator(monkeypatch):
+    """One chip belongs to one process: under a non-CPU parent the OSD
+    children could only serve erasure coding from the host, so the
+    spawn raises instead of falling back quietly."""
+    import jax
+
+    from ceph_tpu.osd.multiproc import OSDProcHandle
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="osd_procs=True needs a CPU"):
+        OSDProcHandle(cluster=None, osd_id=0)

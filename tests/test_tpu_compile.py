@@ -1,0 +1,109 @@
+"""The served path's device programs, compiled at real widths for a
+described (not attached) TPU v5e chip: k=8 m=3, 4 MiB objects, 512 KiB
+shard rows. Nothing runs; what the chip's compiler refuses, or the
+scratch memory a program asks for, shows here at no chip time.
+
+All in this one file and one process: only one process at a time may
+load the TPU's library, so the topology is described inside a
+module-scoped fixture (never at import) and every compile happens in
+the worker that was handed this file.
+"""
+
+import numpy as np
+import pytest
+
+K, M, SHARD = 8, 3, 512 << 10
+MiB = 1 << 20
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # noqa: BLE001 — no libtpu, or it is held
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without the chip: keep it out
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def coder():
+    from ceph_tpu.ec.registry import factory
+    return factory(f"plugin=jerasure technique=reed_sol_van k={K} m={M}")
+
+
+def _compile(fn, one_chip, *shapes_dtypes):
+    import jax
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes_dtypes]
+    return jax.jit(fn).lower(*args).compile()
+
+
+@pytest.mark.parametrize("impl", ["bitlinear", "mxu", "logexp"])
+def test_rs_encode_compiles_at_32_objects(one_chip, coder, impl):
+    from ceph_tpu.ops.rs_kernels import make_encoder
+    enc = make_encoder(coder.matrix, impl, bucket_batch=False)
+    out = _compile(enc, one_chip, ((32, K, SHARD), np.uint8))
+    assert out.memory_analysis().temp_size_in_bytes < 2048 * MiB
+
+
+def test_pallas_kernel_compiles_without_interpret(one_chip, coder):
+    """The kernel itself, on packed uint32 slabs, at 32 objects: 1 s and
+    no scratch. (`apply_matrix_pallas` around it packs uint8 into
+    uint32 through a minor dim of 4; that relayout, not the kernel,
+    is the 55-80 s and 2.5 GiB its whole program costs — ROADMAP C2.)"""
+    from ceph_tpu.ops import pallas_gf
+    n_slabs = SHARD // 4 // pallas_gf._LANES
+    kernel = pallas_gf._build(coder.matrix.tobytes(), M, K, n_slabs,
+                              pallas_gf._SUBLANES, False)
+    out = _compile(kernel, one_chip,
+                   ((32, K, n_slabs, pallas_gf._LANES), np.uint32))
+    assert "tpu_custom_call" in out.as_text()
+    assert out.memory_analysis().temp_size_in_bytes < 16 * MiB
+
+
+def test_crc32c_rows_of_one_object_fit(one_chip):
+    """The defect this guards: 11 rows x 512 KiB took 3,168 MiB of
+    scratch (~580x the input) and a minute to compile."""
+    from ceph_tpu.csum.kernels import crc32c_blocks
+    out = _compile(lambda b: crc32c_blocks(b, init=0xFFFFFFFF, xorout=0),
+                   one_chip, ((K + M, SHARD), np.uint8))
+    assert out.memory_analysis().temp_size_in_bytes \
+        < 16 * (K + M) * SHARD
+
+
+def test_fused_write_compiles_at_bucket_16(one_chip, coder):
+    from ceph_tpu.osd.ecbackend import ECBackend
+    fn = ECBackend._fused_write_fn(coder.matrix.tobytes(), M, K,
+                                   coder.impl, SHARD, 16)
+    out = _compile(fn, one_chip, ((16, K, SHARD), np.uint8))
+    # 64 MiB in, 88 MiB of rows to checksum
+    assert out.memory_analysis().temp_size_in_bytes < 2048 * MiB
+
+
+def test_recover_program_compiles_at_the_staged_batch(one_chip, coder):
+    """The batch RecoveryRunner really stages at 4 MiB objects: its byte
+    bound, not osd_recovery_batch (128 objects asked for 6 GiB of
+    scratch per launch, two launches in flight per daemon)."""
+    from ceph_tpu.osd.ecbackend import (RECOVERY_STAGE_BYTES,
+                                        _build_recover_program)
+    batch = RECOVERY_STAGE_BYTES // (K * SHARD)
+    assert batch == 32
+    lost = (0, 9)
+    helper = tuple(i for i in range(K + M) if i not in lost)[:K]
+    fn = _build_recover_program(coder.batch_decoder(lost, helper),
+                                verify=True, host_crc=False)
+    out = _compile(fn, one_chip, ((batch, K, SHARD), np.uint8),
+                   ((batch,), np.uint32))
+    assert out.memory_analysis().temp_size_in_bytes < 2048 * MiB

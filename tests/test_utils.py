@@ -416,3 +416,49 @@ class TestOpTracking:
         assert "reached_pg" in events
         inflight = c.op_tracker.dump_ops_in_flight()
         assert inflight.get("num_ops", inflight.get("num", 0)) == 0
+
+
+class TestDevicePlacedFromOutside:
+    """One process per chip: nothing claims a device, or chooses a
+    compile-cache directory, behind the caller's back."""
+
+    @staticmethod
+    def _run(code: str, **env_extra) -> str:
+        import os
+        import subprocess
+        import sys
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        env = {k: v for k, v in os.environ.items()
+               if k != "JAX_COMPILATION_CACHE_DIR"}
+        env.update(JAX_PLATFORMS="cpu", PYTHONPATH=repo, **env_extra)
+        out = subprocess.run([sys.executable, "-c", code], env=env,
+                             capture_output=True, text=True, timeout=120)
+        assert out.returncode == 0, out.stderr
+        return out.stdout.strip().splitlines()[-1]
+
+    def test_importing_the_served_path_creates_no_backend(self):
+        got = self._run(
+            "import ceph_tpu.osd.standalone, ceph_tpu.osd.multiproc, "
+            "ceph_tpu.csum, ceph_tpu.ec.registry\n"
+            "from jax._src import xla_bridge\n"
+            "print(xla_bridge.backends_are_initialized())")
+        assert got == "False"
+
+    @pytest.mark.parametrize("outside", ["", "/some/outside/dir"])
+    def test_compile_cache_directory(self, outside):
+        from ceph_tpu.utils.jax_cache import DEFAULT_CACHE_DIR
+        env = {"JAX_COMPILATION_CACHE_DIR": outside} if outside else {}
+        got = self._run(
+            "import jax\n"
+            "from ceph_tpu.utils.jax_cache import "
+            "enable_persistent_compile_cache as on\n"
+            "before = jax.config.jax_compilation_cache_dir\n"
+            "ret = on()\n"
+            "print(before, jax.config.jax_compilation_cache_dir, ret)",
+            **env)
+        if outside:   # placed from outside: the code sets no directory
+            assert got.split() == [outside] * 3
+        else:         # one fixed path inside the checkout
+            assert got.split() == ["None", DEFAULT_CACHE_DIR,
+                                   DEFAULT_CACHE_DIR]
+            assert DEFAULT_CACHE_DIR.endswith("/.jax_bench_cache")

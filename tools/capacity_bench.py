@@ -308,7 +308,6 @@ def main(argv=None) -> None:
     ap.add_argument("--out", default=None)
     ap.add_argument("--json", action="store_true")
     args = ap.parse_args(argv)
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
     from ceph_tpu.utils.jax_cache import enable_persistent_compile_cache
     enable_persistent_compile_cache()
