@@ -52,14 +52,30 @@ CATEGORY_OF = {
     "rpc.window": "queue",
     "msgr.seal": "crypto",
     "msgr.open": "crypto",
+    "osd.pg_lock.wait": "queue",
+    "osd.store_lock.wait": "queue",
+    "ecbackend.write.stripe": "encode",
     "ecbackend.write.encode": "encode",
+    "ecbackend.write.stage": "encode",
+    "ecbackend.write.launch": "encode",
+    "ecbackend.write.fetch": "encode",
+    "ecbackend.write.txns": "encode",
+    "ecbackend.write.fanout": "wire",
+    "ecbackend.read.gather": "encode",
+    "ecbackend.read.verify": "encode",
+    "ecbackend.read.verify.stage": "encode",
+    "ecbackend.read.verify.launch": "encode",
+    "ecbackend.read.verify.fetch": "encode",
     "ecbackend.read.decode": "encode",
+    "ecbackend.read.unstripe": "encode",
     "ecbackend.recover.stage": "encode",
     "ecbackend.recover.launch": "encode",
     "ecbackend.recover.fetch": "encode",
     "ecbackend.recover.batch": "encode",
     "ecbackend.recover.writeback": "store",
     "store.apply": "store",
+    "store.commit": "store",
+    "store.read": "store",
     "osd.subop": "store",
     "retro.reached_pg": "queue",
     "retro.commit_sent": "other",

@@ -90,9 +90,8 @@ from collections import deque
 
 from ..csum.reference import ceph_crc32c, ceph_crc32c_iov
 from ..utils.encoding import Decoder, Encoder
-from ..utils.flight_recorder import current_sampled as _ftrace_active
-from ..utils.flight_recorder import trace_span as _ftrace_span
 from ..utils.perf_counters import PerfCountersBuilder
+from ..utils.tracing import span as _span
 
 
 def msgr_perf_counters():
@@ -116,7 +115,6 @@ def msgr_perf_counters():
             .add_u64_counter("replayed", "unacked frames replayed")
             .add_u64_counter("tx_compressed", "frames compressed on tx")
             .add_u64_counter("rx_compressed", "frames inflated on rx")
-            .add_time_avg("crc_time", "frame crc32c compute (crc mode)")
             .add_time_avg("seal_time",
                           "AEAD seal incl. staging (secure mode)",
                           hist=True)
@@ -559,11 +557,7 @@ class _Conn:
             # the two-step concat produced; the crc is a seeded
             # continuation over header + payload segments — no join
             hdr = struct.pack("<IQH", 10 + plen, seq, type_id)
-            t0 = _time_mod.perf_counter() if self.perf is not None else 0.0
             crc = struct.pack("<I", _crc_iov([hdr] + segs))
-            if self.perf is not None:
-                self.perf.tinc("crc_time",
-                               _time_mod.perf_counter() - t0)
             with self.wlock:
                 if self.reactor is None:
                     _sendmsg_all(self.sock, [hdr] + segs + [crc])
@@ -572,30 +566,17 @@ class _Conn:
             wire = 14 + plen + 4
             nseg = len(segs)
         else:
-            # r15: when a sampled trace context is active on this
-            # thread (an op reply sealing inside the op's dynamic
-            # extent), the AEAD seal records as a crypto span — one
-            # contextvar read per frame otherwise
             with self.wlock:
                 # seal under the lock: the nonce counter must advance
                 # in transmit order or a reordered pair would reuse
                 # one. AEAD needs contiguous input: stage ONE buffer.
                 hdr = struct.pack(
                     "<I", _NONCE + 10 + plen + _GCM_TAG)
-                t0 = _time_mod.perf_counter() \
-                    if self.perf is not None else 0.0
-                if _ftrace_active() is not None:
-                    with _ftrace_span("msgr.seal", nbytes=plen):
-                        plain = _flatten(
-                            [struct.pack("<QH", seq, type_id)] + segs)
-                        sealed = self.box.seal(plain, hdr)
-                else:
+                with _span("msgr.seal", counters=self.perf,
+                           key="seal_time", nbytes=plen):
                     plain = _flatten(
                         [struct.pack("<QH", seq, type_id)] + segs)
                     sealed = self.box.seal(plain, hdr)
-                if self.perf is not None:
-                    self.perf.tinc("seal_time",
-                                   _time_mod.perf_counter() - t0)
                 if self.reactor is None:
                     _sendmsg_all(self.sock, [hdr, sealed])
                 else:
@@ -1492,23 +1473,19 @@ class Messenger:
         incarnation), exactly as before."""
         blen = len(body)
         if conn.box is None:
-            t0 = _time_mod.perf_counter()
             if _crc_iov([raw_len, body]) != crc:
                 # ProtocolV2 crc mode: corrupt frame kills the
                 # session; replay redelivers after reconnect
                 raise ConnectionError("frame crc mismatch")
-            self.perf.tinc("crc_time",
-                           _time_mod.perf_counter() - t0)
             self.perf.inc_many((("frames_rx", 1),
                                 ("bytes_rx", 8 + blen)))
             rx_wire = 8 + blen
         else:
             # secure mode: the GCM tag is the integrity check
             # (and the length header is bound in as AAD)
-            t0 = _time_mod.perf_counter()
-            body = conn.box.open(body, raw_len)
-            self.perf.tinc("open_time",
-                           _time_mod.perf_counter() - t0)
+            with _span("msgr.open", counters=self.perf,
+                       key="open_time", nbytes=blen):
+                body = conn.box.open(body, raw_len)
             self.perf.inc_many((("frames_rx", 1),
                                 ("bytes_rx", 4 + blen)))
             rx_wire = 4 + blen

@@ -174,15 +174,22 @@ def pow2_bucket(n: int) -> int:
     return 1 << max(0, int(n - 1).bit_length())
 
 
-def run_bucketed(fn, arr):
-    """Call `fn` with `arr`'s leading dim padded to the pow2 bucket and
-    slice the result back — the ONE implementation of the bucketing
-    idiom (encoders, CRC stacks, anything row-batched)."""
+def pad_to_bucket(arr):
+    """`arr` on the device with its leading dim padded to the pow2
+    bucket, and the leading dim it had."""
     arr = jnp.asarray(arr)
     B = arr.shape[0]
     bucket = pow2_bucket(B)
     if bucket != B:
         arr = jnp.pad(arr, [(0, bucket - B)] + [(0, 0)] * (arr.ndim - 1))
+    return arr, B
+
+
+def run_bucketed(fn, arr):
+    """Call `fn` with `arr`'s leading dim padded to the pow2 bucket and
+    slice the result back — the ONE implementation of the bucketing
+    idiom (encoders, CRC stacks, anything row-batched)."""
+    arr, B = pad_to_bucket(arr)
     return fn(arr)[:B]
 
 

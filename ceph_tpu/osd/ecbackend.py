@@ -107,6 +107,13 @@ def ec_perf_counters():
                           hist=True)
             .add_time_avg("decode_time", "read-path decode wall time",
                           hist=True)
+            .add_u64_counter("verify_launches",
+                             "read-path crc verify device launches")
+            .add_u64_counter("verify_bytes",
+                             "shard bytes crc-verified on read")
+            .add_time_avg("verify_time",
+                          "read-path crc verify wall time (stage, "
+                          "launch, blocking fetch)", hist=True)
             .add_time_avg("recover_stage_time",
                           "recovery host staging (producer thread)")
             .add_time_avg("recover_launch_time",
@@ -348,15 +355,22 @@ class ECBackend(PGBackend):
                  ("encode_bytes", int(data_shards.size)),
                  ("program_cache_hits", ci1.hits - ci0.hits),
                  ("program_cache_misses", ci1.misses - ci0.misses)))
-            padded = data_shards
-            if bucket != B:
-                padded = np.zeros((bucket,) + data_shards.shape[1:],
-                                  dtype=np.uint8)
-                padded[:B] = data_shards
+            # stage + launch is the host's own work; fetch is the
+            # wait for the device (and for the ops queued before it)
             with span("ecbackend.write.encode", counters=self.perf,
                       key="encode_time"):
-                parity_d, crcs_d = fn(padded)
-                parity, dense_crcs = jax.device_get((parity_d, crcs_d))
+                with span("ecbackend.write.stage"):
+                    padded = data_shards
+                    if bucket != B:
+                        padded = np.zeros(
+                            (bucket,) + data_shards.shape[1:],
+                            dtype=np.uint8)
+                        padded[:B] = data_shards
+                with span("ecbackend.write.launch"):
+                    parity_d, crcs_d = fn(padded)
+                with span("ecbackend.write.fetch"):
+                    parity, dense_crcs = jax.device_get(
+                        (parity_d, crcs_d))
             dense = np.concatenate(
                 [data_shards, np.asarray(parity)[:B]], axis=1)
             dense_crcs = np.asarray(dense_crcs)[:B]
@@ -430,21 +444,14 @@ class ECBackend(PGBackend):
                         txns.append((shard, t))
                     self._fanout_txns(txns)
                 continue
-            batch = np.stack([a for _, a in group])
             sl = self._shard_len(olen)
-            data_shards = self.sinfo.object_to_shards(batch)  # (B, k, sl)
+            with span("ecbackend.write.stripe"):
+                batch = np.stack([a for _, a in group])
+                data_shards = self.sinfo.object_to_shards(batch)  # (B, k, sl)
             shards, crcs = self._encode_shards_with_crcs(data_shards,
                                                          sl)
             for name, _ in group:
                 self.object_sizes[name] = olen
-            add = None
-            if shard_txn_extra is not None:
-                # log FIRST so the extra ops (the metadata persist)
-                # see the post-write history; see the docstring for
-                # why a failed wave cannot wedge the cursors
-                for name, _ in group:
-                    self._log_write(name, live)
-                add = shard_txn_extra([n for n, _ in group])
             # ONE combined transaction per shard for the whole batch
             # (the sub-op fan-out unit; on the wire tier this is one
             # MStoreOp frame per shard instead of one per object —
@@ -452,21 +459,34 @@ class ECBackend(PGBackend):
             # whole RMW plan), fanned out pipelined: all shards'
             # frames hit the wire before any ack is awaited
             txns = []
-            for shard in live:
-                cid = shard_cid(self.pg, shard)
-                t = Transaction()
-                for bi, (name, arr) in enumerate(group):
-                    hinfo = HashInfo(1, sl, [int(crcs[bi, shard])])
-                    # truncate clears any stale tail from a previous,
-                    # larger version of the object
-                    t.write(cid, name, 0, shards[bi, shard, :]) \
-                     .truncate(cid, name, sl) \
-                     .setattr(cid, name, HINFO_KEY, hinfo.to_bytes())
-                if add is not None:
-                    add(shard, t)
-                txns.append((shard, t))
+            with span("ecbackend.write.txns"):
+                add = None
+                if shard_txn_extra is not None:
+                    # log FIRST so the extra ops (the metadata
+                    # persist) see the post-write history; see the
+                    # docstring for why a failed wave cannot wedge
+                    # the cursors
+                    for name, _ in group:
+                        self._log_write(name, live)
+                    add = shard_txn_extra([n for n, _ in group])
+                for shard in live:
+                    cid = shard_cid(self.pg, shard)
+                    t = Transaction()
+                    for bi, (name, arr) in enumerate(group):
+                        hinfo = HashInfo(1, sl, [int(crcs[bi, shard])])
+                        # truncate clears any stale tail from a
+                        # previous, larger version of the object
+                        t.write(cid, name, 0, shards[bi, shard, :]) \
+                         .truncate(cid, name, sl) \
+                         .setattr(cid, name, HINFO_KEY,
+                                  hinfo.to_bytes())
+                    if add is not None:
+                        add(shard, t)
+                    txns.append((shard, t))
             self.perf.inc("write_wire_bytes", len(group) * len(live) * sl)
-            self._fanout_txns(txns)
+            # submit to every shard, then the wait for the slowest ack
+            with span("ecbackend.write.fanout"):
+                self._fanout_txns(txns)
             if shard_txn_extra is None:
                 for name, _ in group:
                     self._log_write(name, live)
@@ -1369,34 +1389,46 @@ class ECBackend(PGBackend):
                     self._count_plan(family)
                 need = sorted(need_set)
                 stacks, missing = {}, None
-                for s in need:
-                    try:
-                        stacks[s] = np.stack(
-                            [self._store(s).read(shard_cid(self.pg, s),
-                                                 n) for n in group])
-                    except KeyError:
-                        # cursor says fresh but the store lacks the
-                        # object: a repointed slot whose rebuild has
-                        # not landed this object yet (recovery in
-                        # flight) — plan around it like a stale shard
-                        missing = s
-                        break
+                with span("ecbackend.read.gather"):
+                    for s in need:
+                        try:
+                            stacks[s] = np.stack(
+                                [self._store(s).read(
+                                    shard_cid(self.pg, s), n)
+                                 for n in group])
+                        except KeyError:
+                            # cursor says fresh but the store lacks
+                            # the object: a repointed slot whose
+                            # rebuild has not landed this object yet
+                            # (recovery in flight) — plan around it
+                            # like a stale shard
+                            missing = s
+                            break
                 if missing is None:
                     break
                 avail.remove(missing)
             bad: dict[str, set[int]] = {}
             if verify:
-                rows = np.concatenate([stacks[s] for s in need])
-                crcs = self._batched_crcs(rows).reshape(
-                    len(need), len(group))
-                for si, s in enumerate(need):
-                    st = self._store(s)
-                    cid = shard_cid(self.pg, s)
-                    for bi, nm in enumerate(group):
-                        hinfo = HashInfo.from_bytes(
-                            st.getattr(cid, nm, HINFO_KEY))
-                        if int(crcs[si, bi]) != hinfo.get_chunk_hash(0):
-                            bad.setdefault(nm, set()).add(s)
+                # the read path's device launch: one crc program over
+                # every row consumed
+                with span("ecbackend.read.verify", counters=self.perf,
+                          key="verify_time"):
+                    rows = np.concatenate([stacks[s] for s in need])
+                    crcs = self._batched_crcs(
+                        rows, "ecbackend.read.verify").reshape(
+                            len(need), len(group))
+                self.perf.inc_many((("verify_launches", 1),
+                                    ("verify_bytes", int(rows.size))))
+                with span("ecbackend.read.gather"):   # the stored crcs
+                    for si, s in enumerate(need):
+                        st = self._store(s)
+                        cid = shard_cid(self.pg, s)
+                        for bi, nm in enumerate(group):
+                            hinfo = HashInfo.from_bytes(
+                                st.getattr(cid, nm, HINFO_KEY))
+                            if int(crcs[si, bi]) \
+                                    != hinfo.get_chunk_hash(0):
+                                bad.setdefault(nm, set()).add(s)
             clean_group = [n for n in group if n not in bad]
             if clean_group:
                 idx = [group.index(n) for n in clean_group]
@@ -1408,9 +1440,10 @@ class ECBackend(PGBackend):
                 with span("ecbackend.read.decode", counters=self.perf,
                           key="decode_time"):
                     rec = self.coder.decode(want, sub)
-                shards = np.stack([rec[s] for s in self.data_slots],
-                                  axis=1)
-                objs = self.sinfo.shards_to_object(shards)
+                with span("ecbackend.read.unstripe"):
+                    shards = np.stack([rec[s] for s in self.data_slots],
+                                      axis=1)
+                    objs = self.sinfo.shards_to_object(shards)
                 for oi, name in enumerate(clean_group):
                     out[name] = objs[oi, :self.object_sizes[name]]
             for name, bad_set in bad.items():

@@ -32,6 +32,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from ..utils.tracing import span
 from .memstore import MemStore, Transaction
 from .pglog import PGLog
 from .stripe import HashInfo, as_flat_u8
@@ -152,17 +153,23 @@ class PGBackend:
                 f"min_size={self.min_live}; write refused (pg inactive)")
 
     @staticmethod
-    def _batched_crcs(blocks: np.ndarray) -> np.ndarray:
+    def _batched_crcs(blocks: np.ndarray,
+                      stages: str = "pgbackend.crcs") -> np.ndarray:
         """One device launch for a (B, L) stack of byte rows -> (B,)
         uint32 CRCs (raw register, seed -1 — the HashInfo convention).
         The row count is bucketed to a power of two: per-PG batches
         vary freely and each distinct B would otherwise compile its
-        own program."""
+        own program. `stages` names the caller's path: its `.stage`
+        (to the device, padded), `.launch` (dispatch returns) and
+        `.fetch` (the wait for the device) spans."""
         from ..csum.kernels import crc32c_blocks
-        from ..ops.rs_kernels import run_bucketed
-        return np.asarray(run_bucketed(
-            lambda b: crc32c_blocks(b, init=0xFFFFFFFF, xorout=0),
-            np.asarray(blocks, dtype=np.uint8)))
+        from ..ops.rs_kernels import pad_to_bucket
+        with span(stages + ".stage"):
+            padded, B = pad_to_bucket(np.asarray(blocks, dtype=np.uint8))
+        with span(stages + ".launch"):
+            crcs = crc32c_blocks(padded, init=0xFFFFFFFF, xorout=0)[:B]
+        with span(stages + ".fetch"):
+            return np.asarray(crcs)
 
     def _remove_strays(self, dead: set[int]) -> int:
         """Remove per-slot leftover objects the PG's metadata no

@@ -72,6 +72,7 @@ from ..msgr.messenger import Message, Messenger, register_message
 from ..utils.encoding import Decoder, Encoder
 from ..utils.flight_recorder import current as _trace_current
 from ..utils.flight_recorder import declare_span_names
+from ..utils.tracing import locked, record_wait, span
 from .ecbackend import ECBackend, ShardSet, shard_cid
 from .memstore import MemStore, Transaction
 from .osdmap import (FULL_BACKFILLFULL, FULL_FULL, FULL_NEARFULL,
@@ -93,12 +94,23 @@ _META_DELTA_MAX = 32
 # ecbackend's span() sites declare themselves through the same call —
 # the observability smoke asserts no ring carries an undeclared name)
 declare_span_names(
-    "client.op", "client.hedge",
-    "osd.queue", "osd.op", "osd.subop", "store.apply",
+    "client.op", "client.hedge", "rpc.window",
+    "osd.queue", "osd.op", "osd.pg_lock.wait",
+    "osd.subop", "osd.store_lock.wait",
+    "store.apply", "store.commit", "store.read",
     "osd.recovery_round",
     "osd.repair_policy", "osd.repair_throttle",
-    "msgr.seal",
-    "ecbackend.write.encode", "ecbackend.read.decode",
+    "msgr.seal", "msgr.open",
+    "ecbackend.write.stripe", "ecbackend.write.encode",
+    "ecbackend.write.stage", "ecbackend.write.launch",
+    "ecbackend.write.fetch", "ecbackend.write.txns",
+    "ecbackend.write.fanout",
+    "ecbackend.read.gather", "ecbackend.read.verify",
+    "ecbackend.read.verify.stage", "ecbackend.read.verify.launch",
+    "ecbackend.read.verify.fetch", "ecbackend.read.decode",
+    "ecbackend.read.unstripe",
+    "pgbackend.crcs.stage", "pgbackend.crcs.launch",
+    "pgbackend.crcs.fetch",
     "ecbackend.recover.stage", "ecbackend.recover.launch",
     "ecbackend.recover.fetch", "ecbackend.recover.writeback",
     "ecbackend.recover.batch",
@@ -808,9 +820,10 @@ class _Rpc:
                     # backpressure accounting: how long a full window
                     # held this submitter (the stall the r8 bench
                     # could only guess at)
+                    stalled = time.perf_counter() - t0
                     self.perf.inc("window_stalls")
-                    self.perf.tinc("window_stall_time",
-                                   time.perf_counter() - t0)
+                    self.perf.tinc("window_stall_time", stalled)
+                    record_wait("rpc.window", t0, stalled)
             rid = self._next
             self._next += 1
             ent = _PendingCall(self, rid, peer, nbytes)
@@ -1377,7 +1390,7 @@ class _BatchJoin:
 
     def __init__(self, daemon: "OSDDaemon", peer: str, msg,
                  n_slots: int, n_groups: int,
-                 t_enq: float | None = None):
+                 t_enq: tuple[float, float] | None = None):
         self.d, self.peer, self.msg = daemon, peer, msg
         self.slots: list = [None] * n_slots
         self._left = n_groups
@@ -1873,26 +1886,23 @@ class OSDDaemon:
                 except (KeyError, OSError, ConnectionError):
                     pass
                 return
-            # r15: a sampled context on the frame puts this hop's
-            # spans under the originating trace — osd.subop covers the
-            # whole service (store-lock wait + reply encode), with the
-            # store apply itself a nested child, so the assembler can
-            # split store time from sub-op queueing.
-            from ..utils.flight_recorder import activate, trace_span
-            ctx = msg.trace if msg.trace is not None \
-                and msg.trace.sampled else None
+            # r15: the context on the frame puts this hop's spans
+            # under the originating trace (the flight ring takes them
+            # only where it is sampled) — osd.subop covers the whole
+            # service, with the store-lock wait and the store apply
+            # nested children, so the assembler can split store time
+            # from sub-op queueing.
+            from ..utils.flight_recorder import activate
             t0w, t0 = time.time(), time.perf_counter()
             apply_s = 0.0
-            with activate(ctx, self.flight if ctx is not None
-                          else None):
-                with trace_span("osd.subop", kind=msg.kind):
-                    with self.perf.time("subop_latency"):
-                        with self._store_lock:
-                            ta = time.perf_counter()
-                            with trace_span("store.apply"):
-                                blob = self._store_op(msg.kind,
-                                                      msg.blob)
-                            apply_s = time.perf_counter() - ta
+            with activate(msg.trace, self.flight):
+                with span("osd.subop", counters=self.perf,
+                          key="subop_latency"):
+                    with locked(self._store_lock, "osd.store_lock.wait"):
+                        ta = time.perf_counter()
+                        with span("store.apply"):
+                            blob = self._store_op(msg.kind, msg.blob)
+                        apply_s = time.perf_counter() - ta
             # r18: an UNSAMPLED context still carries the trace id —
             # remember this hop's window so a later slow-op retro
             # assembly covers the replica too (the sampled case
@@ -3430,8 +3440,11 @@ class OSDDaemon:
                 or f"/tmp/{self.name}-trace"
             return {"started": start_trace(log_dir), "dir": log_dir}
         if cmd == "trace stop":
+            # the capture's stage table beside its directory: self
+            # time by span name, an op being one osd.op
             from ..utils.tracing import stop_trace
-            return {"stopped": stop_trace()}
+            table = stop_trace()
+            return {"stopped": table is not None, **(table or {})}
         if cmd == "dump_mclock":
             # per-class occupancy + grants, tenant classes included,
             # MERGED across op shards (the pre-shard shape — tools
@@ -3601,7 +3614,9 @@ class OSDDaemon:
         # per client entity per shard), so a heavy tenant — hedged
         # duplicates and degraded decodes included — competes under
         # its own (ρ, w, λ) tags instead of starving the rest.
-        t_enq = time.time()     # r15: the osd.queue span's start mark
+        # the osd.queue wait's start mark: wall clock for the flight
+        # ring, perf_counter for the span log
+        t_enq = (time.time(), time.perf_counter())
         if sub_ops is None:
             shard = self._shard_of(self._op_ps(msg.blob))
             cls = "scrub" if msg.kind in ("deep_scrub", "repair") \
@@ -3635,7 +3650,7 @@ class OSDDaemon:
             self._sched_enqueue(
                 cls, lambda items=items: join.run(items), shard=shard)
 
-    def _trace_enter(self, msg, t_enq: float | None):
+    def _trace_enter(self, msg, t_enq: tuple[float, float] | None):
         """One op frame's trace arrival on a shard worker: fold the
         client's cost snapshot (sampled first hops carry it), record
         the mClock queue wait as an `osd.queue` span, and return the
@@ -3643,6 +3658,11 @@ class OSDDaemon:
         no-op manager when the frame is untraced)."""
         from ..utils.flight_recorder import activate
         ctx = msg.trace
+        waited = 0.0
+        if t_enq is not None:
+            waited = max(0.0, time.perf_counter() - t_enq[1])
+            record_wait("osd.queue", t_enq[1], waited,
+                        ctx.trace_id if ctx is not None else None)
         if ctx is None:
             return activate(None, None)
         if ctx.client_lat or ctx.client_suspects:
@@ -3651,8 +3671,7 @@ class OSDDaemon:
             from ..utils.flight_recorder import new_trace_id
             self.flight.record(ctx.trace_id, new_trace_id(),
                                ctx.parent_span_id, "osd.queue",
-                               t_enq, max(0.0, time.time() - t_enq),
-                               {"kind": msg.kind})
+                               t_enq[0], waited, {"kind": msg.kind})
         return activate(ctx, self.flight)
 
     def _maybe_retro_trace(self, op, ctx, ps: int | None = None) -> None:
@@ -3746,7 +3765,9 @@ class OSDDaemon:
         return len(matches)
 
     def _serve_client_op(self, peer: str, msg: MOSDOp,
-                         sub_ops, t_enq: float | None = None) -> None:
+                         sub_ops,
+                         t_enq: tuple[float, float] | None = None
+                         ) -> None:
         with self._trace_enter(msg, t_enq):
             self._serve_client_op_inner(peer, msg, sub_ops)
 
@@ -3780,7 +3801,6 @@ class OSDDaemon:
 
     def _one_client_op(self, peer: str, kind: str, body: bytes) -> bytes:
         from ..utils.flight_recorder import current
-        from ..utils.tracing import span
         ps = self._op_ps(body)
         is_read = kind in self._READ_KINDS
         t0 = time.perf_counter()
@@ -3810,7 +3830,7 @@ class OSDDaemon:
                 # independent PGs really do run concurrently across
                 # shards; reconcile/recovery exclude themselves per PG
                 # (they take self._lock THEN the PG locks they touch)
-                with self._pg_lock(ps):
+                with locked(self._pg_lock(ps), "osd.pg_lock.wait"):
                     op.mark_event("reached_pg")
                     blob = self._client_op(kind, body)
                 op.mark_event("commit_sent")
@@ -6150,7 +6170,7 @@ class _TracedCall:
                  ctx, name: str, tags: dict | None):
         self._cl, self._p, self.ctx = client, pend, ctx
         self._name, self._tags = name, tags
-        self._t0w, self._t0m = time.time(), time.monotonic()
+        self._t0w, self._t0m = time.time(), time.perf_counter()
         self._done = False
 
     def _finish(self) -> None:
@@ -6158,7 +6178,8 @@ class _TracedCall:
         if self._done or ctx is None:
             return
         self._done = True
-        dur = time.monotonic() - self._t0m
+        dur = time.perf_counter() - self._t0m
+        record_wait(self._name, self._t0m, dur, ctx.trace_id)
         retro = not ctx.sampled
         if retro and dur <= self._cl.op_tracker.complaint_time:
             return

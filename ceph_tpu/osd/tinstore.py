@@ -109,6 +109,7 @@ import numpy as np
 from ..kv import TinDB, TinDBCorruption, host_crc32c
 from ..kv.tindb import Segment, scan_wal, write_segment
 from ..utils.encoding import Decoder, Encoder, EncodingError
+from ..utils.tracing import span as _span
 from .memstore import MemStore, Transaction, _Object  # noqa: F401 — _Object
 #                      re-exported for store-agnostic test helpers
 
@@ -972,7 +973,9 @@ class TinStore:
     # -- transactional write path -------------------------------------------
 
     def queue_transaction(self, txn: Transaction) -> None:
-        with self._lock:
+        # the span inside the lock: the device write + WAL append, not
+        # the wait for another transaction
+        with self._lock, _span("store.commit"):
             self._alive()
             self._validate(txn)
             if self._fault is not None:
@@ -1344,7 +1347,7 @@ class TinStore:
 
     def read(self, cid: str, oid: str, offset: int = 0,
              length: int | None = None) -> np.ndarray:
-        with self._lock:
+        with self._lock, _span("store.read"):
             coll = self._alive().get(cid)
             if coll is None or oid not in coll:
                 raise KeyError(f"no object {cid}/{oid}")

@@ -1,0 +1,329 @@
+"""The span log of utils/tracing (PR 25): one `span()` with two sinks
+while a profiler session is live, none while it is not.
+
+Units: self time with children subtracted, `record_wait`, the compile
+listener, `stage_table`, `start_trace`/`stop_trace` on this jax. Live
+(one small cephx + secure EC cluster on TinStore, the fused device
+write path switched on for the CPU backend as bench/tests does): with
+no session a client write and read log nothing; with one, a write
+leaves every write-path stage and a read every read-path stage, the
+spans of one op share its trace id on the client, its primary and the
+replicas, and the new `ec` verify counters rise.
+"""
+
+import os
+import time
+
+import pytest
+
+from ceph_tpu.utils import tracing
+from ceph_tpu.utils.flight_recorder import (FlightRecorder, TraceContext,
+                                            activate, is_span_declared)
+from ceph_tpu.utils.tracing import (record_wait, span, span_log,
+                                    stage_table, start_trace, stop_trace)
+
+WRITE_STAGES = {
+    "client.op", "msgr.seal", "msgr.open", "osd.queue", "osd.op",
+    "osd.pg_lock.wait", "ecbackend.write.stripe", "ecbackend.write.encode",
+    "ecbackend.write.stage", "ecbackend.write.launch",
+    "ecbackend.write.fetch", "ecbackend.write.txns",
+    "ecbackend.write.fanout", "osd.subop", "osd.store_lock.wait",
+    "store.apply", "store.commit"}
+READ_STAGES = {
+    "client.op", "msgr.seal", "msgr.open", "osd.queue", "osd.op",
+    "osd.pg_lock.wait", "ecbackend.read.gather", "ecbackend.read.verify",
+    "ecbackend.read.verify.stage", "ecbackend.read.verify.launch",
+    "ecbackend.read.verify.fetch", "ecbackend.read.decode",
+    "ecbackend.read.unstripe", "osd.subop",
+    "osd.store_lock.wait", "store.apply", "store.read"}
+
+
+@pytest.fixture
+def session(tmp_path):
+    """A live capture, Python tracer off: what makes tracing 'on'."""
+    assert start_trace(str(tmp_path / "capture"))
+    yield tmp_path / "capture"
+    if tracing._session[0] is not None:
+        stop_trace()
+
+
+def _mine(since: float) -> dict:
+    """name -> records logged since `since`, compiles left out."""
+    out: dict = {}
+    for r in span_log(since=since):
+        if r["name"] != "xla.compile":
+            out.setdefault(r["name"], []).append(r)
+    return out
+
+
+class TestSpanLogUnits:
+    def test_no_session_no_record_but_the_counter_ticks(self):
+        from ceph_tpu.utils.perf_counters import PerfCountersBuilder
+        pc = (PerfCountersBuilder("t").add_time_avg("lat")
+              .create_perf_counters())
+        t0 = time.perf_counter()
+        with span("unit.off", counters=pc, key="lat"):
+            pass
+        record_wait("unit.off.wait", t0, 0.001)
+        assert _mine(t0) == {}
+        assert pc.get("lat")["count"] == 1
+
+    def test_self_time_subtracts_children_on_the_same_thread(self, session):
+        t0 = time.perf_counter()
+        with span("unit.parent"):
+            with span("unit.child", nbytes=7):
+                time.sleep(0.02)
+            with span("unit.child"):
+                time.sleep(0.01)
+            time.sleep(0.005)
+        got = _mine(t0)
+        (parent,), kids = got["unit.parent"], got["unit.child"]
+        assert len(kids) == 2 and kids[0]["nbytes"] == 7
+        for r in kids + [parent]:
+            assert 0 <= r["self"] <= r["dur"]
+        covered = sum(k["dur"] for k in kids)
+        assert parent["self"] == pytest.approx(parent["dur"] - covered)
+        assert 0.004 < parent["self"] < parent["dur"] - 0.029
+        # the child ended first, and inside its parent
+        assert kids[0]["start"] >= parent["start"]
+        assert span_log(since=t0)[-1]["name"] == "unit.parent"
+
+    def test_a_span_that_raises_is_logged_and_unwinds_the_stack(self,
+                                                                 session):
+        t0 = time.perf_counter()
+        with pytest.raises(ValueError):
+            with span("unit.outer"):
+                with span("unit.raises"):
+                    raise ValueError("boom")
+        with span("unit.after"):
+            pass
+        got = _mine(t0)
+        assert set(got) == {"unit.outer", "unit.raises", "unit.after"}
+        assert tracing._tls.stack == []
+
+    def test_trace_id_is_the_bound_context_s_sampled_or_not(self, session):
+        t0 = time.perf_counter()
+        fr = FlightRecorder("osd.9")
+        with activate(TraceContext(0xABC, 1, sampled=False), fr):
+            with span("unit.unsampled"):
+                pass
+        with activate(TraceContext(0xDEF, 1, sampled=True), fr):
+            with span("osd.op", nbytes=3):
+                pass
+        with span("unit.unbound"):
+            pass
+        got = _mine(t0)
+        assert got["unit.unsampled"][0]["trace_id"] == 0xABC
+        assert got["osd.op"][0]["trace_id"] == 0xDEF
+        assert got["unit.unbound"][0]["trace_id"] is None
+        # the flight ring takes the sampled one only, with its tag
+        ring = fr.dump()["spans"]
+        assert [s["name"] for s in ring] == ["osd.op"]
+        assert ring[0]["tags"] == {"nbytes": 3}
+
+    def test_locked_spans_the_wait_and_lets_go_when_the_body_raises(
+            self, session):
+        import threading
+        from ceph_tpu.utils.tracing import locked
+        lock = threading.Lock()
+        t0 = time.perf_counter()
+        with pytest.raises(KeyError):
+            with locked(lock, "osd.pg_lock.wait"):
+                assert lock.locked()
+                raise KeyError("body")
+        assert not lock.locked()
+        (wait,) = _mine(t0)["osd.pg_lock.wait"]
+        assert wait["dur"] < 0.01            # the wait, not the hold
+
+    def test_record_wait_logs_the_wait_as_given(self, session):
+        t0 = time.perf_counter()
+        record_wait("osd.queue", t0, 0.25, trace_id=5)
+        (r,) = _mine(t0)["osd.queue"]
+        assert (r["start"], r["dur"], r["self"], r["trace_id"]) == (
+            t0, 0.25, 0.25, 5)
+
+    def test_stage_table_sums_self_by_name(self):
+        recs = [{"name": "a", "self": 0.010}, {"name": "a", "self": 0.030},
+                {"name": "b", "self": 0.002}]
+        table = stage_table(recs, 4)
+        assert table["a"]["count"] == 2
+        assert table["a"]["self_s"] == pytest.approx(0.040)
+        assert table["a"]["self_ms_per_op"] == pytest.approx(10.0)
+        assert table["b"]["self_ms_per_op"] == pytest.approx(0.5)
+        assert stage_table(recs, 0)["a"]["self_ms_per_op"] is None
+
+    def test_a_fresh_jit_is_logged_with_no_session_live(self):
+        import jax
+        import jax.numpy as jnp
+        assert not tracing.TraceAnnotation.is_enabled()
+        t0 = time.perf_counter()
+        salt = float(time.time())            # a program never built before
+
+        def fresh_program(x):
+            return x * salt + 25
+        jax.jit(fresh_program)(jnp.arange(25)).block_until_ready()
+        t1 = time.perf_counter()
+        compiles = [r for r in span_log(since=t0, until=t1)
+                    if r["name"] == "xla.compile"]
+        assert compiles and all(0 < r["dur"] <= t1 - t0 for r in compiles)
+        assert all(t0 <= r["start"] for r in compiles)
+        assert "jit(fresh_program)" in [r["program"] for r in compiles]
+
+    def test_a_compile_inside_a_span_is_not_the_span_s_self_time(self,
+                                                                  session):
+        import jax
+        import jax.numpy as jnp
+        t0 = time.perf_counter()
+        salt = float(time.time()) + 1.0
+        with span("unit.launch"):
+            jax.jit(lambda x: x - salt)(jnp.arange(26)).block_until_ready()
+        (launch,) = _mine(t0)["unit.launch"]
+        compiled = sum(r["dur"] for r in span_log(since=t0)
+                       if r["name"] == "xla.compile")
+        assert compiled > 0
+        assert launch["self"] == pytest.approx(launch["dur"] - compiled)
+
+    def test_start_and_stop_take_the_python_tracer_off_branch(self,
+                                                              tmp_path):
+        out = tmp_path / "cap"
+        assert start_trace(str(out))
+        # the session is the binding's own, not jax.profiler's default
+        from jax._src.lib import _profiler
+        assert isinstance(tracing._session[0], _profiler.ProfilerSession)
+        assert tracing.TraceAnnotation.is_enabled()
+        with span("osd.op"):
+            with span("store.apply"):
+                time.sleep(0.002)
+        got = stop_trace()
+        assert not tracing.TraceAnnotation.is_enabled()
+        assert got["dir"] == str(out) and got["ops"] == 1
+        assert got["stages"]["store.apply"]["self_ms_per_op"] >= 2.0
+        assert got["stages"]["osd.op"]["count"] == 1
+        assert any(f.endswith(".xplane.pb")
+                   for _, _, files in os.walk(out) for f in files)
+        assert stop_trace() is None          # nothing left to stop
+
+
+# -- live cluster ------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def cluster(tmp_path_factory):
+    from ceph_tpu.osd import ecbackend
+    from ceph_tpu.osd.standalone import StandaloneCluster
+    # the fused device write path, as on a chip (the CPU backend's
+    # native-codec shortcut has no stage, launch or fetch)
+    keep = ecbackend._host_crc_available
+    ecbackend._host_crc_available = lambda: False
+    c = StandaloneCluster(
+        n_osds=4, pg_num=2, cephx=True, secret=os.urandom(32),
+        store="tin", store_dir=str(tmp_path_factory.mktemp("tin")))
+    try:
+        c.wait_for_clean(timeout=40)
+        yield c
+    finally:
+        c.shutdown()
+        ecbackend._host_crc_available = keep
+
+
+@pytest.fixture(scope="module")
+def client(cluster):
+    cl = cluster.client()
+    cl.trace_sample_rate = 0.0       # ids travel, nothing is sampled
+    cl.write({"warm": b"w" * 3000})  # programs compiled, sessions up
+    assert cl.read("warm") == b"w" * 3000
+    return cl
+
+
+def _ec(cluster, key):
+    return sum(int(d.ec_perf.get(key)) for d in cluster.osds.values())
+
+
+class TestLiveSpanLog:
+    def test_no_session_a_write_and_a_read_log_nothing(self, cluster,
+                                                       client):
+        t0 = time.perf_counter()
+        client.write({"dark": b"d" * 3000})
+        assert client.read("dark") == b"d" * 3000
+        assert _mine(t0) == {}
+
+    def test_a_traced_write_leaves_every_write_stage(self, cluster,
+                                                     client, session):
+        t0 = time.perf_counter()
+        launches = _ec(cluster, "fused_write_launches")
+        client.write({"lit": b"l" * 3000})
+        got = _mine(t0)
+        assert _ec(cluster, "fused_write_launches") == launches + 1
+        assert WRITE_STAGES <= set(got), WRITE_STAGES - set(got)
+        assert all(is_span_declared(name) for name in got)
+        for recs in got.values():
+            assert all(-1e-9 <= r["self"] <= r["dur"] + 1e-9 for r in recs)
+        # stage, launch and fetch are the encode span's children
+        (enc,) = got["ecbackend.write.encode"]
+        kids = sum(got[f"ecbackend.write.{k}"][0]["dur"]
+                   for k in ("stage", "launch", "fetch"))
+        assert enc["self"] == pytest.approx(enc["dur"] - kids)
+        # one identifier from the client through the primary to the
+        # replicas' store applies
+        tid = got["client.op"][-1]["trace_id"]
+        assert tid
+        for name in ("osd.queue", "osd.op", "ecbackend.write.fanout"):
+            assert got[name][-1]["trace_id"] == tid, name
+        applies = [r for r in got["store.apply"] if r["trace_id"] == tid]
+        assert len(applies) == 2             # k+m-1 shards are remote
+        # osd.op's stages cover it: the queue wait lies before it
+        (op,) = got["osd.op"]
+        (queue,) = got["osd.queue"]
+        assert queue["start"] + queue["dur"] <= op["start"] + 1e-4
+        assert op["self"] < op["dur"]
+
+    def test_a_traced_read_leaves_every_read_stage(self, cluster, client,
+                                                   session):
+        client.write({"seen": b"s" * 3000})
+        t0 = time.perf_counter()
+        before = {k: _ec(cluster, k) for k in ("verify_launches",
+                                               "verify_bytes")}
+        timed = sum(d.ec_perf.get("verify_time")["count"]
+                    for d in cluster.osds.values())
+        assert client.read("seen") == b"s" * 3000
+        got = _mine(t0)
+        assert READ_STAGES <= set(got), READ_STAGES - set(got)
+        assert all(is_span_declared(name) for name in got)
+        # a healthy read is one verify launch over the k rows it used
+        assert _ec(cluster, "verify_launches") == before["verify_launches"] + 1
+        assert _ec(cluster, "verify_bytes") == before["verify_bytes"] + 3072
+        assert sum(d.ec_perf.get("verify_time")["count"]
+                   for d in cluster.osds.values()) == timed + 1
+        (verify,) = got["ecbackend.read.verify"]
+        kids = sum(got[f"ecbackend.read.verify.{k}"][0]["dur"]
+                   for k in ("stage", "launch", "fetch"))
+        assert verify["self"] == pytest.approx(verify["dur"] - kids)
+        tid = got["client.op"][-1]["trace_id"]
+        assert got["osd.op"][-1]["trace_id"] == tid
+        assert any(r["trace_id"] == tid for r in got["store.read"])
+
+    def test_seal_and_open_feed_their_counters(self, cluster, client):
+        d = next(iter(cluster.osds.values()))
+        before = {k: d.msgr.perf.get(k)["count"]
+                  for k in ("seal_time", "open_time")}
+        client.write({"sealed": b"q" * 3000})
+        client.read("sealed")
+        total = {k: sum(x.msgr.perf.get(k)["count"]
+                        for x in cluster.osds.values())
+                 for k in before}
+        assert all(total[k] > before[k] for k in before)
+
+    def test_trace_start_stop_over_the_admin_socket(self, cluster, client,
+                                                    tmp_path):
+        from ceph_tpu.utils.admin_socket import admin_command
+        d = next(iter(cluster.osds.values()))
+        out = str(tmp_path / "asok-capture")
+        got = admin_command(cluster.asok_path(d.name), f"trace start {out}")
+        assert got == {"started": True, "dir": out}
+        client.write({"by-operator": b"o" * 3000})
+        got = admin_command(cluster.asok_path(d.name), "trace stop")
+        assert got["stopped"] is True and got["dir"] == out
+        assert got["ops"] >= 1
+        assert got["stages"]["osd.op"]["count"] == got["ops"]
+        assert got["stages"]["ecbackend.write.fanout"]["self_ms_per_op"] > 0
+        again = admin_command(cluster.asok_path(d.name), "trace stop")
+        assert again == {"stopped": False}

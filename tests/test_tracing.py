@@ -260,7 +260,10 @@ class TestLiveTracing:
         def assembled():
             for m in cluster.mons:
                 a = m.traces.assemble(tid)
-                if a["found"] and len(a["daemons"]) >= 2:
+                # the daemons' reports can reach a monitor before the
+                # client's flush does: wait for both
+                if a["found"] and len(a["daemons"]) >= 2 and any(
+                        d.startswith("client.") for d in a["daemons"]):
                     return a
             return None
         asm = _wait_for(assembled, 30, "trace assembled on a monitor")
@@ -277,15 +280,19 @@ class TestLiveTracing:
         # a WRITE trace from the primary's ring covers >= 3 daemons
         # (client + primary + replica store applies). mon.0
         # specifically: ceph_cli's live mode asks it first.
-        wide = _wait_for(
-            lambda: next(
-                (cluster.mons[0].traces.assemble(t["trace_id"])
-                 for t in cluster.mons[0].traces.list_traces()
-                 if len(t["daemons"]) >= 3), None),
-            30, "a >=3-daemon trace assembled")
+        # (each daemon reports on its own: wait until the primary's
+        # and a replica's spans are both in)
+        def wide_trace():
+            for t in cluster.mons[0].traces.list_traces():
+                if len(t["daemons"]) >= 3:
+                    a = cluster.mons[0].traces.assemble(t["trace_id"])
+                    if {"osd.queue", "osd.subop"} <= {
+                            s["name"] for s in a["spans"]}:
+                        return a
+            return None
+        wide = _wait_for(wide_trace, 30, "a >=3-daemon trace assembled")
         assert len(wide["daemons"]) >= 3
         wide_names = {s["name"] for s in wide["spans"]}
-        assert {"osd.queue", "osd.subop"} <= wide_names
         assert ("ecbackend.write.encode" in wide_names
                 or "ecbackend.read.decode" in wide_names
                 or "msgr.seal" in wide_names)
@@ -431,25 +438,42 @@ class TestLiveTracing:
         assert aged[s_slow] == base[s_slow]
         d._client_lat.clear()
 
-    def test_off_sample_ops_record_nothing(self, cluster, client):
+    def test_off_sample_ops_record_nothing(self, cluster, client,
+                                           monkeypatch):
         """The overhead-guard property in miniature: at sample rate 0
-        (contexts stamped, never sampled) no NEW spans are recorded
-        anywhere for a fast op."""
+        (contexts stamped, never sampled) a fast op records no span
+        anywhere under the trace ids it carried. Keyed by those ids:
+        under load a recovery round (sampled at its own rate) or
+        another test's trace can land spans in the rings meanwhile."""
+        live = [d for d in cluster.osds.values() if not d._stop.is_set()]
+        # the retro test's complaint time is taken back daemon by
+        # daemon: until then every op is "slow" and records retro.*
+        _wait_for(lambda: all(d.op_tracker.complaint_time > 1.0
+                              for d in live),
+                  20, "complaint time restored")
+        handed = []
+        make_ctx = client._make_trace_ctx
+
+        def spy(force: bool = False):
+            ctx = make_ctx(force)
+            handed.append(ctx)
+            return ctx
+        monkeypatch.setattr(client, "_make_trace_ctx", spy)
         client.trace_sample_rate = 0.0
         try:
-            before = {d.name: d.flight.dump()["recorded"]
-                      for d in cluster.osds.values()
-                      if not d._stop.is_set()}
             client.write({"offsample": b"x" * 512})
             assert client.read("offsample") == b"x" * 512
-            after = {d.name: d.flight.dump()["recorded"]
-                     for d in cluster.osds.values()
-                     if not d._stop.is_set()}
-            # recovery rounds may trace independently; client ops must
-            # not have added spans (no recovery is running here)
-            assert after == before
         finally:
             client.trace_sample_rate = 1.0
+        assert handed and not any(ctx.sampled for ctx in handed)
+        mine = {f"{ctx.trace_id:016x}" for ctx in handed}
+        rings = [d.flight for d in live] + [client.flight]
+        recorded = {
+            ring.daemon: [(s["name"], s["dur"])
+                          for s in ring.dump()["spans"]
+                          if s["trace_id"] in mine]
+            for ring in rings}
+        assert not any(recorded.values()), recorded
 
 
 @pytest.mark.slow

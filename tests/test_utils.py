@@ -361,16 +361,6 @@ class TestPrometheusExport:
 
 
 class TestTracing:
-    def test_annotation_import_memoized(self):
-        """The jax.profiler import resolves ONCE at module level (the
-        per-span try/import was measurable on the msgr hot path)."""
-        from ceph_tpu.utils import tracing
-        tracing._annotation("warm")           # resolve
-        assert tracing._TRACE_ANNOTATION is not False
-        resolved = tracing._TRACE_ANNOTATION
-        tracing._annotation("again")
-        assert tracing._TRACE_ANNOTATION is resolved
-
     def test_span_noop_and_counter(self):
         from ceph_tpu.utils.perf_counters import PerfCountersBuilder
         from ceph_tpu.utils.tracing import span
