@@ -10,15 +10,25 @@ Unit of work: (batch, block_len) uint8 — many equal-sized blocks checked
 in one launch (exactly the Checksummer csum_block_size model).
 
 crc32c lowering: CRC is GF(2)-linear in the message, so instead of the
-CPU's serial byte loop we
-  1. compute the 8-byte chunk CRCs of all chunks in parallel
-     (slicing-by-8 tables as vectorized gathers),
-  2. reduce across the chunk axis in log2(n) levels; the "advance
-     register by S zero bytes" operator of each level is a constant
-     32x32 GF(2) matrix applied as 32 masked-XOR ops on uint32 lanes,
-  3. fold in the (static) init/xorout contribution as host constants.
-No per-byte dependency chain remains — wall time scales with the VPU,
-not the byte count.
+CPU's serial byte loop the whole program is linear maps on uint32 lanes
+  1. chunk stage: a row is cut into 8 contiguous byte planes and lane j
+     takes one byte of each. A crc table is linear in its index
+     (T[b] = XOR_k bit_k(b) * T[1 << k]: b as a register, advanced
+     through one zero byte), so a byte's contribution is 8 masked XORs
+     of constants, the first 8 columns of a shift matrix — VPU work.
+     (The table lookup it
+     replaces, jnp.take with ~1 M indices, cost 8.6 ms a plane on a
+     v5e: 69 of the 71 ms of a served write's launch.)
+  2. combine: crc(A || B) = shift_{|B|}(crc A) ^ crc(B) in any
+     grouping, so the first half of the lanes is folded onto the
+     second, log2(n) times; each level's "advance the register by h
+     zero bytes" is a constant 32x32 GF(2) matrix, 32 masked XORs.
+     Halves are contiguous slices of the long minor axis (folding
+     neighbours instead de-interleaves it with stride 2 at every
+     level: 13x the bytes moved),
+  3. the (static) init/xorout contribution is a host constant.
+No gather, no transpose and no per-byte dependency chain: wall time
+scales with the VPU, not the byte count.
 
 xxhash is NOT linear (mod-2^32/64 mul/add/rot), so it keeps its stripe
 recurrence: lax.fori_loop over 16/32-byte stripes, batch-parallel.
@@ -34,22 +44,19 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .reference import (apply_shift, crc32c_slice8_tables, crc32c_table,
-                        inv_shift_matrix, matrix_cols_u32, shift_matrix)
+from .reference import (apply_shift, inv_shift_matrix, matrix_cols_u32,
+                        shift_matrix)
 
 Array = jax.Array
 
-# host tables; they become device constants inside the traced programs,
-# so importing this module creates no backend
-_SLICE8 = crc32c_slice8_tables()  # (8, 256) uint32
-_T0 = crc32c_table()              # (256,) uint32
-
 
 def _apply_bitmatrix32(cols: np.ndarray, x: Array) -> Array:
-    """y = M @ x over GF(2), M given as 32 uint32 column constants."""
+    """y = M @ x over GF(2): M given as uint32 column constants, one
+    for each low bit of the uint32 lanes of x (32 for a register, the
+    first 8 for a widened byte)."""
     acc = jnp.zeros_like(x)
-    for b in range(32):
-        c = int(cols[b])
+    for b, c in enumerate(cols):
+        c = int(c)
         if c == 0:
             continue
         mask = jnp.uint32(0) - ((x >> np.uint32(b)) & np.uint32(1))
@@ -60,65 +67,56 @@ def _apply_bitmatrix32(cols: np.ndarray, x: Array) -> Array:
 def _crc32c_linear(blocks: Array) -> Array:
     """Zero-init CRC register over each row of (..., L) uint8, L % 8 == 0.
 
-    Layout is sized for the TPU tiling: the chunk axis (long) stays
-    minor through the widening and the gathers — byte planes
-    (8, ..., n), not (..., n, 8) int32, whose minor dim of 8 padded
-    every tile 16x (3 GiB of scratch for 5.5 MiB of rows at 512 KiB
-    shards)."""
+    A byte is a register with its low 8 bits set (T[b] is b advanced
+    through one zero byte), so a row's register is
+    XOR_p shift^{L-p}(byte_p) over its byte positions p, in any
+    grouping. The grouping here is the one the TPU tiling likes: the
+    long axis stays minor from the first op to the last, and is only
+    ever cut into contiguous runs. Byte planes are the 8 contiguous
+    eighths of a row, (..., n) each — never (..., n, 8), whose minor
+    dim of 8 padded every tile 16x (3 GiB of scratch for 5.5 MiB of
+    rows at 512 KiB shards), and never a transpose. Lane j of the
+    chunk stage holds bytes j, n + j, ..., 7n + j; the combine then
+    folds the first half of the lanes onto the second until one is
+    left, the lanes one byte apart."""
     lead, n = blocks.shape[:-1], blocks.shape[-1] // 8
-    planes = jnp.moveaxis(blocks.reshape(lead + (n, 8)), -1, 0)
-    # chunk CRC: XOR_i T[7-i][byte_i]  (slicing-by-8, zero-init)
     c = jnp.zeros(lead + (n,), dtype=jnp.uint32)
     for i in range(8):
-        c = c ^ jnp.take(jnp.asarray(_SLICE8[7 - i]),
-                         planes[i].astype(jnp.int32), axis=0)
-    # log-depth combine; pad FRONT with zero chunks (zero-init register
-    # stays 0 through a zero prefix, so the result is unchanged)
-    span = 8
+        plane = blocks[..., i * n:(i + 1) * n].astype(jnp.uint32)
+        cols = matrix_cols_u32(shift_matrix((7 - i) * n + 1))
+        c = c ^ _apply_bitmatrix32(cols[:8], plane)
+    # an odd lane count is padded at the FRONT with a zero lane (a zero
+    # register stays 0 through a zero prefix, so nothing changes)
     while c.shape[-1] > 1:
-        m = c.shape[-1]
-        if m % 2:
+        if c.shape[-1] % 2:
             c = jnp.concatenate(
                 [jnp.zeros(lead + (1,), dtype=jnp.uint32), c], axis=-1)
-            m += 1
-        pairs = c.reshape(lead + (m // 2, 2))
-        cols = matrix_cols_u32(shift_matrix(span))
-        c = _apply_bitmatrix32(cols, pairs[..., 0]) ^ pairs[..., 1]
-        span *= 2
+        h = c.shape[-1] // 2
+        cols = matrix_cols_u32(shift_matrix(h))
+        c = _apply_bitmatrix32(cols, c[..., :h]) ^ c[..., h:]
     return c[..., 0]
 
 
 def _crc32c_zero_seed(blocks: Array) -> Array:
-    """Zero-seed CRC register over each row of (..., R, L) uint8, any L:
-    parallel slicing + log-depth combine for the 8-aligned head, <=7
-    unrolled byte steps for the tail.
-
-    Three things keep the TPU program small and its compile to seconds
-    at 512 KiB rows, whatever the caller's row count (sized by
-    compiling for a described v5e, tests/test_tpu_compile.py):
-    the rows are materialised first (fused into the gathers, the
-    recovery decode took the planes' layout and 1 GiB of scratch per
-    4 MiB object); leading dims are kept, never merged into R (the
-    relayout of (16, 11, L) to (176, L) alone compiled for 55 s); and R
-    is zero-padded to a multiple of 8, 16 at least (11 rows compiled
-    for 62 s, 8 for 9 s, 16 for 3 s)."""
-    n_rows, block_len = blocks.shape[-2:]
-    row_pad = max(16, n_rows + (-n_rows % 8)) - n_rows
-    if row_pad:
-        blocks = jnp.pad(blocks, [(0, 0)] * (blocks.ndim - 2)
-                         + [(0, row_pad), (0, 0)])
-    blocks = jax.lax.optimization_barrier(blocks)
-    main = (block_len // 8) * 8
-    if main:
-        reg = _crc32c_linear(blocks[..., :main])
-    else:
-        reg = jnp.zeros(blocks.shape[:-1], dtype=jnp.uint32)
-    t0 = jnp.asarray(_T0)
-    for t in range(main, block_len):
-        byte = blocks[..., t].astype(jnp.uint32)
-        reg = (reg >> np.uint32(8)) ^ jnp.take(
-            t0, ((reg ^ byte) & np.uint32(0xFF)).astype(jnp.int32))
-    return reg[..., :n_rows]
+    """Zero-seed CRC register over each row of (..., R, L) uint8, any L.
+    A length that is no multiple of 8 is zero-padded at the front (the
+    register passes a zero prefix unchanged). Leading dims are kept,
+    never merged into R (the relayout of (16, 11, L) to (176, L) alone
+    compiled for 55 s), and walked one (R, L) object at a time:
+    traced over all objects at once the program asks for more scratch
+    than the rows it reads (sized by compiling for a described v5e,
+    tests/test_tpu_compile.py)."""
+    block_len = blocks.shape[-1]
+    if block_len == 0:
+        return jnp.zeros(blocks.shape[:-1], dtype=jnp.uint32)
+    if block_len % 8:
+        blocks = jnp.pad(blocks, [(0, 0)] * (blocks.ndim - 1)
+                         + [(-block_len % 8, 0)])
+    if blocks.ndim == 2:
+        return _crc32c_linear(blocks)
+    objects = blocks.reshape((int(np.prod(blocks.shape[:-2])),)
+                             + blocks.shape[-2:])
+    return jax.lax.map(_crc32c_linear, objects).reshape(blocks.shape[:-1])
 
 
 @functools.lru_cache(maxsize=64)

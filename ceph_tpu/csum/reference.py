@@ -15,7 +15,7 @@ extensions). Two conventions are exposed:
 xxh32 / xxh64: XXHash as bundled by the reference (src/xxHash/), needed
 for BlueStore csum_type=xxhash32/64 parity.
 
-Everything that the device kernels close over (slicing tables, GF(2)
+Everything that the device kernels close over (the byte table, GF(2)
 shift matrices for the log-depth CRC combine) is built here once.
 """
 
@@ -49,9 +49,11 @@ def crc32c_slice8_tables() -> np.ndarray:
 
     T[0] is the basic table; T[j+1][v] advances T[j][v] through one more
     zero byte. With a zero initial register, the CRC register after 8
-    bytes b0..b7 is XOR_i T[7-i][b_i] — the byte-parallel form the device
-    kernel uses (same math as the reference's sctp_crc32 slicing fallback
-    and the PCLMUL folding constants, ref: src/common/crc32c_intel_fast_asm.s).
+    bytes b0..b7 is XOR_i T[7-i][b_i] — the byte-parallel table form
+    (same math as the reference's sctp_crc32 slicing fallback and the
+    PCLMUL folding constants, ref: src/common/crc32c_intel_fast_asm.s).
+    The device kernel (csum/kernels) applies the same linear maps bit by
+    bit instead of by lookup: a table gather is slow on a TPU.
     """
     t0 = crc32c_table()
     out = np.zeros((8, 256), dtype=np.uint32)

@@ -1914,8 +1914,9 @@ def _host_encoder_handle(matrix_bytes: bytes, k: int, m: int):
 @_functools.lru_cache(maxsize=1)
 def _host_crc_available() -> bool:
     """Host-integrity mode: on the CPU backend with the native SSE4.2
-    crc32c built, checksums run ~20x faster as host instructions than
-    as gather-bound XLA programs — the device then runs DECODE ONLY
+    crc32c built, checksums run faster as host instructions than as
+    XLA programs (~20x against the table-gather program; the bit-linear
+    one is not measured there) — the device then runs DECODE ONLY
     (plus the helper XOR-fold) and integrity moves off the launch.
     On a real accelerator the device checksum is nearly free and the
     host would serialize, so this stays device-side there."""

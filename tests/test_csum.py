@@ -57,35 +57,69 @@ class TestCephConvention:
             assert apply_shift(r, n) == ceph_crc32c(r, bytes(n))
 
 
-@pytest.mark.parametrize("length", [0, 1, 5, 8, 16, 63, 64, 100, 4096, 4099])
+def _registers(seed, rows):
+    """ceph_crc32c(seed, row) of every row of (..., L), leading dims kept."""
+    flat = rows.reshape(int(np.prod(rows.shape[:-1])), rows.shape[-1])
+    return np.array([ceph_crc32c(seed, row.tobytes()) for row in flat],
+                    dtype=np.uint32).reshape(rows.shape[:-1])
+
+
+# 8*3, 8*37, 8*1000 + 5 and 65536 + 8: chunk counts that are odd at some
+# level of the halving combine (and so take its front pad)
+@pytest.mark.parametrize("length", [0, 1, 5, 8, 16, 63, 64, 100, 4096, 4099,
+                                    8 * 3, 8 * 37, 8 * 1000 + 5, 65536 + 8])
 def test_crc32c_kernel_matches_oracle(length):
     rng = np.random.default_rng(length)
     data = rng.integers(0, 256, size=(5, length), dtype=np.uint8)
     got = np.asarray(crc32c_blocks(data))
     want = np.array([crc32c(row.tobytes()) for row in data], dtype=np.uint32)
     np.testing.assert_array_equal(got, want)
-    # ceph raw-register convention
-    got = np.asarray(crc32c_blocks(data, init=0xFFFFFFFF, xorout=0))
-    want = np.array([ceph_crc32c(0xFFFFFFFF, row.tobytes()) for row in data],
-                    dtype=np.uint32)
-    np.testing.assert_array_equal(got, want)
+    # ceph raw-register convention, seed -1 and seed 0 (the parity-delta
+    # path's)
+    for seed in (0xFFFFFFFF, 0):
+        got = np.asarray(crc32c_blocks(data, init=seed, xorout=0))
+        np.testing.assert_array_equal(got, _registers(seed, data))
 
 
 @pytest.mark.parametrize("length", [8, 4096, 65536, 524288 + 3])
 def test_crc32c_kernel_row_layouts_match_oracle(length):
-    """The layout the TPU needs (byte planes, rows padded to 16, leading
-    dims kept) at the served path's row counts: the 11 rows of one
+    """The layout the TPU needs (contiguous byte planes, leading dims
+    kept) at the served path's row counts: the 11 rows of one
     k=8 m=3 object, and a (batch, rebuilt rows, L) recovery stack. The
-    longest length takes the tail path at a 512 KiB shard."""
+    longest length takes the unaligned path at a 512 KiB shard."""
     rng = np.random.default_rng(length)
     rows = rng.integers(0, 256, size=(11, length), dtype=np.uint8)
-    want = np.array([ceph_crc32c(0xFFFFFFFF, row.tobytes()) for row in rows],
-                    dtype=np.uint32)
+    want = _registers(0xFFFFFFFF, rows)
     got = np.asarray(crc32c_blocks(rows, init=0xFFFFFFFF, xorout=0))
     np.testing.assert_array_equal(got, want)
     stack = rows[:6].reshape(3, 2, length)
     got = np.asarray(crc32c_blocks(stack, init=0xFFFFFFFF, xorout=0))
     np.testing.assert_array_equal(got, want[:6].reshape(3, 2))
+
+
+@pytest.mark.parametrize("n_rows", [1, 8, 11, 16, 17])
+@pytest.mark.parametrize("init, xorout", [(0xFFFFFFFF, 0xFFFFFFFF),
+                                          (0x1234ABCD, 0), (0, 0)])
+def test_crc32c_kernel_row_counts_match_oracle(n_rows, init, xorout):
+    """Row counts on both sides of the tile heights (8 and 16 rows),
+    in both conventions, at a length (8 * 37) whose lane count is odd
+    at four of the combine's six levels."""
+    rng = np.random.default_rng(n_rows)
+    rows = rng.integers(0, 256, size=(n_rows, 8 * 37), dtype=np.uint8)
+    got = np.asarray(crc32c_blocks(rows, init=init, xorout=xorout))
+    np.testing.assert_array_equal(got, _registers(init, rows) ^ xorout)
+
+
+@pytest.mark.parametrize("lead", [(2,), (16,), (2, 3)])
+@pytest.mark.parametrize("length", [8 * 37, 4096, 8 * 100 + 5])
+def test_crc32c_kernel_keeps_leading_dims(lead, length):
+    """(objects, 11 rows, L) stacks, as the batched fused write and the
+    recover program send them: one value a row, leading dims kept,
+    every object its own bytes."""
+    rng = np.random.default_rng(length + len(lead))
+    stack = rng.integers(0, 256, size=lead + (11, length), dtype=np.uint8)
+    got = np.asarray(crc32c_blocks(stack, init=0xFFFFFFFF, xorout=0))
+    np.testing.assert_array_equal(got, _registers(0xFFFFFFFF, stack))
 
 
 @pytest.mark.parametrize("length", [0, 1, 3, 4, 15, 16, 17, 31, 32, 33, 100,
@@ -166,15 +200,22 @@ class TestCrc32cExtend:
             want = [ceph_crc32c(int(r), b) for r, b in zip(regs, blocks)]
             assert got.tolist() == want, L
 
-    def test_chaining(self):
+    # (37, 91): neither is a bucket; (64, 4096): both are; (256, 8 * 37)
+    # and (8 * 1000 + 5, 128): one of each, either way round
+    @pytest.mark.parametrize("len_a, len_b", [(37, 91), (64, 4096),
+                                              (256, 8 * 37),
+                                              (8 * 1000 + 5, 128)])
+    def test_chaining(self, len_a, len_b):
         import numpy as np
         from ceph_tpu.csum.kernels import crc32c_extend
         from ceph_tpu.csum.reference import ceph_crc32c
         rng = np.random.default_rng(12)
-        a = rng.integers(0, 256, size=(2, 37), dtype=np.uint8)
-        b = rng.integers(0, 256, size=(2, 91), dtype=np.uint8)
-        regs = np.full(2, 0xFFFFFFFF, np.uint32)
+        a = rng.integers(0, 256, size=(2, len_a), dtype=np.uint8)
+        b = rng.integers(0, 256, size=(2, len_b), dtype=np.uint8)
+        regs = np.array([0xFFFFFFFF, 0x1234ABCD], np.uint32)
         step = crc32c_extend(crc32c_extend(regs, a), b)
-        whole = [ceph_crc32c(0xFFFFFFFF, np.concatenate([a[i], b[i]]))
+        joined = crc32c_extend(regs, np.concatenate([a, b], axis=1))
+        whole = [ceph_crc32c(int(regs[i]), np.concatenate([a[i], b[i]]))
                  for i in range(2)]
         assert np.asarray(step).tolist() == whole
+        assert np.asarray(joined).tolist() == whole

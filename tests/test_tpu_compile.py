@@ -73,23 +73,44 @@ def test_pallas_kernel_compiles_without_interpret(one_chip, coder):
     assert out.memory_analysis().temp_size_in_bytes < 16 * MiB
 
 
+def _no_gather(compiled):
+    """What PR 26 bought: the crc's chunk stage is masked XORs on the
+    VPU, not table gathers (8.6 ms each for 1 M indices on the chip)."""
+    return " gather(" not in compiled.as_text()
+
+
 def test_crc32c_rows_of_one_object_fit(one_chip):
-    """The defect this guards: 11 rows x 512 KiB took 3,168 MiB of
-    scratch (~580x the input) and a minute to compile."""
+    """The defects this guards: 11 rows x 512 KiB took 3,168 MiB of
+    scratch (~580x the input) and a minute to compile (PR 22); then
+    41 MiB and eight gathers (PR 26 read 0 MiB and none)."""
     from ceph_tpu.csum.kernels import crc32c_blocks
     out = _compile(lambda b: crc32c_blocks(b, init=0xFFFFFFFF, xorout=0),
                    one_chip, ((K + M, SHARD), np.uint8))
-    assert out.memory_analysis().temp_size_in_bytes \
-        < 16 * (K + M) * SHARD
+    assert _no_gather(out)
+    assert out.memory_analysis().temp_size_in_bytes < 4 * MiB
+
+
+def _fused_write(one_chip, coder, bucket):
+    from ceph_tpu.osd.ecbackend import ECBackend
+    fn = ECBackend._fused_write_fn(coder.matrix.tobytes(), M, K,
+                                   coder.impl, SHARD, bucket)
+    return _compile(fn, one_chip, ((bucket, K, SHARD), np.uint8))
+
+
+def test_fused_write_compiles_at_bucket_1(one_chip, coder):
+    """What a served write launches. With the gathers it touched
+    37.2 GB by the compiler's count; PR 26 read 0.35 GB."""
+    out = _fused_write(one_chip, coder, 1)
+    assert _no_gather(out)
+    assert out.cost_analysis()["bytes accessed"] < 2e9
 
 
 def test_fused_write_compiles_at_bucket_16(one_chip, coder):
-    from ceph_tpu.osd.ecbackend import ECBackend
-    fn = ECBackend._fused_write_fn(coder.matrix.tobytes(), M, K,
-                                   coder.impl, SHARD, 16)
-    out = _compile(fn, one_chip, ((16, K, SHARD), np.uint8))
-    # 64 MiB in, 88 MiB of rows to checksum
-    assert out.memory_analysis().temp_size_in_bytes < 2048 * MiB
+    out = _fused_write(one_chip, coder, 16)
+    assert _no_gather(out)
+    # 64 MiB in, 88 MiB of rows to checksum; 1,174 MiB is what the
+    # gathers asked for: the program after them may not ask for more
+    assert out.memory_analysis().temp_size_in_bytes < 1174 * MiB
 
 
 def test_recover_program_compiles_at_the_staged_batch(one_chip, coder):
@@ -106,4 +127,6 @@ def test_recover_program_compiles_at_the_staged_batch(one_chip, coder):
                                 verify=True, host_crc=False)
     out = _compile(fn, one_chip, ((batch, K, SHARD), np.uint8),
                    ((batch,), np.uint32))
-    assert out.memory_analysis().temp_size_in_bytes < 2048 * MiB
+    assert _no_gather(out)
+    # 1,687 MiB with the gathers (PR 26 read 1,010)
+    assert out.memory_analysis().temp_size_in_bytes < 1687 * MiB
