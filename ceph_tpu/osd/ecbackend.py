@@ -71,7 +71,19 @@ def ec_perf_counters():
                              "backend only — bit-identical to the "
                              "fused device launch)")
             .add_u64_counter("decode_launches",
-                             "read-path decode launches")
+                             "read-path decode calls, a healthy "
+                             "read's pass-through among them")
+            .add_u64_counter("degraded_reads",
+                             "objects read that rebuilt at least one "
+                             "wanted row")
+            .add_u64_counter("decode_rows_rebuilt",
+                             "wanted rows rebuilt by read-path "
+                             "decodes (rows x objects)")
+            .add_u64_counter("decode_bytes_rebuilt",
+                             "bytes of those rebuilt rows")
+            .add_u64_counter("host_decode_launches",
+                             "read-path rebuilds computed on the "
+                             "host (a codec's impl=ref numpy oracle)")
             .add_u64_counter("recover_launches",
                              "fused recovery launches")
             .add_u64_counter("program_cache_hits",
@@ -234,6 +246,7 @@ class ECBackend(PGBackend):
         self._init_common(pg, acting, cluster or ShardSet(),
                           ensure_collections=ensure_collections)
         self._fused_cache: dict = {}
+        self._read_plans: dict = {}      # survivors -> (rows, family)
         # partial-stripe RMW state: per-PG stripe-journal sequence
         # (replay re-anchors it past every seq seen on disk) and the
         # crash hook the phase-boundary tests drive (None in prod)
@@ -1361,7 +1374,9 @@ class ECBackend(PGBackend):
         (plan_read): an LRC single-shard loss pulls its local group
         instead of any-k, and `helper_costs` (slot -> cost) biases
         which survivors serve (the daemon's complaint/latency
-        memory)."""
+        memory) at the first read a set of survivors serves: the
+        rows then stay the same (`_read_plan`), so a degraded PG meets
+        one decode program, not one for every order of the costs."""
         dead = dead_osds or set()
         alive = [s for s in range(self.n)
                  if self.acting[s] not in dead]
@@ -1383,8 +1398,7 @@ class ECBackend(PGBackend):
             while True:
                 # the planner raises when the survivors can't cover
                 # `want` — the caller's retry boundary
-                need_set, family = plan_read(self.coder, want, avail,
-                                             costs=helper_costs)
+                need_set, family = self._read_plan(avail, helper_costs)
                 if family != "direct":
                     self._count_plan(family)
                 need = sorted(need_set)
@@ -1439,7 +1453,7 @@ class ECBackend(PGBackend):
                       len(clean_group) * len(need) * sl)))
                 with span("ecbackend.read.decode", counters=self.perf,
                           key="decode_time"):
-                    rec = self.coder.decode(want, sub)
+                    rec = self._decode_rows(want, sub, sl)
                 with span("ecbackend.read.unstripe"):
                     shards = np.stack([rec[s] for s in self.data_slots],
                                       axis=1)
@@ -1452,6 +1466,60 @@ class ECBackend(PGBackend):
                 out[name] = self._read_eio(name, sl, avail, bad_set,
                                            repair=repair)
         return out
+
+    def _read_plan(self, avail: list[int],
+                   costs: dict[int, int] | None) -> tuple[set[int], str]:
+        """`plan_read` for the data rows, decided once for each set of
+        survivors. A decode program is compiled for each (lost rows,
+        helper rows) pattern, and the costs are latencies that change
+        from read to read: they choose among equal helpers when the
+        plan is made, and a plan that is made stands until the
+        survivors change."""
+        key = tuple(avail)
+        plan = self._read_plans.get(key)
+        if plan is None:
+            if len(self._read_plans) >= 64:      # survivors came and
+                self._read_plans.clear()         # went: start again
+            plan = self._read_plans[key] = plan_read(
+                self.coder, self.data_slots, avail, costs=costs)
+        return plan
+
+    def _decode_rows(self, want: list[int], rows: dict[int, np.ndarray],
+                     sl: int) -> dict[int, np.ndarray]:
+        """The `want` rows of a (B, sl) stack a slot: those gathered
+        pass through, the rest are rebuilt from the gathered rows, on
+        the device where the codec has a program for the pattern
+        (stage: the helper rows stacked; launch: the dispatch with its
+        copy to the device; fetch: the wait for the rebuilt rows)."""
+        from ..ec.rs import ReedSolomon
+        lost = [s for s in want if s not in rows]
+        if not lost:
+            return rows
+        # a static decode matrix takes the first k rows there are
+        helpers = sorted(rows)[:self.k]
+        n_obj = len(rows[helpers[0]])
+        fn = self.coder.batch_decoder(lost, helpers) \
+            if isinstance(self.coder, ReedSolomon) else None
+        if fn is None:
+            # the codec's own decode (LRC's layers, Clay's coupled
+            # planes, SHEC's windows): device programs of its making,
+            # but for impl=ref, the numpy oracle
+            if getattr(self.coder, "impl", None) == "ref":
+                self.perf.inc("host_decode_launches")
+            rebuilt = self.coder.decode_chunks(lost, rows)
+        else:
+            with span("ecbackend.read.decode.stage"):
+                stack = np.stack([rows[s] for s in helpers], axis=1)
+            with span("ecbackend.read.decode.launch"):
+                out_d = fn(stack)
+            with span("ecbackend.read.decode.fetch"):
+                out = np.asarray(out_d)
+            rebuilt = {s: out[:, i] for i, s in enumerate(lost)}
+        self.perf.inc_many(
+            (("degraded_reads", n_obj),
+             ("decode_rows_rebuilt", len(lost) * n_obj),
+             ("decode_bytes_rebuilt", len(lost) * n_obj * sl)))
+        return {**rows, **rebuilt}
 
     def _read_eio(self, name: str, sl: int, avail: list[int],
                   bad: set[int], repair: bool = True) -> np.ndarray:

@@ -203,5 +203,9 @@ def plan_read(coder, want: Sequence[int], available: Sequence[int],
     missing = want_s - avail
     if not missing:
         return set(want_s), "direct"
+    if costs:
+        # a wanted row that is there is read whatever the plan says:
+        # as a helper it costs nothing more
+        costs = {c: 0 if c in want_s else v for c, v in costs.items()}
     rp = plan_repair(coder, sorted(missing), avail, costs=costs)
     return (want_s & avail) | set(rp.helpers), rp.family

@@ -83,8 +83,9 @@ def _read_all(client, objects: dict[str, bytes], what: str) -> None:
 
 def served_phase(n_objects: int, object_bytes: int) -> dict:
     """12 OSD daemons + 3 monitors in this process, cephx and secure
-    frames, TinStore: write, read, kill an OSD, read degraded, recover
-    to the spare OSD, read again. Returns the daemons' summed counters.
+    frames, TinStore: write, read, kill an OSD and mark it down, read
+    degraded while it is down and in, let it go out, recover to the
+    spare OSD, read again. Returns the daemons' summed counters.
 
     op_timeout stays at the harness default (8 s): it also bounds every
     OSD-to-OSD call, and daemons that wait on each other under their
@@ -134,15 +135,23 @@ def served_phase(n_objects: int, object_bytes: int) -> dict:
             victim = max((o for o in cluster.osd_ids()
                           if o not in primaries),
                          key=lambda o: sum(o in a for a in acting))
+            # down and not yet out: the admin `down` marks the dead
+            # daemon at once (no 45 s of heartbeat grace to read
+            # through), and the interval keeps it in, so every read of
+            # a PG it held a data slot of is rebuilt by the decode
             t0 = time.perf_counter()
+            client.config_set("mon_osd_down_out_interval", 600)
             cluster.kill_osd(victim)
+            client.osd_down(victim)
             _read_all(client, objects, "degraded read")
             phase("served.degraded_read", t0, objects=n_objects,
                   killed_osd=victim,
                   pgs_hit=sum(victim in a for a in acting))
 
+            # back to the harness's interval (0): the monitors' next
+            # tick marks it out, the spare takes its slots, recovery
             t0 = time.perf_counter()
-            cluster.wait_for_down(victim, timeout=120)
+            client.config_rm("mon_osd_down_out_interval")
             cluster.wait_for_clean(timeout=600)
             phase("served.recovery", t0)
             t0 = time.perf_counter()
@@ -153,6 +162,8 @@ def served_phase(n_objects: int, object_bytes: int) -> dict:
                                  for d in cluster.osds.values())
                         for key in ("fused_write_launches",
                                     "recover_launches", "decode_launches",
+                                    "degraded_reads",
+                                    "host_decode_launches",
                                     "host_encode_launches",
                                     "recovered_objects",
                                     "program_cache_misses")}
@@ -166,6 +177,8 @@ def assert_device_did_the_work(counters: dict) -> None:
           flush=True)
     if not (counters["fused_write_launches"] > 0
             and counters["recover_launches"] > 0
+            and counters["degraded_reads"] > 0
+            and counters["host_decode_launches"] == 0
             and counters["host_encode_launches"] == 0):
         raise AssertionError(
             f"the device did not serve the EC path: {counters}")

@@ -8,7 +8,10 @@ write path switched on for the CPU backend as bench/tests does): with
 no session a client write and read log nothing; with one, a write
 leaves every write-path stage and a read every read-path stage, the
 spans of one op share its trace id on the client, its primary and the
-replicas, and the new `ec` verify counters rise.
+replicas, and the new `ec` verify counters rise. A read that rebuilds
+a row (its PG's data-slot holder down and not yet out, PR 27) leaves
+the decode's stage, launch and fetch and feeds `degraded_reads`,
+`decode_rows_rebuilt` and `decode_bytes_rebuilt`: one case each.
 """
 
 import os
@@ -327,3 +330,80 @@ class TestLiveSpanLog:
         assert got["stages"]["ecbackend.write.fanout"]["self_ms_per_op"] > 0
         again = admin_command(cluster.asok_path(d.name), "trace stop")
         assert again == {"stopped": False}
+
+
+# -- a read that rebuilds a row ------------------------------------------------
+
+@pytest.fixture(scope="module")
+def degraded():
+    """(cluster, client, name, bytes) of an object whose PG has lost a
+    data slot: its holder killed, marked down by the admin `down`, and
+    kept in by the interval."""
+    from ceph_tpu.osd.standalone import StandaloneCluster
+    c = StandaloneCluster(n_osds=4, pg_num=2, hb_interval=0.5,
+                          hb_grace=30.0, down_out_interval=600.0)
+    try:
+        c.wait_for_clean(timeout=40)
+        cl = c.client(hedge_delay_ms=-1)
+        cl.trace_sample_rate = 0.0
+        acting = [cl.osdmap.pg_to_up_acting_osds(1, ps)[2] for ps in (0, 1)]
+        primaries = {a[0] for a in acting}
+        ps, victim = next((ps, a[1]) for ps, a in enumerate(acting)
+                          if a[1] not in primaries)   # data slot 1 of k=2
+        name = next(f"lost-{i}" for i in range(64)
+                    if cl.osdmap.object_to_pg(1, f"lost-{i}")[1] == ps)
+        cl.write({name: b"r" * 3000})
+        c.kill_osd(victim)
+        cl.osd_down(victim)
+        c._wait(lambda: all(not d.osdmap.osd_up[victim]
+                            for d in c.osds.values()
+                            if not d._stop.is_set()), 15, "maps show down")
+        assert cl.read(name) == b"r" * 3000   # the pattern's program
+        yield c, cl, name, b"r" * 3000
+    finally:
+        c.shutdown()
+
+
+class TestDegradedReadSpansAndCounters:
+    """One case a span and a counter, beside `verify_time`'s and
+    `open_time`'s above."""
+
+    @pytest.mark.parametrize("stage", ["stage", "launch", "fetch"])
+    def test_a_degraded_read_leaves_the_decode_s_stage(self, degraded,
+                                                       session, stage):
+        _, client, name, want = degraded
+        t0 = time.perf_counter()
+        assert client.read(name) == want
+        got = _mine(t0)
+        kid = f"ecbackend.read.decode.{stage}"
+        assert is_span_declared(kid)
+        (decode,), (rec,) = got["ecbackend.read.decode"], got[kid]
+        assert decode["start"] <= rec["start"]
+        assert rec["start"] + rec["dur"] \
+            <= decode["start"] + decode["dur"] + 1e-6
+        kids = sum(got[f"ecbackend.read.decode.{k}"][0]["dur"]
+                   for k in ("stage", "launch", "fetch"))
+        assert decode["self"] == pytest.approx(decode["dur"] - kids)
+
+    @pytest.mark.parametrize("key,rise", [("degraded_reads", 1),
+                                          ("decode_rows_rebuilt", 1),
+                                          ("decode_bytes_rebuilt", 1536)])
+    def test_a_degraded_read_feeds_its_counter(self, degraded, key, rise):
+        """3000 bytes over k=2 in 256-byte units: one 1536-byte row
+        rebuilt, on the device."""
+        cluster, client, name, want = degraded
+        keys = (key, "decode_launches", "decode_time",
+                "host_decode_launches")
+
+        def read():
+            return {k: sum(d.ec_perf.get(k)["count"] if k == "decode_time"
+                           else int(d.ec_perf.get(k))
+                           for d in cluster.osds.values()
+                           if not d._stop.is_set()) for k in keys}
+        before = read()
+        assert client.read(name) == want
+        after = read()
+        assert after[key] == before[key] + rise
+        assert after["decode_launches"] == before["decode_launches"] + 1
+        assert after["decode_time"] == before["decode_time"] + 1
+        assert after["host_decode_launches"] == before["host_decode_launches"]
