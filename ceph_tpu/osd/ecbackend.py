@@ -119,6 +119,14 @@ def ec_perf_counters():
                           hist=True)
             .add_time_avg("decode_time", "read-path decode wall time",
                           hist=True)
+            .add_u64_counter("gather_rounds",
+                             "read-path gather rounds: one a planned "
+                             "read, one more for each re-plan round a "
+                             "slot that lacks the object")
+            .add_u64_counter("gather_frames",
+                             "readv frames those rounds sent to "
+                             "remote stores (rows and hinfo attrs in "
+                             "one answer)")
             .add_u64_counter("verify_launches",
                              "read-path crc verify device launches")
             .add_u64_counter("verify_bytes",
@@ -1370,6 +1378,14 @@ class ECBackend(PGBackend):
         re-decode but skips the writeback — the read-only contract of
         a degraded-read view served by a non-primary.
 
+        The rows of a plan are gathered in one overlapped round
+        (`_gather`): one `readv` frame a remote slot, each answer
+        carrying its rows' hinfo attrs, all on the wire before any is
+        awaited; the slots the primary holds itself are read in place
+        meanwhile. A slot whose store lacks an object (a re-pointed
+        slot whose rebuild has not landed) is dropped and the read
+        planned again without it.
+
         Degraded reads gather through the repair-locality planner
         (plan_read): an LRC single-shard loss pulls its local group
         instead of any-k, and `helper_costs` (slot -> cost) biases
@@ -1402,25 +1418,16 @@ class ECBackend(PGBackend):
                 if family != "direct":
                     self._count_plan(family)
                 need = sorted(need_set)
-                stacks, missing = {}, None
                 with span("ecbackend.read.gather"):
-                    for s in need:
-                        try:
-                            stacks[s] = np.stack(
-                                [self._store(s).read(
-                                    shard_cid(self.pg, s), n)
-                                 for n in group])
-                        except KeyError:
-                            # cursor says fresh but the store lacks
-                            # the object: a repointed slot whose
-                            # rebuild has not landed this object yet
-                            # (recovery in flight) — plan around it
-                            # like a stale shard
-                            missing = s
-                            break
-                if missing is None:
+                    stacks, hinfos, missing = self._gather(
+                        need, group, sl, verify)
+                if not missing:
                     break
-                avail.remove(missing)
+                # cursor says fresh but the store lacks the object: a
+                # repointed slot whose rebuild has not landed this
+                # object yet (recovery in flight) — plan around it
+                # like a stale shard
+                avail = [s for s in avail if s not in missing]
             bad: dict[str, set[int]] = {}
             if verify:
                 # the read path's device launch: one crc program over
@@ -1433,16 +1440,11 @@ class ECBackend(PGBackend):
                             len(need), len(group))
                 self.perf.inc_many((("verify_launches", 1),
                                     ("verify_bytes", int(rows.size))))
-                with span("ecbackend.read.gather"):   # the stored crcs
-                    for si, s in enumerate(need):
-                        st = self._store(s)
-                        cid = shard_cid(self.pg, s)
-                        for bi, nm in enumerate(group):
-                            hinfo = HashInfo.from_bytes(
-                                st.getattr(cid, nm, HINFO_KEY))
-                            if int(crcs[si, bi]) \
-                                    != hinfo.get_chunk_hash(0):
-                                bad.setdefault(nm, set()).add(s)
+                for si, s in enumerate(need):
+                    for bi, nm in enumerate(group):
+                        stored = HashInfo.from_bytes(hinfos[s][bi])
+                        if int(crcs[si, bi]) != stored.get_chunk_hash(0):
+                            bad.setdefault(nm, set()).add(s)
             clean_group = [n for n in group if n not in bad]
             if clean_group:
                 idx = [group.index(n) for n in clean_group]
@@ -1483,6 +1485,74 @@ class ECBackend(PGBackend):
             plan = self._read_plans[key] = plan_read(
                 self.coder, self.data_slots, avail, costs=costs)
         return plan
+
+    def _gather(self, need: list[int], group: list[str], sl: int,
+                verify: bool) -> tuple[dict, dict, set[int]]:
+        """One round of a read's gather: slot -> (len(group), sl) rows,
+        slot -> the rows' hinfo attrs (with `verify`), and the slots
+        whose store lacks one of the objects. Every remote slot's
+        `readv` frames (rows and attrs in one answer; a group larger
+        than RECOVERY_FETCH_BYTES split, so no frame grows with the
+        group) are on the wire before any answer is awaited or the
+        primary's own slots are read in place, so the round costs the
+        slowest answer, not their sum. A failure other than a missing
+        object surfaces from the first handle that meets it; no handle
+        is left in flight."""
+        stacks: dict[int, np.ndarray] = {}
+        hinfos: dict[int, list[bytes]] = {}
+        missing: set[int] = set()
+        attr_key = HINFO_KEY if verify else None
+        per = max(1, RECOVERY_FETCH_BYTES // sl)
+        parts: dict[int, list[np.ndarray]] = {}
+        handles: list[tuple] = []
+        local: list[int] = []
+        try:
+            for s in need:
+                submit = getattr(self._store(s), "readv_submit", None)
+                if submit is None:
+                    local.append(s)
+                    continue
+                cid = shard_cid(self.pg, s)
+                for c0 in range(0, len(group), per):
+                    names = group[c0:c0 + per]
+                    handles.append((s, len(names),
+                                    submit(cid, names, sl, attr_key)))
+            self.perf.inc_many((("gather_rounds", 1),
+                                ("gather_frames", len(handles))))
+            for s in local:
+                st, cid = self._store(s), shard_cid(self.pg, s)
+                try:
+                    read_batch = getattr(st, "read_batch", None)
+                    stacks[s] = read_batch(cid, group, sl) \
+                        if read_batch is not None else np.stack(
+                            [st.read(cid, n) for n in group])
+                    if verify:
+                        hinfos[s] = [st.getattr(cid, n, HINFO_KEY)
+                                     for n in group]
+                except KeyError:
+                    missing.add(s)
+            for s, nb, handle in handles:
+                try:
+                    data, attrs = handle.result()
+                except KeyError:
+                    missing.add(s)
+                    continue
+                rows = np.frombuffer(data, np.uint8)
+                if rows.size != nb * sl:
+                    raise ValueError(
+                        f"readv: got {rows.size} bytes, "
+                        f"expected {nb * sl}")
+                parts.setdefault(s, []).append(rows.reshape(nb, sl))
+                if verify:
+                    hinfos.setdefault(s, []).extend(attrs)
+        except BaseException:
+            # what this round still has on the wire is nobody's now
+            for _s, _nb, handle in handles:
+                handle.cancel()
+            raise
+        for s, got in parts.items():
+            stacks[s] = got[0] if len(got) == 1 else np.concatenate(got)
+        return stacks, hinfos, missing
 
     def _decode_rows(self, want: list[int], rows: dict[int, np.ndarray],
                      sl: int) -> dict[int, np.ndarray]:

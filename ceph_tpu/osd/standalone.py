@@ -658,7 +658,7 @@ class _PendingCall:
     contract, split so callers can have MANY of these on the wire."""
 
     __slots__ = ("_rpc", "rid", "peer", "nbytes", "_ev", "_replies",
-                 "_released", "_waiters")
+                 "_released", "_waiters", "t_done")
 
     def __init__(self, rpc: "_Rpc", rid: int, peer: str, nbytes: int):
         self._rpc = rpc
@@ -715,6 +715,9 @@ class _PendingCall:
             ev.set()
 
     def _notify(self) -> None:
+        # when the reply (or the transport error) landed, not when a
+        # waiter got round to it: a pipelined caller collects late
+        self.t_done = time.perf_counter()
         self._ev.set()
         for ev in self._waiters:
             ev.set()
@@ -865,15 +868,28 @@ class _Rpc:
 class _AsyncStoreOp:
     """In-flight MStoreOp with the same error surface as
     RemoteStore._call: result() maps the reply like the sync path,
-    including the one cephx re-authorize retry on a cold session."""
+    including the one cephx re-authorize retry on a cold session.
+    `timed` reports the first round trip, submit to the reply's
+    arrival, to the store's on_latency as _call does (the reads are
+    what feeds a daemon's peer-latency EWMA; the write fan-out's
+    commits never did)."""
 
-    def __init__(self, rs: "RemoteStore", kind: str, body: bytes):
+    def __init__(self, rs: "RemoteStore", kind: str, body: bytes,
+                 timed: bool = False):
         self._rs, self._kind, self._body = rs, kind, body
+        self._t0 = time.perf_counter() if timed else None
         self._pending = rs._submit(kind, body)
+
+    def cancel(self) -> None:
+        """Abandon an op nobody will collect (a sibling of its round
+        failed): its table entry and window slot go now."""
+        self._pending.cancel()
 
     def result(self) -> bytes:
         rs = self._rs
         rep = self._pending.wait(rs._timeout)
+        if self._t0 is not None and rs._on_latency is not None:
+            rs._on_latency(rs._peer, self._pending.t_done - self._t0)
         if not rep.ok and rep.err == "EPERM:unauthenticated" \
                 and rs._authorize is not None:
             # first store op to this peer since (re)boot: run the
@@ -891,11 +907,14 @@ class _AsyncStoreOp:
 class _ReadvOp:
     """In-flight readv: result() -> (data bytes, attrs list | None),
     with _AsyncStoreOp's error surface (incl. the one cephx
-    re-authorize retry)."""
+    re-authorize retry); its round trip is reported to on_latency."""
 
     def __init__(self, rs: "RemoteStore", body: bytes, want_attrs: bool):
-        self._op = _AsyncStoreOp(rs, "readv", body)
+        self._op = _AsyncStoreOp(rs, "readv", body, timed=True)
         self._want_attrs = want_attrs
+
+    def cancel(self) -> None:
+        self._op.cancel()
 
     def result(self) -> tuple[bytes, list[bytes] | None]:
         d = Decoder(self._op.result())
@@ -1019,9 +1038,12 @@ class RemoteStore:
                      attr_key: str | None = None) -> "_ReadvOp":
         """Pipelined multi-object fetch: ONE readv frame carries every
         row (+ optional per-row attr) for `oids`; transmit now, collect
-        later. The recovery runner submits one of these per (PG,
-        helper shard) before awaiting any — pulls from different
-        source OSDs overlap (the windowed PULL)."""
+        later. A client read's gather (ECBackend.read_objects) submits
+        one a needed slot, hinfo attr in the same answer, and the
+        recovery runner one per (PG, helper shard), before awaiting
+        any: fetches from different source OSDs overlap (the windowed
+        PULL). A store that lacks any of `oids` answers KeyError; a
+        row of another length than `length` fails the frame."""
         body = self._co(cid, "", lambda e: e.string(attr_key or "")
                         .i64(length).list(list(oids), Encoder.string))
         return _ReadvOp(self, body, attr_key is not None)

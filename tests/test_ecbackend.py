@@ -383,3 +383,216 @@ class TestStraySweep:
         got = be.read_objects(list(objs))
         for name, data in objs.items():
             np.testing.assert_array_equal(got[name], data)
+
+
+# ------------------------------------------------- the read path's gather
+
+class _Frame:
+    """What RemoteStore.readv_submit hands back: the answer is made
+    when it is collected, from the wrapped store as a daemon's
+    `readv` handler makes it."""
+
+    def __init__(self, store, cid, oids, length, attr_key):
+        self.store, self.args = store, (cid, list(oids), length, attr_key)
+        store.in_flight.add(self)
+
+    def cancel(self):
+        self.store.in_flight.discard(self)
+        self.store.cancelled += 1
+
+    def result(self):
+        st = self.store
+        st.in_flight.discard(self)
+        cid, oids, length, attr_key = self.args
+        if st.fail is not None:
+            raise st.fail
+        rows = [MemStore.read(st, cid, o).tobytes() for o in oids]
+        attrs = [MemStore.getattr(st, cid, o, attr_key) for o in oids] \
+            if attr_key else None
+        return b"".join(rows)[:st.cut], attrs
+
+
+class FramedStore(MemStore):
+    """A store reached through frames, as a remote one is: it offers
+    `readv_submit`, and its one-at-a-time reads are counted."""
+
+    def __init__(self, log):
+        super().__init__()
+        self.log, self.in_flight, self.cancelled = log, set(), 0
+        self.fail, self.cut = None, None
+
+    def readv_submit(self, cid, oids, length, attr_key=None):
+        self.log.append(("readv", cid, tuple(oids), attr_key))
+        return _Frame(self, cid, oids, length, attr_key)
+
+    def read(self, cid, oid, offset=0, length=None):
+        self.log.append(("read", cid, oid))
+        return super().read(cid, oid, offset, length)
+
+    def getattr(self, cid, oid, key):
+        self.log.append(("getattr", cid, oid))
+        return super().getattr(cid, oid, key)
+
+
+def framed_backend(local_osd=0, n_osds=6):
+    """k=4 m=2 over stores reached by frames, but for `local_osd`,
+    which the primary holds itself (a MemStore, read in place)."""
+    log: list = []
+    cluster = ShardSet(store_factory=lambda osd: MemStore()
+                       if osd == local_osd else FramedStore(log))
+    be = ECBackend("plugin=tpu_rs k=4 m=2 impl=bitlinear", "1.0",
+                   list(range(n_osds)), cluster, chunk_size=256)
+    return be, cluster, log
+
+
+def _counts(be):
+    return (int(be.perf.get("gather_rounds")),
+            int(be.perf.get("gather_frames")))
+
+
+class TestGatherRound:
+    """ECBackend.read_objects gathers a plan's rows in one round: one
+    readv frame a remote slot with the hinfo attr in the same answer,
+    the primary's own slot read in place (PR 28)."""
+
+    def test_one_frame_a_remote_slot_and_no_attr_pass(self):
+        be, _, log = framed_backend()
+        objs = write_corpus(be, n=5, size=900)
+        del log[:]
+        got = be.read_objects(list(objs))
+        for name, data in objs.items():
+            np.testing.assert_array_equal(got[name], data, err_msg=name)
+        # data slots 0-3, slot 0 local: three frames, each naming the
+        # whole group and asking for the hinfo with the rows
+        assert [e[0] for e in log] == ["readv"] * 3
+        assert {e[1] for e in log} == {shard_cid("1.0", s)
+                                       for s in (1, 2, 3)}
+        assert all(e[2] == tuple(objs) and e[3] == HINFO_KEY for e in log)
+        assert _counts(be) == (1, 3)
+
+    def test_verify_off_asks_for_no_attr(self):
+        be, _, log = framed_backend()
+        objs = write_corpus(be, n=2, size=900)
+        del log[:]
+        got = be.read_objects(list(objs), verify=False)
+        np.testing.assert_array_equal(got["obj1"], objs["obj1"])
+        assert [e[0] for e in log] == ["readv"] * 3
+        assert all(e[3] is None for e in log)
+
+    def test_every_frame_is_out_before_any_is_collected(self, monkeypatch):
+        be, cluster, _ = framed_backend()
+        objs = write_corpus(be, n=2, size=900)
+        most = []
+        keep = _Frame.result
+
+        def result(self):
+            most.append(sum(len(cluster.osd(o).in_flight)
+                            for o in (1, 2, 3)))
+            return keep(self)
+        monkeypatch.setattr(_Frame, "result", result)
+        be.read_objects(list(objs))
+        assert most == [3, 2, 1]          # all out, then taken in turn
+
+    @pytest.mark.parametrize("lacking", [2, 0])
+    def test_a_slot_that_lacks_the_object_is_planned_around(self, lacking):
+        """A remote slot answers KeyError, or the local store raises
+        it: the slot is dropped and the read planned again, after
+        the round's other answers are in."""
+        be, cluster, log = framed_backend()
+        objs = write_corpus(be, n=3, size=900)
+        cluster.osd(lacking).queue_transaction(
+            Transaction().remove(shard_cid("1.0", lacking), "obj1"))
+        before = be.eio_stats["repaired"]
+        got = be.read_objects(list(objs))
+        for name, data in objs.items():
+            np.testing.assert_array_equal(got[name], data, err_msg=name)
+        rounds, frames = _counts(be)
+        assert rounds == 2
+        # round 1: 3 remote data slots; round 2: a parity slot stands
+        # in (for the local slot, beside the three; for a remote one,
+        # beside the two left)
+        assert frames == (3 + 3 if lacking else 3 + 4)
+        assert int(be.perf.get("degraded_reads")) == 3
+        assert be.eio_stats["repaired"] == before
+        assert all(not cluster.osd(o).in_flight and
+                   not cluster.osd(o).cancelled for o in range(1, 6))
+
+    @pytest.mark.parametrize("repair", [True, False])
+    def test_a_rotten_row_takes_the_eio_path(self, repair):
+        be, cluster, log = framed_backend()
+        objs = write_corpus(be, n=3, size=900)
+        cid = shard_cid("1.0", 2)
+        good = cluster.osd(2).read(cid, "obj1").copy()
+        cluster.osd(2).queue_transaction(
+            Transaction().write(cid, "obj1", 7, b"\x5a\xa5"))
+        rotten = MemStore.read(cluster.osd(2), cid, "obj1").copy()
+        assert not np.array_equal(rotten, good)
+        got = be.read_objects(list(objs), repair=repair)
+        for name, data in objs.items():
+            np.testing.assert_array_equal(got[name], data, err_msg=name)
+        assert be.eio_stats["read_eio"] == 1
+        assert int(be.perf.get("read_eio")) == 1
+        assert be.eio_stats["repaired"] == (1 if repair else 0)
+        np.testing.assert_array_equal(
+            MemStore.read(cluster.osd(2), cid, "obj1"),
+            good if repair else rotten)
+
+    def test_a_group_over_the_frame_budget_is_split_in_order(
+            self, monkeypatch):
+        from ceph_tpu.osd import ecbackend
+        be, _, log = framed_backend()
+        objs = write_corpus(be, n=7, size=900)     # rows of 256 bytes
+        monkeypatch.setattr(ecbackend, "RECOVERY_FETCH_BYTES", 3 * 256)
+        del log[:]
+        got = be.read_objects(list(objs))
+        for name, data in objs.items():
+            np.testing.assert_array_equal(got[name], data, err_msg=name)
+        names = list(objs)
+        for s in (1, 2, 3):
+            frames = [e[2] for e in log if e[1] == shard_cid("1.0", s)]
+            assert frames == [tuple(names[0:3]), tuple(names[3:6]),
+                              tuple(names[6:7])]
+        assert {e[0] for e in log} == {"readv"}
+        assert _counts(be) == (1, 9)
+
+    @pytest.mark.parametrize("cut,said", [(-1, "got 767 bytes"),
+                                          (None, "got 1024 bytes")])
+    def test_a_short_or_long_answer_fails_loudly(self, cut, said):
+        be, cluster, _ = framed_backend()
+        objs = write_corpus(be, n=3, size=900)
+        if cut is None:
+            # the answer carries a row more than was asked for
+            cluster.osd(2).readv_submit = lambda cid, oids, ln, key=None: \
+                _Frame(cluster.osd(2), cid, list(oids) + ["obj0"], ln, key)
+        else:
+            cluster.osd(2).cut = cut
+        with pytest.raises(ValueError, match="readv: " + said
+                           + ", expected 768"):
+            be.read_objects(list(objs))
+        assert not any(cluster.osd(o).in_flight for o in range(1, 6))
+
+    def test_a_transport_error_surfaces_and_leaves_nothing_in_flight(self):
+        be, cluster, _ = framed_backend()
+        objs = write_corpus(be, n=2, size=900)
+        cluster.osd(1).fail = ConnectionError("rpc to osd.1 timed out")
+        with pytest.raises(ConnectionError, match="osd.1 timed out"):
+            be.read_objects(list(objs))
+        assert not any(cluster.osd(o).in_flight for o in range(1, 6))
+        assert cluster.osd(2).cancelled == cluster.osd(3).cancelled == 1
+
+    def test_sim_cluster_reads_through_the_local_branch(self):
+        """The in-process cluster's stores are MemStores: no frame is
+        sent and the bytes are the same."""
+        from ceph_tpu.osd.cluster import SimCluster
+        c = SimCluster(n_osds=8, pg_num=2)
+        rng = np.random.default_rng(28)
+        objs = {f"o{i}": rng.integers(0, 256, 1500, np.uint8)
+                for i in range(6)}
+        c.write(objs)
+        for name, data in objs.items():
+            np.testing.assert_array_equal(c.read(name), data)
+        rounds = sum(int(be.perf.get("gather_rounds"))
+                     for be in c.pgs.values())
+        assert rounds == len(objs)
+        assert sum(int(be.perf.get("gather_frames"))
+                   for be in c.pgs.values()) == 0

@@ -12,6 +12,10 @@ replicas, and the new `ec` verify counters rise. A read that rebuilds
 a row (its PG's data-slot holder down and not yet out, PR 27) leaves
 the decode's stage, launch and fetch and feeds `degraded_reads`,
 `decode_rows_rebuilt` and `decode_bytes_rebuilt`: one case each.
+A read's gather (PR 28) is one `readv` frame a remote slot with the
+hinfo in the answer: the sub-op kinds the OSDs serve, `gather_rounds`
+and `gather_frames`, a slot planned around, rot still caught, and the
+peer-latency EWMA fed by the pipelined reads.
 """
 
 import os
@@ -217,8 +221,11 @@ def cluster(tmp_path_factory):
     # native-codec shortcut has no stage, launch or fetch)
     keep = ecbackend._host_crc_available
     ecbackend._host_crc_available = lambda: False
+    # a grace no loaded host runs out: a peer held for dead would turn
+    # a healthy read into a degraded one under the counts below
     c = StandaloneCluster(
         n_osds=4, pg_num=2, cephx=True, secret=os.urandom(32),
+        hb_interval=0.5, hb_grace=30.0,
         store="tin", store_dir=str(tmp_path_factory.mktemp("tin")))
     try:
         c.wait_for_clean(timeout=40)
@@ -407,3 +414,176 @@ class TestDegradedReadSpansAndCounters:
         assert after["decode_launches"] == before["decode_launches"] + 1
         assert after["decode_time"] == before["decode_time"] + 1
         assert after["host_decode_launches"] == before["host_decode_launches"]
+
+
+# -- a read's gather: one overlapped round (PR 28) ------------------------------
+
+@pytest.fixture
+def served_kinds(monkeypatch):
+    """kind -> store sub-ops the OSDs served since the fixture."""
+    from ceph_tpu.osd.standalone import OSDDaemon
+    served: dict = {}
+    keep = OSDDaemon._store_op
+
+    def counted(self, kind, body):
+        served[kind] = served.get(kind, 0) + 1
+        return keep(self, kind, body)
+    monkeypatch.setattr(OSDDaemon, "_store_op", counted)
+    return served
+
+
+def _placed(cluster, cl, name):
+    """(primary daemon, acting, pg seed) of `name`'s PG."""
+    ps = cl.osdmap.object_to_pg(1, name)[1]
+    acting = cl.osdmap.pg_to_up_acting_osds(1, ps)[2]
+    return cluster.osds[acting[0]], acting, ps
+
+
+def _quiet_client(cluster):
+    cl = cluster.client(hedge_delay_ms=-1)   # one view a read, no hedge
+    cl.trace_sample_rate = 0.0
+    return cl
+
+
+class TestLiveGather:
+    @pytest.mark.parametrize("pool", ["healthy", "degraded"])
+    def test_a_read_is_one_readv_a_remote_slot_and_no_getattr(
+            self, request, pool, served_kinds, session):
+        """k=2 m=1: the primary holds data slot 0 itself and asks one
+        remote store, for slot 1 or, with that one's holder down, for
+        the parity slot: one `readv` frame a read, rows and hinfo in
+        it; no `read`, no `getattr`; one gather span a plan."""
+        if pool == "healthy":
+            cluster = request.getfixturevalue("cluster")
+            request.getfixturevalue("client")
+            cl, want = _quiet_client(cluster), b"g" * 3000
+            names = [f"gather-{i}" for i in range(3)]
+            cl.write({n: want for n in names})
+        else:
+            cluster, cl, name, want = request.getfixturevalue("degraded")
+            names = [name] * 3
+        keys = ("gather_rounds", "gather_frames", "degraded_reads")
+
+        def counters():
+            return [sum(int(d.ec_perf.get(k)) for d in cluster.osds.values()
+                        if not d._stop.is_set()) for k in keys]
+        before = counters()
+        served_kinds.clear()
+        t0 = time.perf_counter()
+        for n in names:
+            assert cl.read(n) == want
+        got = _mine(t0)
+        assert served_kinds.get("readv") == 3
+        assert "getattr" not in served_kinds and "read" not in served_kinds
+        rise = [a - b for a, b in zip(counters(), before)]
+        assert rise == [3, 3, 3 if pool == "degraded" else 0]
+        # and `perf dump` shows them
+        from ceph_tpu.utils.admin_socket import admin_command
+        dumped = [admin_command(cluster.asok_path(d.name), "perf dump")["ec"]
+                  for d in cluster.osds.values() if not d._stop.is_set()]
+        assert [sum(int(e[k]) for e in dumped) for k in keys] == counters()
+        assert len(got["ecbackend.read.gather"]) == 3
+        if pool == "healthy":                # TinStore: 2 rows a read
+            assert len(got["store.read"]) == 6
+
+    def test_a_slot_whose_store_lacks_the_object_is_planned_around(
+            self, cluster, client, served_kinds):
+        from ceph_tpu.osd.memstore import Transaction
+        from ceph_tpu.osd.pgbackend import shard_cid
+        cl, want = _quiet_client(cluster), os.urandom(3000)
+        cl.write({"unlanded": want})
+        primary, acting, ps = _placed(cluster, cl, "unlanded")
+        cluster.osds[acting[1]].store.queue_transaction(
+            Transaction().remove(shard_cid(f"1.{ps}", 1), "unlanded"))
+        before = {k: int(primary.ec_perf.get(k)) for k in
+                  ("gather_rounds", "gather_frames", "degraded_reads")}
+        served_kinds.clear()
+        assert cl.read("unlanded") == want
+        rise = {k: int(primary.ec_perf.get(k)) - v
+                for k, v in before.items()}
+        assert rise == {"gather_rounds": 2, "gather_frames": 2,
+                        "degraded_reads": 1}
+        assert served_kinds.get("readv") == 2
+        assert "getattr" not in served_kinds and "read" not in served_kinds
+        # no handle of either round is left in flight
+        assert int(primary.rpc.perf.get("inflight_ops")) == 0
+        assert not primary.rpc._pending
+
+    @pytest.mark.parametrize("repair", [True, False])
+    def test_a_rotten_row_still_takes_the_eio_path(self, cluster, client,
+                                                   repair):
+        """The stored crc comes with the row now; the row is still
+        checked against it before a byte goes back, and a view that
+        may not repair writes nothing."""
+        from ceph_tpu.osd.memstore import Transaction
+        from ceph_tpu.osd.pgbackend import shard_cid
+        cl, want = _quiet_client(cluster), os.urandom(3000)
+        name = f"rot-{int(repair)}"
+        cl.write({name: want})
+        primary, acting, ps = _placed(cluster, cl, name)
+        st, cid = cluster.osds[acting[1]].store, shard_cid(f"1.{ps}", 1)
+        good = st.read(cid, name).tobytes()
+        flipped = bytes(b ^ 0xFF for b in good[9:11])
+        st.queue_transaction(Transaction().write(cid, name, 9, flipped))
+        rotten = st.read(cid, name).tobytes()
+        assert rotten != good
+        eio = int(primary.ec_perf.get("read_eio"))
+        if repair:
+            assert cl.read(name) == want
+        else:
+            be = primary.backends[ps]
+            assert be.read_objects([name], repair=False)[name].tobytes() \
+                == want
+        assert int(primary.ec_perf.get("read_eio")) == eio + 1
+        assert st.read(cid, name).tobytes() == (good if repair else rotten)
+
+    def test_pipelined_reads_alone_move_the_peer_latency_ewma(
+            self, cluster, client, monkeypatch):
+        from ceph_tpu.osd.standalone import RemoteStore
+        cl, want = _quiet_client(cluster), b"e" * 3000
+        cl.write({"ewma": want})
+        primary, acting, _ = _placed(cluster, cl, "ewma")
+        called = []
+        keep = RemoteStore._call
+
+        def call(self, kind, body=b""):
+            if self._on_latency == primary._note_peer_latency \
+                    and self._peer == f"osd.{acting[1]}":
+                called.append(kind)
+            return keep(self, kind, body)
+        monkeypatch.setattr(RemoteStore, "_call", call)
+        primary._peer_lat.pop(acting[1], None)
+        assert cl.read("ewma") == want
+        first = primary._peer_lat[acting[1]]
+        assert 0 < first < 5
+        assert cl.read("ewma") == want
+        assert primary._peer_lat[acting[1]] != first    # blended again
+        assert called == []              # no blocking call fed it
+
+    def test_a_handle_reports_submit_to_reply_not_to_collection(self):
+        """The round trip a pipelined read reports ends when the reply
+        lands, however late the caller collects it."""
+        from ceph_tpu.osd.standalone import (MStoreReply, RemoteStore,
+                                             _PendingCall)
+        from ceph_tpu.utils.encoding import Encoder
+
+        class Rpc:
+            def submit(self, peer, make_msg):
+                self.ent = _PendingCall(self, 1, peer, 0)
+                self.msg = make_msg(1)
+                return self.ent
+
+            def _retire(self, ent):
+                pass
+        rpc, seen = Rpc(), []
+        rs = RemoteStore(rpc, "osd.3", on_latency=lambda p, dt:
+                         seen.append((p, dt)))
+        handle = rs.readv_submit("1.0s1", ["a", "b"], 4, "hinfo")
+        assert rpc.msg.kind == "readv" and seen == []
+        body = Encoder().blob(b"aaaabbbb").list([b"x", b"y"], Encoder.blob)
+        rpc.ent._replies.append(MStoreReply(1, True, "readv", body.bytes()))
+        rpc.ent._notify()
+        time.sleep(0.08)
+        assert handle.result() == (b"aaaabbbb", [b"x", b"y"])
+        ((peer, dt),) = seen
+        assert peer == "osd.3" and 0 <= dt < 0.06
