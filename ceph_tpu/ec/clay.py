@@ -29,8 +29,9 @@ plane-by-plane "intersection score" schedule (ErasureCodeClay::
 decode_layered), the whole decode/repair is LINEAR over GF(2^8), so we
 symbolically solve the coupled system ONCE per erasure pattern and cache
 a single (outputs x inputs) GF matrix. Applying it is then one batched
-GF matmul on the MXU (ops.rs_kernels impl="mxu") — no data-dependent
-control flow, perfectly XLA-shaped. Encode is "decode the parities".
+GF matmul (ops.rs_kernels: matrices this large take the dense bit-plane
+form on the MXU) — no data-dependent control flow, perfectly
+XLA-shaped. Encode is "decode the parities".
 """
 
 from __future__ import annotations
@@ -115,6 +116,7 @@ class Clay(ErasureCode):
     # bytes are coupled across the sub-chunk axis of each chunk, so a
     # sub-window of a chunk is not independently en/decodable
     positionwise = False
+    has_ref_oracle = True
 
     def init(self, profile: Mapping[str, str]) -> None:
         self.k = int(profile.get("k", 4))
@@ -143,7 +145,6 @@ class Clay(ErasureCode):
         technique = profile.get("technique", "reed_sol_van")
         self.base_matrix = coding_matrix(technique, self.k + self.nu, self.m)
         self.technique = technique
-        self.impl = profile.get("impl", "mxu")
         nn = self.q * self.t
         # parity-check H = [C | I_m] over node order [data, virtual, parity]
         self.H = np.concatenate(
@@ -407,7 +408,7 @@ class Clay(ErasureCode):
         erasures = tuple(int(e) for e in erasures)
         survivors = tuple(int(s) for s in survivors)
         if len(erasures) != 1 or len(survivors) != self.d \
-                or self.impl == "ref":   # ref = numpy oracle, no
+                or self.ref_oracle:      # impl=ref: numpy oracle, no
             return None                  # device path to fuse into
         key = ("bd", erasures, survivors)
         fn = self._affine_cache.get(key)
@@ -415,7 +416,7 @@ class Clay(ErasureCode):
             from ..ops.rs_kernels import make_encoder
             lost = erasures[0]
             D, planes = self.repair_plan_matrix(lost, survivors)
-            mfn = make_encoder(D, self.impl)
+            mfn = make_encoder(D)
             P = self.sub_chunk_count
             beta = len(planes)
             planes_idx = np.asarray(planes)
@@ -446,14 +447,14 @@ class Clay(ErasureCode):
         erasures = tuple(int(e) for e in erasures)
         survivors = tuple(int(s) for s in survivors)
         if len(erasures) != 1 or len(survivors) != self.d \
-                or self.impl == "ref":
+                or self.ref_oracle:
             return None
         key = ("bdr", erasures, survivors)
         fn = self._affine_cache.get(key)
         if fn is None:
             from ..ops.rs_kernels import make_encoder
             D, planes = self.repair_plan_matrix(erasures[0], survivors)
-            mfn = make_encoder(D, self.impl)
+            mfn = make_encoder(D)
             beta = len(planes)
             P = self.sub_chunk_count
 
@@ -478,20 +479,19 @@ class Clay(ErasureCode):
         if self.range_batch_decoder(erasures, survivors) is None:
             return None
         D, planes = self.repair_plan_matrix(erasures[0], survivors)
-        return ("clayrng", D.tobytes(), D.shape, tuple(planes),
-                self.impl)
+        return ("clayrng", D.tobytes(), D.shape, tuple(planes))
 
     # -- data paths ---------------------------------------------------------
 
     def _apply(self, D: np.ndarray, stacked: np.ndarray) -> np.ndarray:
         """(B, nin, sub) -> (B, nout, sub) via the cached GF matrix."""
-        if self.impl == "ref":
+        if self.ref_oracle:
             return encode_ref(D, stacked)
         from ..ops.rs_kernels import make_encoder
         fid = id(D)
         fn = self._fn_cache.get(fid)
         if fn is None:
-            fn = make_encoder(D, self.impl)
+            fn = make_encoder(D)
             self._fn_cache[fid] = fn
         return np.asarray(fn(stacked))
 

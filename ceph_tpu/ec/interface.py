@@ -46,8 +46,21 @@ class ErasureCode(abc.ABC):
     # like the RMW write path then fall back to whole-object windows.
     positionwise: bool = True
 
+    # True for the codecs that keep a numpy oracle behind the profile
+    # key impl=ref (clay, shec): tests compare the device path with it.
+    has_ref_oracle: bool = False
+
     def __init__(self, profile: Mapping[str, str] | None = None):
         self.profile: ErasureCodeProfile = dict(profile or {})
+        impl = self.profile.get("impl")
+        if impl is not None and not (impl == "ref" and self.has_ref_oracle):
+            # profiles arrive from outside the program
+            raise ValueError(
+                f"impl={impl!r}: the GF(2^8) lowering is no longer "
+                f"selectable (ops/rs_kernels picks it from the matrix); "
+                f"the key's one value left is impl=ref, the numpy oracle "
+                f"of the clay and shec plugins")
+        self.ref_oracle = impl == "ref"
         if profile is not None:
             self.init(self.profile)
 
@@ -96,8 +109,7 @@ class ErasureCode(abc.ABC):
         if not getattr(self, "positionwise", True):
             return None          # byte positions couple (clay
         #                          overrides with its sub-chunk plan)
-        impl = getattr(self, "impl", None) or "mxu"
-        if impl == "ref":
+        if self.ref_oracle:
             return None          # numpy oracle: no device path
         erasures = tuple(int(e) for e in erasures)
         survivors = tuple(int(s) for s in survivors)
@@ -114,12 +126,12 @@ class ErasureCode(abc.ABC):
                     break
                 except ValueError:
                     continue
-            fn = make_encoder(R, impl) if R is not None else False
+            fn = make_encoder(R) if R is not None else False
             cache[(erasures, survivors)] = fn
             if R is not None:
                 self.__dict__.setdefault("_bd_keys", {})[
                     (erasures, survivors)] = (
-                        "lin", R.tobytes(), R.shape, impl)
+                        "lin", R.tobytes(), R.shape)
         return fn or None
 
     # -- parity-delta fast path (partial-stripe RMW) -----------------------
@@ -154,8 +166,7 @@ class ErasureCode(abc.ABC):
         D = self.delta_matrix(touched)
         if D is None:
             return None
-        impl = getattr(self, "impl", None) or "mxu"
-        return ("delta", D.tobytes(), D.shape, impl)
+        return ("delta", D.tobytes(), D.shape)
 
     def parity_delta(self, touched: Sequence[int],
                      deltas: np.ndarray) -> np.ndarray:

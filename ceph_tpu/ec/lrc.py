@@ -165,11 +165,6 @@ class Lrc(ErasureCode):
             lprof = profile_from_string(lprof_s) if isinstance(
                 lprof_s, str) and lprof_s else dict(lprof_s or {})
             lprof.setdefault("plugin", "tpu_rs")
-            if "impl" in profile:
-                # the top-level impl choice reaches the layer coders
-                # (k/m/l expansions carry empty layer profiles, which
-                # otherwise pinned every layer to the plugin default)
-                lprof.setdefault("impl", profile["impl"])
             lprof["k"] = str(len(d_pos))
             lprof["m"] = str(len(c_pos))
             self.layers.append(_Layer(d_pos, c_pos, factory(lprof)))
@@ -293,18 +288,6 @@ class Lrc(ErasureCode):
             return direct
         _, reads, _ = self._repair_plan(want - avail, avail, costs=available)
         return direct | reads
-
-    # -- device fast path ---------------------------------------------------
-
-    @property
-    def impl(self) -> str:
-        """Device lowering for the base class's derived batch_decoder
-        (the layered plan collapses to ONE static GF matrix via
-        ec/linearize — positionwise-linear, so the multi-stage local/
-        global walk composes into a single device launch; ref:
-        ErasureCodeLrc::minimum_to_decode layer walk)."""
-        return getattr(self.layers[0].coder, "impl", "mxu") \
-            if self.layers else "mxu"
 
     # -- decode ------------------------------------------------------------
 

@@ -19,7 +19,7 @@ from typing import Mapping, Sequence
 import numpy as np
 
 from ..gf.numpy_ref import decode_matrix
-from ..ops.rs_kernels import DEFAULT_IMPL, make_encoder
+from ..ops.rs_kernels import make_encoder
 from .interface import ErasureCode
 from .matrices import coding_matrix
 from .registry import register
@@ -33,15 +33,10 @@ class ReedSolomon(ErasureCode):
         self.m = int(profile.get("m", 3))
         technique = profile.get("technique", "reed_sol_van")
         self.technique = technique
-        self.impl = profile.get("impl", DEFAULT_IMPL)
-        from ..ops.rs_kernels import _IMPLS
-        if self.impl not in _IMPLS:
-            raise ValueError(f"unknown impl {self.impl!r}; "
-                             f"available: {sorted(_IMPLS)}")
         if self.k < 1 or self.m < 1 or self.k + self.m > 256:
             raise ValueError(f"bad geometry k={self.k} m={self.m} (w=8)")
         self.matrix = coding_matrix(technique, self.k, self.m)
-        self._encode_fn = make_encoder(self.matrix, self.impl)
+        self._encode_fn = make_encoder(self.matrix)
         self._decode_cache: dict[tuple[tuple[int, ...], tuple[int, ...]], tuple] = {}
 
     def encode_chunks(self, data: np.ndarray) -> np.ndarray:
@@ -61,7 +56,7 @@ class ReedSolomon(ErasureCode):
         hit = self._decode_cache.get(key)
         if hit is None:
             D = decode_matrix(self.matrix, list(erasures), self.k, list(survivors))
-            hit = (make_encoder(D, self.impl), survivors)
+            hit = (make_encoder(D), survivors)
             self._decode_cache[key] = hit
         return hit
 
@@ -80,14 +75,13 @@ class ReedSolomon(ErasureCode):
     def decode_program_key(self, erasures: Sequence[int],
                            survivors: Sequence[int]):
         # the compiled program is a pure function of (coding matrix,
-        # erasure/survivor pattern, impl) — every PG backend with the
+        # erasure/survivor pattern) — every PG backend with the
         # same profile shares one program per pattern
         erasures = tuple(int(e) for e in erasures)
         survivors = tuple(int(s) for s in survivors)[:self.k]
         if len(survivors) < self.k:
             return None
-        return ("rs", self.matrix.tobytes(), self.impl, erasures,
-                survivors)
+        return ("rs", self.matrix.tobytes(), erasures, survivors)
 
     def decode_chunks(self, want_to_read: Sequence[int],
                       chunks: Mapping[int, np.ndarray]) -> dict[int, np.ndarray]:

@@ -93,6 +93,7 @@ class Shec(ErasureCode):
 
     # exhaustive durability verification budget (subsets tested at init)
     _VERIFY_BUDGET = 100_000
+    has_ref_oracle = True
 
     def init(self, profile: Mapping[str, str]) -> None:
         self.k = int(profile.get("k", 4))
@@ -105,7 +106,6 @@ class Shec(ErasureCode):
         if self.k + self.m > 256:
             raise ValueError(f"bad geometry k={self.k} m={self.m} (w=8)")
         self.l = -(-self.k * self.c // self.m)  # ceil(k*c/m) window width
-        self.impl = profile.get("impl", "bitlinear")
         base = reed_sol_van_matrix(self.k, self.m)
         M = np.zeros_like(base)
         self.windows: list[tuple[int, ...]] = []
@@ -121,14 +121,18 @@ class Shec(ErasureCode):
         self._mtd_cache: dict[tuple, set[int]] = {}
         self._fn_cache: dict[int, object] = {}
         self._verify_durability()
-        if self.impl == "ref":
+        self._encode_fn = self._matrix_fn(self.matrix)
+
+    def _matrix_fn(self, X: np.ndarray):
+        """(B, cols, L) -> X (GF@) it: the device program, or for
+        impl=ref the numpy oracle."""
+        if self.ref_oracle:
             from functools import partial
 
             from ..gf.numpy_ref import encode_ref
-            self._encode_fn = partial(encode_ref, self.matrix)
-        else:
-            from ..ops.rs_kernels import make_encoder
-            self._encode_fn = make_encoder(self.matrix, self.impl)
+            return partial(encode_ref, X)
+        from ..ops.rs_kernels import make_encoder
+        return make_encoder(X)
 
     def _verify_durability(self) -> None:
         n = self.k + self.m
@@ -245,14 +249,7 @@ class Shec(ErasureCode):
             if X is None:
                 raise ValueError(
                     f"shec cannot decode {list(want)} from {list(surv)}")
-            if self.impl == "ref":
-                from ..gf.numpy_ref import encode_ref
-                from functools import partial
-                fn = partial(encode_ref, X)
-            else:
-                from ..ops.rs_kernels import make_encoder
-                fn = make_encoder(X, self.impl)
-            hit = (fn, surv)
+            hit = (self._matrix_fn(X), surv)
             self._decode_cache[key] = hit
         return hit
 

@@ -5,26 +5,28 @@ The device-side replacement for the reference's CPU hot loops
 jerasure_matrix_encode / jerasure_matrix_decode — see SURVEY.md §3.1).
 
 Unit of work: uint8 tensors shaped (batch, shard, chunk_bytes). The
-coding/decoding matrix is STATIC (baked into the compiled program) on the
-fast paths — codes are fixed per pool, so this is the common case, and it
-lets every GF coefficient become a compile-time constant (no gathers).
+coding/decoding matrix is STATIC (baked into the compiled program) —
+codes are fixed per pool, and it lets every GF coefficient become a
+compile-time constant (no gathers).
 
-Three interchangeable lowerings, all bit-exact vs the numpy oracle:
+A caller hands over a matrix and gets a program (`apply_matrix`,
+`make_encoder`). Two lowerings, both bit-exact vs the numpy oracle
+(gf/numpy_ref), and ONE rule that picks between them from the matrix
+alone (`_lowering`, `_UNROLL_MAX_ENTRIES`):
 
-  impl="bitlinear"  (default) — GF(2^8) multiply by a constant c is
+  unrolled (`_apply_bitlinear`) — GF(2^8) multiply by a constant c is
       GF(2)-linear in x:  c*x = XOR_{b set in x} (c * 2^b).  Each term is
       a shift/AND/select/XOR over uint8 lanes on the VPU; no gathers, no
       table memory traffic. The XOR tree over (j, b) is unrolled at trace
-      time (k*8 terms, static).
+      time (k*8 terms, static): the form of every small matrix — a
+      pool's RS/LRC/SHEC encode, decode and delta matrices.
 
-  impl="mxu" — unpack bytes to GF(2) bit-planes, multiply by the (m*8,
-      k*8) bit-expansion of the coding matrix on the MXU as an int8
-      matmul with int32 accumulation, take the low bit (sum mod 2 == XOR),
-      re-pack to bytes. Rides the systolic array instead of the VPU.
-
-  impl="logexp" — classic log/antilog table gathers. Slowest on TPU but
-      the simplest; also the only path that supports a *traced* (runtime)
-      matrix, which mixed-erasure-pattern decode batches use.
+  dense (`_apply_mxu`) — unpack bytes to GF(2) bit-planes, multiply by
+      the (m*8, k*8) bit-expansion of the matrix on the MXU as an int8
+      matmul with int32 accumulation, take the low bit (sum mod 2 ==
+      XOR), re-pack to bytes. Program size does not grow with the
+      matrix: the form of Clay's solved plane matrices (256 x 512 at
+      k=8 m=4 d=11), which the unrolled form cannot compile in minutes.
 """
 
 from __future__ import annotations
@@ -35,14 +37,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..gf.tables import GF_EXP, GF_LOG, bit_powers, matrix_to_bitmatrix
+from ..gf.tables import bit_powers, matrix_to_bitmatrix
 
 Array = jax.Array
-
-# host tables; jnp.asarray at the point of use makes them constants of
-# the traced program, so importing this module creates no backend
-_LOG_T = GF_LOG.astype(np.int32)
-_EXP_T = GF_EXP[:512].astype(np.uint8)
 
 
 def _check(data: Array, k: int) -> None:
@@ -98,73 +95,41 @@ def _apply_mxu(matrix: np.ndarray, data: Array) -> Array:
     return jnp.bitwise_xor.reduce(pbits << shifts[None, None, :, None], axis=2)
 
 
-# ---------------------------------------------------------------- logexp
+# ---------------------------------------------------------------- the rule
 
-def _apply_logexp_static(matrix: np.ndarray, data: Array) -> Array:
-    m, k = matrix.shape
-    _check(data, k)
-    logs = GF_LOG[matrix].astype(np.int32)  # (m, k) host constants
-    zero = matrix == 0
-    ld = jnp.take(jnp.asarray(_LOG_T), data.astype(jnp.int32))  # (B, k, L)
-    exp_t = jnp.asarray(_EXP_T)
-    acc = None
-    for i in range(m):
-        row = None
-        for j in range(k):
-            if zero[i, j]:
-                continue
-            prod = jnp.take(exp_t, ld[:, j, :] + int(logs[i, j]))
-            prod = jnp.where(data[:, j, :] == 0, jnp.uint8(0), prod)
-            row = prod if row is None else row ^ prod
-        if row is None:
-            row = jnp.zeros_like(data[:, 0, :])
-        row = row[:, None, :]
-        acc = row if acc is None else jnp.concatenate([acc, row], axis=1)
-    return acc
+# Matrices of up to this many entries take the unrolled form, larger
+# ones the dense one. One v5e, 32 MiB of data, first call / device time
+# a call (PR 29's chip run; PERF.md has the table): 3 x 8 unrolled
+# 4.6 s / 1.61 ms, dense 4.2 s / 0.51 ms; Clay's 16 x 32 unrolled
+# 12.3 s / 6.03 ms, dense 5.8 s / 0.53 ms; Clay's 256 x 512 unrolled
+# not compiled in 120 s, dense 8.8 s / 3.60 ms. The dense form is ahead
+# on the chip at every size; the line stands between the largest pool
+# matrix (RS k=12 m=5: 60 entries) and Clay's smallest solved one (512)
+# because every ledger line measured the pools on the unrolled form —
+# moving them is a perf_opt with a claim in ecbench_encode_4m_b32
+# (ROADMAP A2), and this constant is all it has to change — and because
+# the CPU backend, which runs the tests, is several times slower
+# through the dense form at small matrices.
+_UNROLL_MAX_ENTRIES = 128
 
 
-def apply_matrix_traced(matrix: Array, data: Array) -> Array:
-    """GF matmul with a RUNTIME (traced) matrix — per-batch decode matrices.
-
-    matrix: (..., m, k) uint8 (may carry a leading batch dim matching data).
-    data:   (..., k, L) uint8.
-    Returns (..., m, L).
-    """
-    log_t = jnp.asarray(_LOG_T)
-    lm = jnp.take(log_t, matrix.astype(jnp.int32))           # (..., m, k)
-    ld = jnp.take(log_t, data.astype(jnp.int32))             # (..., k, L)
-    s = lm[..., :, :, None] + ld[..., None, :, :]            # (..., m, k, L)
-    prod = jnp.take(jnp.asarray(_EXP_T), s)
-    nz = (matrix[..., :, :, None] != 0) & (data[..., None, :, :] != 0)
-    prod = jnp.where(nz, prod, jnp.uint8(0))
-    return jnp.bitwise_xor.reduce(prod, axis=-2)
+def _lowering(matrix: np.ndarray):
+    """The one place a lowering is chosen: from the matrix alone."""
+    if matrix.size <= _UNROLL_MAX_ENTRIES:
+        return _apply_bitlinear
+    return _apply_mxu
 
 
-def _apply_pallas(matrix: np.ndarray, data: Array) -> Array:
-    from .pallas_gf import apply_matrix_pallas
-    return apply_matrix_pallas(matrix, data)
-
-
-_IMPLS = {
-    "bitlinear": _apply_bitlinear,
-    "mxu": _apply_mxu,
-    "logexp": _apply_logexp_static,
-    "pallas": _apply_pallas,
-}
-
-DEFAULT_IMPL = "bitlinear"
-
-
-def apply_matrix(matrix: np.ndarray, data: Array, impl: str = DEFAULT_IMPL) -> Array:
+def apply_matrix(matrix: np.ndarray, data: Array) -> Array:
     """out = matrix (GF) @ data along the shard axis. matrix is static."""
-    return _IMPLS[impl](np.asarray(matrix, dtype=np.uint8), data)
+    matrix = np.asarray(matrix, dtype=np.uint8)
+    return _lowering(matrix)(matrix, data)
 
 
 @functools.lru_cache(maxsize=128)
-def _make_jitted(matrix_bytes: bytes, m: int, k: int, impl: str):
+def _make_jitted(matrix_bytes: bytes, m: int, k: int):
     matrix = np.frombuffer(matrix_bytes, dtype=np.uint8).reshape(m, k)
-    fn = functools.partial(_IMPLS[impl], matrix)
-    return jax.jit(fn)
+    return jax.jit(functools.partial(_lowering(matrix), matrix))
 
 
 def pow2_bucket(n: int) -> int:
@@ -193,8 +158,7 @@ def run_bucketed(fn, arr):
     return fn(arr)[:B]
 
 
-def make_encoder(matrix: np.ndarray, impl: str = DEFAULT_IMPL,
-                 bucket_batch: bool = True):
+def make_encoder(matrix: np.ndarray, bucket_batch: bool = True):
     """Jitted closure computing matrix @ data for a fixed matrix.
 
     Works for encode (coding matrix) and decode (decode matrix) alike —
@@ -208,7 +172,7 @@ def make_encoder(matrix: np.ndarray, impl: str = DEFAULT_IMPL,
     measured bytes match the computed bytes exactly.
     """
     matrix = np.ascontiguousarray(matrix, dtype=np.uint8)
-    jitted = _make_jitted(matrix.tobytes(), *matrix.shape, impl)
+    jitted = _make_jitted(matrix.tobytes(), *matrix.shape)
     if not bucket_batch:
         return jitted
     return lambda data: run_bucketed(jitted,

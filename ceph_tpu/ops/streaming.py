@@ -21,9 +21,9 @@ Two lowering levels, composable:
   dispatch overlaps them), and results land in a preallocated host
   buffer one tile behind. Use for > HBM objects — the P5/P7 dataflow.
 
-Both reuse make_encoder's impls (bitlinear/mxu/pallas/logexp), and —
-like make_encoder — both serve ENCODE and DECODE alike: the "matrix"
-is any static GF matrix (coding matrix or inverted decode matrix).
+Both run make_encoder's program for the matrix, and — like
+make_encoder — both serve ENCODE and DECODE alike: the "matrix" is
+any static GF matrix (coding matrix or inverted decode matrix).
 """
 
 from __future__ import annotations
@@ -32,23 +32,12 @@ import functools
 
 import numpy as np
 
-from .rs_kernels import DEFAULT_IMPL, apply_matrix, make_encoder
-
-
-@functools.lru_cache(maxsize=256)
-def _shared_encoder(matrix_bytes: bytes, m: int, k: int, impl: str):
-    """Process-wide program cache for streaming/tiled codecs: every
-    instance with the same (matrix, impl) shares ONE jitted kernel —
-    per-instance make_encoder recompiled the identical HLO once per
-    PG backend (the same lesson the write path and the r10 recovery
-    program cache already encode)."""
-    matrix = np.frombuffer(matrix_bytes, dtype=np.uint8).reshape(m, k)
-    return make_encoder(matrix, impl)
+from .rs_kernels import apply_matrix, make_encoder
 
 
 @functools.lru_cache(maxsize=64)
 def _tiled_encoder_cached(matrix_bytes: bytes, m: int, k: int,
-                          impl: str, tile: int):
+                          tile: int):
     import jax
     import jax.numpy as jnp
 
@@ -66,23 +55,20 @@ def _tiled_encoder_cached(matrix_bytes: bytes, m: int, k: int,
         # (B, k, T, tile) -> (T, B, k, tile): tiles become the mapped
         # leading axis; lax.map emits ONE tile program + a loop
         tiles = jnp.moveaxis(data.reshape(B, kk, t, tile), 2, 0)
-        out = jax.lax.map(
-            functools.partial(apply_matrix, matrix, impl=impl), tiles)
+        out = jax.lax.map(functools.partial(apply_matrix, matrix), tiles)
         return jnp.moveaxis(out, 0, 2).reshape(B, m, L)
 
     return enc
 
 
-def make_tiled_encoder(matrix: np.ndarray, impl: str = DEFAULT_IMPL,
-                       tile: int = 1 << 20):
+def make_tiled_encoder(matrix: np.ndarray, tile: int = 1 << 20):
     """Jitted (B, k, L) -> (B, m, L) that internally lax.maps over
     L/tile chunk tiles. L must be a multiple of `tile` (the stripe
     layer already pads chunks to alignment). Process-wide cached per
-    (matrix, impl, tile)."""
+    (matrix, tile)."""
     matrix = np.ascontiguousarray(matrix, dtype=np.uint8)
     m, k = matrix.shape
-    return _tiled_encoder_cached(matrix.tobytes(), m, k, impl,
-                                 int(tile))
+    return _tiled_encoder_cached(matrix.tobytes(), m, k, int(tile))
 
 
 class StreamingCodec:
@@ -96,16 +82,17 @@ class StreamingCodec:
     of the output is exact).
     """
 
-    def __init__(self, matrix: np.ndarray, impl: str = DEFAULT_IMPL,
-                 tile: int = 1 << 20, depth: int = 2, perf=None):
+    def __init__(self, matrix: np.ndarray, tile: int = 1 << 20,
+                 depth: int = 2, perf=None):
         if depth < 1:
             raise ValueError("depth must be >= 1")
         matrix = np.ascontiguousarray(matrix, dtype=np.uint8)
         self.m, self.k = matrix.shape
         self.tile = int(tile)
         self.depth = depth  # in-flight tiles (double buffering = 2)
-        self._fn = _shared_encoder(matrix.tobytes(), self.m, self.k,
-                                   impl)
+        # make_encoder's program cache is process-wide and keyed by the
+        # matrix: every instance with one matrix shares ONE jitted kernel
+        self._fn = make_encoder(matrix)
         # optional instrumentation: a PerfCounters with
         # stream_launches / stream_bytes / stream_drain_time declared
         # (the daemon's "ec" logger fits; None = uncounted)

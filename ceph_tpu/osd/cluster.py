@@ -44,7 +44,7 @@ class SimCluster:
     handling."""
 
     def __init__(self, n_osds: int = 12, profile: str | dict =
-                 "plugin=tpu_rs k=4 m=2 impl=bitlinear",
+                 "plugin=tpu_rs k=4 m=2",
                  pg_num: int = 8, osds_per_host: int = 1,
                  chunk_size: int = 256,
                  heartbeat_interval: float = 6.0,

@@ -285,8 +285,8 @@ class ECBackend(PGBackend):
 
     @staticmethod
     @_functools.lru_cache(maxsize=256)
-    def _fused_write_fn(matrix_bytes: bytes, m: int, k: int, impl: str,
-                        sl: int, bucket: int):
+    def _fused_write_fn(matrix_bytes: bytes, m: int, k: int, sl: int,
+                        bucket: int):
         """Process-wide cache (like rs_kernels._make_jitted): every
         PG backend with the same coder geometry shares ONE compiled
         program per (shard len, batch bucket) — a per-backend cache
@@ -298,7 +298,7 @@ class ECBackend(PGBackend):
         from ..ops.rs_kernels import make_encoder
         matrix = np.frombuffer(matrix_bytes,
                                dtype=np.uint8).reshape(m, k)
-        enc = make_encoder(matrix, impl, bucket_batch=False)
+        enc = make_encoder(matrix, bucket_batch=False)
 
         def fused(d):                # (bucket, k, sl) u8
             parity = enc(d)          # (bucket, m, sl)
@@ -369,7 +369,7 @@ class ECBackend(PGBackend):
                                        dtype=np.uint8)
             ci0 = self._fused_write_fn.cache_info()
             fn = self._fused_write_fn(mat.tobytes(), self.m, self.k,
-                                      self.coder.impl, sl, bucket)
+                                      sl, bucket)
             ci1 = self._fused_write_fn.cache_info()
             self.perf.inc_many(
                 (("fused_write_launches", 1),
@@ -859,8 +859,8 @@ class ECBackend(PGBackend):
 
     @staticmethod
     @_functools.lru_cache(maxsize=256)
-    def _fused_delta_fn(matrix_bytes: bytes, m: int, t: int, impl: str,
-                        wl: int, bucket: int):
+    def _fused_delta_fn(matrix_bytes: bytes, m: int, t: int, wl: int,
+                        bucket: int):
         """Process-wide fused delta-encode program (the r10 recovery-
         program sharing rule): every PG backend whose coder exposes
         the same delta_program_key shares ONE compiled program per
@@ -874,7 +874,7 @@ class ECBackend(PGBackend):
         from ..csum.kernels import crc32c_blocks
         from ..ops.rs_kernels import make_encoder
         D = np.frombuffer(matrix_bytes, dtype=np.uint8).reshape(m, t)
-        enc = make_encoder(D, impl, bucket_batch=False)
+        enc = make_encoder(D, bucket_batch=False)
 
         def fused(d):                   # (bucket, t, wl) u8
             parity = enc(d)             # (bucket, m, wl)
@@ -921,8 +921,7 @@ class ECBackend(PGBackend):
             ci0 = self._fused_delta_fn.cache_info()
             fn = self._fused_delta_fn(
                 np.ascontiguousarray(D, np.uint8).tobytes(), self.m,
-                t, getattr(self.coder, "impl", None) or "mxu", wl,
-                bucket)
+                t, wl, bucket)
             ci1 = self._fused_delta_fn.cache_info()
             self.perf.inc_many(
                 (("rmw_delta_launches", 1),
@@ -1574,7 +1573,7 @@ class ECBackend(PGBackend):
             # the codec's own decode (LRC's layers, Clay's coupled
             # planes, SHEC's windows): device programs of its making,
             # but for impl=ref, the numpy oracle
-            if getattr(self.coder, "impl", None) == "ref":
+            if self.coder.ref_oracle:
                 self.perf.inc("host_decode_launches")
             rebuilt = self.coder.decode_chunks(lost, rows)
         else:

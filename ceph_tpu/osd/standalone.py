@@ -7468,7 +7468,7 @@ class StandaloneCluster:
     """Orchestrates the endpoints; the qa/standalone helpers' role."""
 
     def __init__(self, n_osds: int = 6,
-                 profile: str = "plugin=tpu_rs k=2 m=1 impl=bitlinear",
+                 profile: str = "plugin=tpu_rs k=2 m=1",
                  pg_num: int = 4, store: str = "mem",
                  store_dir: str | None = None,
                  secret: bytes | None = None,

@@ -29,7 +29,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..gf.numpy_ref import decode_matrix
-from ..ops.rs_kernels import DEFAULT_IMPL, apply_matrix
+from ..ops.rs_kernels import apply_matrix
 
 
 def encode_all_chunks(coder, obj: np.ndarray) -> np.ndarray:
@@ -77,8 +77,7 @@ def padded_slots(n_chunks: int, mesh: Mesh) -> int:
     return -(-n_chunks // s) * s
 
 
-def make_sharded_encoder(matrix: np.ndarray, mesh: Mesh,
-                         impl: str = DEFAULT_IMPL):
+def make_sharded_encoder(matrix: np.ndarray, mesh: Mesh):
     """Jitted step: (B, k, L) data -> (B, padded_slots(k+m), L) chunks,
     output scattered over the shard axis (the TPU analog of
     MOSDECSubOpWrite fan-out). Slots >= k+m are zero padding."""
@@ -87,7 +86,7 @@ def make_sharded_encoder(matrix: np.ndarray, mesh: Mesh,
     pad = padded_slots(n, mesh) - n
 
     def step(data):
-        parity = apply_matrix(matrix, data, impl=impl)
+        parity = apply_matrix(matrix, data)
         chunks = jnp.concatenate([data, parity], axis=1)
         if pad:
             chunks = jnp.pad(chunks, ((0, 0), (0, pad), (0, 0)))
@@ -98,7 +97,7 @@ def make_sharded_encoder(matrix: np.ndarray, mesh: Mesh,
 
 
 def make_sharded_gather_apply(D: np.ndarray, slots: tuple[int, ...],
-                              mesh: Mesh, impl: str = DEFAULT_IMPL):
+                              mesh: Mesh):
     """Jitted step: sharded (B, n_slots, L) chunks -> (B, rows(D), L).
 
     Indexing the given shard slots forces an ICI all-gather of exactly
@@ -110,26 +109,24 @@ def make_sharded_gather_apply(D: np.ndarray, slots: tuple[int, ...],
     idx = np.asarray(slots, dtype=np.int32)
 
     def step(chunks):
-        return apply_matrix(D, chunks[:, idx, :], impl=impl)
+        return apply_matrix(D, chunks[:, idx, :])
 
     return jax.jit(step, in_shardings=chunk_sharding(mesh),
                    out_shardings=data_sharding(mesh))
 
 
 def make_sharded_decoder(matrix: np.ndarray, erasures: tuple[int, ...],
-                         survivors: tuple[int, ...], mesh: Mesh,
-                         impl: str = DEFAULT_IMPL):
+                         survivors: tuple[int, ...], mesh: Mesh):
     """Jitted step: sharded (B, n, L) chunks -> (B, E, L) reconstructed
     (degraded read across the mesh; see make_sharded_gather_apply)."""
     matrix = np.asarray(matrix, dtype=np.uint8)
     k = matrix.shape[1]
     D = decode_matrix(matrix, list(erasures), k, list(survivors))
-    return make_sharded_gather_apply(D, tuple(survivors), mesh, impl)
+    return make_sharded_gather_apply(D, tuple(survivors), mesh)
 
 
 def make_sharded_clay_repair(coder, failed_chunk: int,
-                             helper_chunks: tuple[int, ...], mesh: Mesh,
-                             impl: str = DEFAULT_IMPL):
+                             helper_chunks: tuple[int, ...], mesh: Mesh):
     """Jitted step: sharded (B, n_slots, L) chunks -> (B, L) rebuilt
     Clay chunk, reading ONLY the helpers' repair-plane sub-chunks (the
     MSR bandwidth win, beta = q^(t-1) of q^t sub-chunks per helper)
@@ -147,7 +144,7 @@ def make_sharded_clay_repair(coder, failed_chunk: int,
         sub = helpers.reshape(B, d, nsub, L // nsub)
         rp = sub[:, :, planes, :]                      # beta sub-chunks
         stacked = rp.reshape(B, d * nrp, L // nsub)
-        out = apply_matrix(D, stacked, impl=impl)      # (B, nsub, L//nsub)
+        out = apply_matrix(D, stacked)                 # (B, nsub, L//nsub)
         return out.reshape(B, L)
 
     return jax.jit(step, in_shardings=chunk_sharding(mesh),

@@ -378,7 +378,7 @@ def test_rados_bench_json_schema(capsys):
         "seq", "--transport", "standalone", "--insecure",
         "--seconds", "0.4", "--object-size", "2048", "--batch", "2",
         "--num-osds", "4", "--pg-num", "2", "--op-shards", "2",
-        "--profile", "plugin=tpu_rs k=2 m=1 impl=bitlinear",
+        "--profile", "plugin=tpu_rs k=2 m=1",
         "--tenants", "2", "--hedge-delay-ms", "30", "--min-ops", "2",
         "--json"])
     out = json.loads(capsys.readouterr().out)
@@ -538,7 +538,7 @@ def test_recovery_bench_json_schema_live():
          os.path.join(os.path.dirname(__file__), "..", "tools",
                       "recovery_bench.py"),
          "-P", "plugin=lrc", "-P", "k=4", "-P", "m=2", "-P", "l=3",
-         "-P", "impl=bitlinear", "--objects", "4", "--size", "8192",
+         "--objects", "4", "--size", "8192",
          "--json"],
         capture_output=True, text=True, timeout=300)
     assert out.returncode == 0, out.stderr[-2000:]
@@ -624,7 +624,7 @@ def test_rados_bench_overwrite_schema_live():
          "--object-size", "65536", "--batch", "2", "--num-osds", "8",
          "--pg-num", "2", "--rmw-ops", "8", "--overwrite-size",
          "2048", "--chunk-size", "8192",
-         "--profile", "plugin=tpu_rs k=4 m=2 impl=bitlinear",
+         "--profile", "plugin=tpu_rs k=4 m=2",
          "--json"],
         capture_output=True, text=True, timeout=420)
     assert out.returncode == 0, out.stderr[-2000:]

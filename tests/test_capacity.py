@@ -308,7 +308,7 @@ class TestBackfillfullRecovery:
         # rebuild is NOT urgent and must respect the rung
         c = StandaloneCluster(
             n_osds=7, pg_num=4, op_timeout=3.0,
-            profile="plugin=tpu_rs k=2 m=3 impl=bitlinear")
+            profile="plugin=tpu_rs k=2 m=3")
         try:
             cl = c.client()
             base = corpus(41)

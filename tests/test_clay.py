@@ -184,7 +184,7 @@ def test_minimum_to_decode_semantics():
 def test_mxu_impl_matches_ref():
     import os
     prof_ref = make(4, 2, 5)
-    prof_dev = Clay({"k": "4", "m": "2", "d": "5", "impl": "mxu"})
+    prof_dev = Clay({"k": "4", "m": "2", "d": "5"})
     rng = np.random.default_rng(7)
     L = prof_ref.get_chunk_size(4 * prof_ref.sub_chunk_count * 4)
     data = rng.integers(0, 256, size=(2, 4, L), dtype=np.uint8)

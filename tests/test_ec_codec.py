@@ -123,9 +123,27 @@ def test_bitmatrix_techniques_dispatch():
         assert isinstance(coder, JerasureBitmatrix)
 
 
-def test_bad_impl_rejected_with_choices():
-    with pytest.raises(ValueError, match="bitlinear"):
-        registry.factory("k=4 m=2 impl=bitlinea")
+@pytest.mark.parametrize("impl", ["bitlinear", "mxu", "logexp", "pallas"])
+@pytest.mark.parametrize("profile", [
+    "plugin=tpu_rs k=4 m=2", "plugin=jerasure k=4 m=2",
+    "plugin=isa k=4 m=2", "plugin=shec k=4 m=3 c=2",
+    "plugin=clay k=4 m=2", "plugin=lrc k=4 m=2 l=3"])
+def test_bad_impl_rejected_with_choices(profile, impl):
+    # the lowering was a profile key once; profiles arrive from outside
+    with pytest.raises(ValueError, match="no longer selectable"):
+        registry.factory(f"{profile} impl={impl}")
+
+
+@pytest.mark.parametrize("profile,builds", [
+    ("plugin=clay k=4 m=2", True), ("plugin=shec k=4 m=3 c=2", True),
+    ("plugin=tpu_rs k=4 m=2", False), ("plugin=lrc k=4 m=2 l=3", False)])
+def test_impl_ref_is_the_oracle_of_clay_and_shec_only(profile, builds):
+    if builds:
+        assert registry.factory(f"{profile} impl=ref").ref_oracle
+        assert not registry.factory(profile).ref_oracle
+    else:
+        with pytest.raises(ValueError, match="impl=ref"):
+            registry.factory(f"{profile} impl=ref")
 
 
 def test_isa_plugin_distinct_matrix():

@@ -52,7 +52,7 @@ class TestMemStore:
 
 # ------------------------------------------------------------- ECBackend
 
-def make_backend(profile="plugin=tpu_rs k=4 m=2 impl=bitlinear",
+def make_backend(profile="plugin=tpu_rs k=4 m=2",
                  n_osds=6, chunk_size=256):
     cluster = ShardSet()
     be = ECBackend(profile, "1.0", list(range(n_osds)), cluster,
@@ -279,13 +279,13 @@ class TestFusedLrcClayRecovery:
 
     def test_clay_single_loss_fused_d_helpers(self):
         helper = self._assert_fused_recovery(
-            "plugin=clay k=4 m=2 d=5 impl=bitlinear", lose_slot=2)
+            "plugin=clay k=4 m=2 d=5", lose_slot=2)
         assert len(helper) == 5
 
     def test_clay_multi_loss_falls_back(self):
         """Two losses have no static single-chunk repair matrix: the
         generic path must still recover bit-exact."""
-        profile = "plugin=clay k=4 m=2 d=5 impl=bitlinear"
+        profile = "plugin=clay k=4 m=2 d=5"
         from ceph_tpu.ec.registry import factory
         n = factory(profile).get_chunk_count()
         cluster = ShardSet()
@@ -440,7 +440,7 @@ def framed_backend(local_osd=0, n_osds=6):
     log: list = []
     cluster = ShardSet(store_factory=lambda osd: MemStore()
                        if osd == local_osd else FramedStore(log))
-    be = ECBackend("plugin=tpu_rs k=4 m=2 impl=bitlinear", "1.0",
+    be = ECBackend("plugin=tpu_rs k=4 m=2", "1.0",
                    list(range(n_osds)), cluster, chunk_size=256)
     return be, cluster, log
 

@@ -181,12 +181,13 @@ def test_known_answer_parity_jax_kernels():
     lowering; the expected parity literals were computed by this
     file's independent arithmetic, NOT the repo oracle."""
     from ceph_tpu.ec.matrices import reed_sol_van_matrix
-    from ceph_tpu.ops.rs_kernels import make_encoder
+    from ceph_tpu.ops import rs_kernels
     data, parity = _kat_arrays()
     matrix = reed_sol_van_matrix(4, 2)
-    for impl in ("bitlinear", "mxu", "logexp"):
-        got = np.asarray(make_encoder(matrix, impl)(data))
-        np.testing.assert_array_equal(got, parity, err_msg=impl)
+    for lowering in (rs_kernels._apply_bitlinear, rs_kernels._apply_mxu):
+        got = np.asarray(lowering(matrix, data))
+        np.testing.assert_array_equal(got, parity,
+                                      err_msg=lowering.__name__)
 
 
 def test_known_answer_parity_native_codec():
@@ -228,14 +229,14 @@ def test_native_vs_jax_random_geometries(k, m, tech):
     np.testing.assert_array_equal(np.asarray(matrix),
                                   np.asarray(nc.matrix))
     native_parity = np.asarray(nc.encode_chunks(data))
-    jax_parity = np.asarray(make_encoder(matrix, "bitlinear")(data))
+    jax_parity = np.asarray(make_encoder(matrix)(data))
     np.testing.assert_array_equal(native_parity, jax_parity)
     # single-erasure decodes through both paths
     full = np.concatenate([data, jax_parity], axis=1)
     for lost in (0, k - 1, k):
         surv = [i for i in range(k + m) if i != lost][:k]
         D = decode_matrix(matrix, [lost], k, surv)
-        jax_rec = np.asarray(make_encoder(D, "bitlinear")(full[:, surv]))
+        jax_rec = np.asarray(make_encoder(D)(full[:, surv]))
         native_rec = nc.decode_chunks([lost],
                                       {s: full[:, s] for s in surv})
         np.testing.assert_array_equal(jax_rec[:, 0], full[:, lost])

@@ -26,7 +26,7 @@ def _proc_cluster(tmp_path, n_osds, store="tin", op_shards=2):
         store_dir=str(tmp_path / "osds") if store == "tin" else None,
         osd_procs=True, op_shards=op_shards,
         cephx=True, secret=os.urandom(32),
-        profile="plugin=tpu_rs k=2 m=1 impl=bitlinear",
+        profile="plugin=tpu_rs k=2 m=1",
         # deadline scaling, not schedule input: a loaded host
         # stretches child spawn + every ping round trip
         hb_grace=1.2 * LF)

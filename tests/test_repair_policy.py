@@ -351,7 +351,7 @@ def test_lazy_repair_live_revive_cancels_with_zero_bytes():
 
     from ceph_tpu.osd.standalone import StandaloneCluster
     c = StandaloneCluster(
-        n_osds=8, profile="plugin=tpu_rs k=2 m=3 impl=bitlinear",
+        n_osds=8, profile="plugin=tpu_rs k=2 m=3",
         pg_num=2, hb_interval=0.25, hb_grace=1.2)
     try:
         cl = c.client()

@@ -28,7 +28,7 @@ class TestPlanner:
     """Pure planning: families, laddering, costs — no data moved."""
 
     def test_lrc_single_loss_plans_local_group(self):
-        lrc = factory("plugin=lrc k=8 m=4 l=4 impl=bitlinear")
+        lrc = factory("plugin=lrc k=8 m=4 l=4")
         n = lrc.get_chunk_count()
         rp = plan_repair(lrc, [1], [i for i in range(n) if i != 1])
         assert rp.family == "lrc_local"
@@ -42,7 +42,7 @@ class TestPlanner:
         """Broken locality: two losses in one local group can't be
         served by that group — the structural walk ladders to the
         global layer and the family says so."""
-        lrc = factory("plugin=lrc k=8 m=4 l=4 impl=bitlinear")
+        lrc = factory("plugin=lrc k=8 m=4 l=4")
         n = lrc.get_chunk_count()
         rp = plan_repair(lrc, [1, 2],
                          [i for i in range(n) if i not in (1, 2)])
@@ -53,7 +53,7 @@ class TestPlanner:
             set(range(n)) - {1, 2}))) <= set(rp.helpers) | {1, 2}
 
     def test_clay_single_loss_plans_repair_planes(self):
-        clay = factory("plugin=clay k=8 m=4 impl=bitlinear")
+        clay = factory("plugin=clay k=8 m=4")
         n = clay.get_chunk_count()
         rp = plan_repair(clay, [3], [i for i in range(n) if i != 3])
         assert rp.family == "clay_planes"
@@ -68,7 +68,7 @@ class TestPlanner:
         assert rp.row_bytes(sl) == sl // clay.q
 
     def test_clay_multi_loss_ladders_to_full(self):
-        clay = factory("plugin=clay k=8 m=4 impl=bitlinear")
+        clay = factory("plugin=clay k=8 m=4")
         n = clay.get_chunk_count()
         rp = plan_repair(clay, [3, 4],
                          [i for i in range(n) if i not in (3, 4)])
@@ -76,7 +76,7 @@ class TestPlanner:
         assert rp.planes is None and rp.integrity == "row"
 
     def test_mds_costs_bias_helper_pick(self):
-        rs = factory("plugin=tpu_rs k=4 m=2 impl=bitlinear")
+        rs = factory("plugin=tpu_rs k=4 m=2")
         rp = plan_repair(rs, [0], [1, 2, 3, 4, 5],
                          costs={1: 10_000, 2: 1, 3: 1, 4: 1, 5: 1})
         assert rp.cost_ranked
@@ -87,7 +87,7 @@ class TestPlanner:
         """SHEC stays structural (fewest reads first) — the cost only
         picks among equally small workable sets, never an undecodable
         'cheapest k'."""
-        shec = factory("plugin=shec k=4 m=3 c=2 impl=bitlinear")
+        shec = factory("plugin=shec k=4 m=3 c=2")
         n = shec.get_chunk_count()
         avail = [i for i in range(n) if i != 0]
         base = plan_repair(shec, [0], avail)
@@ -101,7 +101,7 @@ class TestPlanner:
     def test_clay_costs_never_evict_column_mates(self):
         """Clay's surviving grid-column mates are structurally required
         helpers; a hostile cost table must not push them out."""
-        clay = factory("plugin=clay k=4 m=2 impl=bitlinear")
+        clay = factory("plugin=clay k=4 m=2")
         n = clay.get_chunk_count()
         lost = 0
         avail = [i for i in range(n) if i != lost]
@@ -113,7 +113,7 @@ class TestPlanner:
         assert mates <= set(rp.helpers)
 
     def test_unreconstructible_raises_value_error(self):
-        rs = factory("plugin=tpu_rs k=4 m=2 impl=bitlinear")
+        rs = factory("plugin=tpu_rs k=4 m=2")
         with pytest.raises(ValueError):
             plan_repair(rs, [0, 1, 2], [3, 4])   # 2 survivors < k
 
@@ -124,7 +124,7 @@ class TestPlanner:
         assert coalesce_ranges([(0, 8), (4, 8)]) == ((0, 12),)
 
     def test_plan_read_lrc_degraded_gathers_local_group(self):
-        lrc = factory("plugin=lrc k=4 m=2 l=3 impl=bitlinear")
+        lrc = factory("plugin=lrc k=4 m=2 l=3")
         n = lrc.get_chunk_count()
         # k4m2l3 layout: group0 = slots 0..3 (0 local parity, 1 global),
         # group1 = 4..7; data positions are {2, 3, 6, 7}
@@ -164,11 +164,11 @@ def _full_decode_oracle(be, lost, names):
 
 
 GEOMETRIES = [
-    ("plugin=tpu_rs k=4 m=2 impl=bitlinear", [1]),
-    ("plugin=lrc k=4 m=2 l=3 impl=bitlinear", [2]),
-    ("plugin=lrc k=4 m=2 l=3 impl=bitlinear", [2, 3]),   # broken group
-    ("plugin=clay k=2 m=2 impl=bitlinear", [1]),
-    ("plugin=shec k=4 m=3 c=2 impl=bitlinear", [0]),
+    ("plugin=tpu_rs k=4 m=2", [1]),
+    ("plugin=lrc k=4 m=2 l=3", [2]),
+    ("plugin=lrc k=4 m=2 l=3", [2, 3]),   # broken group
+    ("plugin=clay k=2 m=2", [1]),
+    ("plugin=shec k=4 m=3 c=2", [0]),
 ]
 
 
@@ -209,8 +209,8 @@ class TestPlannerRecoveryBitExact:
         reads pull strictly fewer helper bytes than a full-k plan
         would for the same rebuild."""
         for profile, expect_frac in [
-                ("plugin=lrc k=8 m=4 l=4 impl=bitlinear", 0.55),
-                ("plugin=clay k=2 m=2 impl=bitlinear", 0.80)]:
+                ("plugin=lrc k=8 m=4 l=4", 0.55),
+                ("plugin=clay k=2 m=2", 0.80)]:
             cluster = ShardSet()
             coder = factory(profile)
             n = coder.get_chunk_count()
@@ -234,7 +234,7 @@ class TestPlannerRecoveryBitExact:
         planner: an expensively-priced survivor sits out when k others
         are available."""
         cluster = ShardSet()
-        be = ECBackend("plugin=tpu_rs k=4 m=2 impl=bitlinear", "1.0",
+        be = ECBackend("plugin=tpu_rs k=4 m=2", "1.0",
                        list(range(6)), cluster, chunk_size=512)
         _write_corpus(be, "hc", n=3, sizes=(2048,))
         cluster.stores.pop(1)
@@ -254,7 +254,7 @@ class TestRangeIntegrity:
     def test_rot_in_shipped_plane_detected_and_decoded_around(
             self, host_crc):
         cluster = ShardSet()
-        be = ECBackend("plugin=clay k=2 m=2 impl=bitlinear", "1.0",
+        be = ECBackend("plugin=clay k=2 m=2", "1.0",
                        list(range(4)), cluster, chunk_size=512)
         objs = _write_corpus(be, "rot", n=4, sizes=(4096,))
         refs = _full_decode_oracle(be, [1], sorted(objs))
@@ -285,7 +285,7 @@ class TestRangeIntegrity:
         plan never ships is still caught (a later full-row read would
         have tripped over it) and the rebuild decodes around it."""
         cluster = ShardSet()
-        be = ECBackend("plugin=clay k=2 m=2 impl=bitlinear", "1.0",
+        be = ECBackend("plugin=clay k=2 m=2", "1.0",
                        list(range(4)), cluster, chunk_size=512)
         objs = _write_corpus(be, "rq", n=3, sizes=(4096,))
         refs = _full_decode_oracle(be, [1], sorted(objs))
@@ -312,7 +312,7 @@ class TestRangeIntegrity:
         """verify_hinfo=False must not pay the source-side full-row
         CRC pass (and still rebuild correctly on clean data)."""
         cluster = ShardSet()
-        be = ECBackend("plugin=clay k=2 m=2 impl=bitlinear", "1.0",
+        be = ECBackend("plugin=clay k=2 m=2", "1.0",
                        list(range(4)), cluster, chunk_size=512)
         objs = _write_corpus(be, "nv", n=3, sizes=(4096,))
         refs = _full_decode_oracle(be, [1], sorted(objs))
@@ -333,7 +333,7 @@ class TestDegradedLocalRead:
         LRC data shard gathers direct data + ONE local group — the
         other group's parities are never touched."""
         cluster = ShardSet()
-        be = ECBackend("plugin=lrc k=4 m=2 l=3 impl=bitlinear", "1.0",
+        be = ECBackend("plugin=lrc k=4 m=2 l=3", "1.0",
                        list(range(8)), cluster, chunk_size=512)
         objs = _write_corpus(be, "dg", n=4, sizes=(4096,))
         lost = be.data_slots[0]           # a data position in group 0
@@ -380,7 +380,7 @@ class TestWireRangeRecovery:
         # to re-home onto, or the PG can never go clean
         c = StandaloneCluster(
             n_osds=5, pg_num=2, op_timeout=5.0 * load_factor(),
-            profile="plugin=clay k=2 m=2 impl=bitlinear",
+            profile="plugin=clay k=2 m=2",
             chunk_size=512)
         try:
             c.wait_for_clean(timeout=30 * load_factor())

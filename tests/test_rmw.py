@@ -14,7 +14,7 @@ import pytest
 from ceph_tpu.osd.ecbackend import ECBackend, ShardSet, shard_cid
 
 
-def make_backend(profile="plugin=tpu_rs k=4 m=2 impl=bitlinear",
+def make_backend(profile="plugin=tpu_rs k=4 m=2",
                  n_osds=6, chunk_size=256):
     cluster = ShardSet()
     be = ECBackend(profile, "1.0", list(range(n_osds)), cluster,
@@ -180,8 +180,8 @@ class TestClayRMW:
 
 class TestRMWProperty:
     @pytest.mark.parametrize("profile", [
-        "plugin=tpu_rs k=4 m=2 impl=bitlinear",
-        "plugin=tpu_rs k=3 m=3 technique=cauchy_good impl=logexp",
+        "plugin=tpu_rs k=4 m=2",
+        "plugin=tpu_rs k=3 m=3 technique=cauchy_good",
     ])
     def test_thrash_partial_writes_and_kills(self, profile):
         """Random full/partial writes interleaved with OSD kills and

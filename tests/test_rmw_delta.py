@@ -56,11 +56,11 @@ def integrity(request, monkeypatch):
 
 
 GEOMETRIES = [
-    ("plugin=tpu_rs k=4 m=2 impl=bitlinear", 256),
-    pytest.param("plugin=tpu_rs k=3 m=3 technique=cauchy_good "
-                 "impl=logexp", 256, marks=pytest.mark.slow),
-    ("plugin=lrc k=4 m=2 l=3 impl=bitlinear", 256),
-    ("plugin=clay k=4 m=2 impl=bitlinear", 512),
+    ("plugin=tpu_rs k=4 m=2", 256),
+    pytest.param("plugin=tpu_rs k=3 m=3 technique=cauchy_good", 256,
+                 marks=pytest.mark.slow),
+    ("plugin=lrc k=4 m=2 l=3", 256),
+    ("plugin=clay k=4 m=2", 512),
     pytest.param("plugin=clay k=4 m=2 d=5 impl=ref", None,
                  marks=pytest.mark.slow),
 ]
@@ -136,7 +136,7 @@ class TestDeltaBitExact:
         """The wire contract: a single-column overwrite transacts on
         exactly 1 data + m parity shards — untouched data shards see
         no store transaction at all."""
-        be, _ = _make("plugin=tpu_rs k=4 m=2 impl=bitlinear", 256)
+        be, _ = _make("plugin=tpu_rs k=4 m=2", 256)
         rng = np.random.default_rng(7)
         base = rng.integers(0, 256, 3000, np.uint8)
         be.write_objects({"o": base})
@@ -157,10 +157,9 @@ class TestDeltaBitExact:
         geometry expose EQUAL delta keys (the r10 sharing rule — one
         compiled program per process, not per PG per daemon); a
         different geometry does not."""
-        a = factory("plugin=tpu_rs k=4 m=2 impl=bitlinear")
-        b = factory("plugin=tpu_rs k=4 m=2 impl=bitlinear")
-        c = factory("plugin=tpu_rs k=4 m=2 impl=bitlinear "
-                    "technique=cauchy_good")
+        a = factory("plugin=tpu_rs k=4 m=2")
+        b = factory("plugin=tpu_rs k=4 m=2")
+        c = factory("plugin=tpu_rs k=4 m=2 technique=cauchy_good")
         assert a.delta_program_key((1,)) == b.delta_program_key((1,))
         assert a.delta_program_key((1,)) != c.delta_program_key((1,))
         # vector codes have no static form; the generic path serves
@@ -170,7 +169,7 @@ class TestDeltaBitExact:
 
 class TestDeltaRefusal:
     def test_degraded_stripe_refuses_and_ladders_to_full(self):
-        be, cluster = _make("plugin=tpu_rs k=4 m=2 impl=bitlinear",
+        be, cluster = _make("plugin=tpu_rs k=4 m=2",
                             256)
         rng = np.random.default_rng(9)
         base = rng.integers(0, 256, 3000, np.uint8)
@@ -191,7 +190,7 @@ class TestDeltaRefusal:
     def test_stale_shard_refuses_delta(self):
         """A revived-but-behind shard (cursor below the object's
         version) is as unsafe a delta base as a dead one."""
-        be, _ = _make("plugin=tpu_rs k=4 m=2 impl=bitlinear", 256)
+        be, _ = _make("plugin=tpu_rs k=4 m=2", 256)
         rng = np.random.default_rng(10)
         be.write_objects({"o": rng.integers(0, 256, 2000, np.uint8)})
         be.shard_applied[2] = 0          # simulate a lagging shard
@@ -200,7 +199,7 @@ class TestDeltaRefusal:
         assert d["rmw_ops"] == 0 and d["rmw_full_fallbacks"] >= 1
 
     def test_overlapping_writes_in_one_wave_refuse(self):
-        be, _ = _make("plugin=tpu_rs k=4 m=2 impl=bitlinear", 256)
+        be, _ = _make("plugin=tpu_rs k=4 m=2", 256)
         rng = np.random.default_rng(11)
         base = rng.integers(0, 256, 2000, np.uint8)
         be.write_objects({"o": base})
@@ -238,7 +237,7 @@ class TestAppendStreams:
         the padded stripe read NOTHING (the pre-image is zeros by the
         layout rule) and never re-encode previously appended bytes —
         no full-stripe encode launches after the create."""
-        be, _ = _make("plugin=tpu_rs k=4 m=2 impl=bitlinear", 256)
+        be, _ = _make("plugin=tpu_rs k=4 m=2", 256)
         rng = np.random.default_rng(13)
         first = rng.integers(0, 256, 100, np.uint8)
         be.write_objects({"log": first})
@@ -277,7 +276,7 @@ def _rebuild(cluster, meta_src):
     """A post-crash primary: fresh backend view over the remounted
     stores, carrying the persisted-metadata analog (sizes/versions/
     log/cursors survive on the wire tier's meta plane)."""
-    be2 = ECBackend("plugin=tpu_rs k=4 m=2 impl=bitlinear", "1.0",
+    be2 = ECBackend("plugin=tpu_rs k=4 m=2", "1.0",
                     list(range(6)), cluster, chunk_size=256,
                     ensure_collections=False)
     be2.object_sizes = dict(meta_src.object_sizes)
@@ -303,7 +302,7 @@ class TestStripeJournalCrashMatrix:
         from ceph_tpu.osd.tinstore import TinStore
         root = str(tmp_path)
         cluster = _tin_cluster(root)
-        be = ECBackend("plugin=tpu_rs k=4 m=2 impl=bitlinear", "1.0",
+        be = ECBackend("plugin=tpu_rs k=4 m=2", "1.0",
                        list(range(6)), cluster, chunk_size=256)
         rng = np.random.default_rng(21)
         base = rng.integers(0, 256, 3000, np.uint8)
@@ -357,7 +356,7 @@ class TestStripeJournalCrashMatrix:
         numbers an old watermark already covers (a reused seq would
         fake the roll-forward evidence)."""
         cluster = _tin_cluster(str(tmp_path))
-        be = ECBackend("plugin=tpu_rs k=4 m=2 impl=bitlinear", "1.0",
+        be = ECBackend("plugin=tpu_rs k=4 m=2", "1.0",
                        list(range(6)), cluster, chunk_size=256)
         rng = np.random.default_rng(22)
         base = rng.integers(0, 256, 2000, np.uint8)
@@ -380,7 +379,7 @@ class TestPrepareFetchCoalescing:
     and spans the group carries."""
 
     def test_one_wave_one_frame_per_participant(self):
-        be, _ = _make("plugin=tpu_rs k=4 m=2 impl=bitlinear", 256)
+        be, _ = _make("plugin=tpu_rs k=4 m=2", 256)
         rng = np.random.default_rng(31)
         base = rng.integers(0, 256, 3000, np.uint8)
         be.write_objects({"a": base, "b": base[::-1].copy()})
@@ -398,7 +397,7 @@ class TestPrepareFetchCoalescing:
         _assert_stores_match_oracle(be, "a", want)
 
     def test_growth_wave_touches_every_shard_once(self):
-        be, _ = _make("plugin=tpu_rs k=4 m=2 impl=bitlinear", 256)
+        be, _ = _make("plugin=tpu_rs k=4 m=2", 256)
         rng = np.random.default_rng(32)
         base = rng.integers(0, 256, 900, np.uint8)
         be.write_objects({"g": base})
@@ -414,8 +413,7 @@ class TestPrepareFetchCoalescing:
         bytes land bit-exact."""
         from ceph_tpu.osd.standalone import StandaloneCluster
         c = StandaloneCluster(n_osds=5,
-                              profile="plugin=tpu_rs k=2 m=1 "
-                                      "impl=bitlinear",
+                              profile="plugin=tpu_rs k=2 m=1",
                               pg_num=2)
         try:
             cl = c.client()
@@ -443,7 +441,7 @@ class TestJournalAwareDeepScrub:
     watermark) instead of skipping the collection."""
 
     def test_clean_pg_reports_empty_journal_blocks(self):
-        be, _ = _make("plugin=tpu_rs k=4 m=2 impl=bitlinear", 256)
+        be, _ = _make("plugin=tpu_rs k=4 m=2", 256)
         rng = np.random.default_rng(41)
         be.write_objects({"o": rng.integers(0, 256, 2000, np.uint8)})
         be.write_at("o", 10, rng.integers(0, 256, 40, np.uint8))
@@ -454,7 +452,7 @@ class TestJournalAwareDeepScrub:
 
     def test_corrupt_intent_detected(self):
         from ceph_tpu.osd.memstore import Transaction
-        be, _ = _make("plugin=tpu_rs k=4 m=2 impl=bitlinear", 256)
+        be, _ = _make("plugin=tpu_rs k=4 m=2", 256)
         rng = np.random.default_rng(42)
         be.write_objects({"o": rng.integers(0, 256, 2000, np.uint8)})
         s = 0
@@ -490,7 +488,7 @@ class TestJournalAwareDeepScrub:
     def test_pending_intent_counts_not_flags(self):
         """A legitimate in-flight intent (prepare done, apply not) is
         journal_pending — crash-recovery state, never 'bad'."""
-        be, _ = _make("plugin=tpu_rs k=4 m=2 impl=bitlinear", 256)
+        be, _ = _make("plugin=tpu_rs k=4 m=2", 256)
         rng = np.random.default_rng(43)
         be.write_objects({"o": rng.integers(0, 256, 2000, np.uint8)})
 

@@ -1,4 +1,5 @@
-"""Device-kernel correctness: every lowering bit-exact vs the numpy oracle.
+"""Device-kernel correctness: both lowerings bit-exact vs the numpy oracle,
+and the rule that picks between them.
 
 The rebuild's analog of TestErasureCode round-trip tests (ref:
 src/test/erasure-code/TestErasureCode*.cc: encode random buffers, erase
@@ -11,19 +12,12 @@ import numpy as np
 import pytest
 
 from ceph_tpu.ec.matrices import reed_sol_van_matrix
+from ceph_tpu.ec.registry import factory
 from ceph_tpu.gf import numpy_ref as R
 from ceph_tpu.ops import rs_kernels as K
 
-IMPLS = ["bitlinear", "mxu", "logexp", "pallas"]
-
-
-def _apply(mat, data, impl):
-    if impl == "pallas":
-        # no TPU under the tests: the kernel's interpreter, asked for by
-        # name (the served path never interprets)
-        from ceph_tpu.ops.pallas_gf import apply_matrix_pallas
-        return apply_matrix_pallas(mat, data, interpret=True)
-    return K.apply_matrix(mat, data, impl=impl)
+LOWERINGS = [K._apply_bitlinear, K._apply_mxu]
+_ids = [f.__name__ for f in LOWERINGS]
 
 
 def _rand(b, k, L, seed=0):
@@ -31,28 +25,28 @@ def _rand(b, k, L, seed=0):
                                                 dtype=np.uint8)
 
 
-@pytest.mark.parametrize("impl", IMPLS)
-@pytest.mark.parametrize("k,m", [(4, 2), (8, 3)])
-def test_encode_matches_oracle(impl, k, m):
+@pytest.mark.parametrize("lowering", LOWERINGS, ids=_ids)
+@pytest.mark.parametrize("k,m", [(4, 2), (8, 3), (2, 1), (8, 4), (10, 4)])
+def test_encode_matches_oracle(lowering, k, m):
     mat = reed_sol_van_matrix(k, m)
     data = _rand(3, k, 256)
     want = R.encode_ref(mat, data)
-    got = np.asarray(_apply(mat, data, impl))
+    got = np.asarray(lowering(mat, data))
     np.testing.assert_array_equal(got, want)
 
 
-@pytest.mark.parametrize("impl", IMPLS)
-def test_zero_and_identity_rows(impl):
+@pytest.mark.parametrize("lowering", LOWERINGS, ids=_ids)
+def test_zero_and_identity_rows(lowering):
     # degenerate coefficients exercise the zero-skip paths
     mat = np.array([[0, 0, 0], [1, 0, 0], [2, 3, 0]], dtype=np.uint8)
     data = _rand(2, 3, 128, seed=1)
     want = R.encode_ref(mat, data)
-    got = np.asarray(_apply(mat, data, impl))
+    got = np.asarray(lowering(mat, data))
     np.testing.assert_array_equal(got, want)
 
 
-@pytest.mark.parametrize("impl", IMPLS)
-def test_decode_roundtrip_all_erasure_patterns(impl):
+@pytest.mark.parametrize("lowering", LOWERINGS, ids=_ids)
+def test_decode_roundtrip_all_erasure_patterns(lowering):
     k, m = 4, 2
     mat = reed_sol_van_matrix(k, m)
     data = _rand(2, k, 128, seed=2)
@@ -65,30 +59,65 @@ def test_decode_roundtrip_all_erasure_patterns(impl):
             D = R.decode_matrix(mat, list(erased), k)
             survivors = sorted(have)[:k]
             stack = np.stack([have[s] for s in survivors], axis=1)
-            rec = np.asarray(_apply(D, stack, impl))
+            rec = np.asarray(lowering(D, stack))
             for idx, e in enumerate(erased):
                 np.testing.assert_array_equal(rec[:, idx, :], chunks_all[e],
-                                              err_msg=f"erased={erased} impl={impl}")
+                                              err_msg=f"erased={erased}")
 
 
-def test_traced_matrix_matches_static():
-    import jax.numpy as jnp
-    k, m = 4, 2
-    mat = reed_sol_van_matrix(k, m)
-    data = _rand(2, k, 64, seed=3)
-    want = R.encode_ref(mat, data)
-    got = np.asarray(K.apply_matrix_traced(jnp.asarray(mat), jnp.asarray(data)))
-    np.testing.assert_array_equal(got, want)
+# -- the rule: which lowering a matrix gets --------------------------------
+
+def _cell_matrix(what):
+    """The matrices the benchmark's cells compile (jerasure
+    reed_sol_van k=8 m=3): encode, a decode for one to three erasures,
+    an RMW delta's columns."""
+    coder = factory("plugin=jerasure technique=reed_sol_van k=8 m=3")
+    if what == "encode":
+        return coder.matrix
+    if what == "delta":
+        return coder.delta_matrix((1, 6))
+    lost = {"decode1": [3], "decode2": [0, 9], "decode3": [2, 5, 10]}[what]
+    surv = [i for i in range(11) if i not in lost][:8]
+    return R.decode_matrix(coder.matrix, lost, 8, surv)
 
 
-def test_traced_matrix_batched():
-    import jax.numpy as jnp
-    rng = np.random.default_rng(4)
-    mats = rng.integers(0, 256, size=(3, 2, 4), dtype=np.uint8)
-    data = rng.integers(0, 256, size=(3, 4, 32), dtype=np.uint8)
-    want = np.stack([R.encode_ref(mats[i], data[i]) for i in range(3)])
-    got = np.asarray(K.apply_matrix_traced(jnp.asarray(mats), jnp.asarray(data)))
-    np.testing.assert_array_equal(got, want)
+@pytest.mark.parametrize(
+    "what", ["encode", "decode1", "decode2", "decode3", "delta"])
+def test_rule_unrolls_every_matrix_of_the_cells(what):
+    mat = _cell_matrix(what)
+    assert mat.size <= 24
+    assert K._lowering(mat) is K._apply_bitlinear
+
+
+@pytest.mark.parametrize("profile,shape", [
+    ("plugin=clay k=4 m=2 d=5", (16, 32)),
+    ("plugin=clay k=8 m=4 d=11", (256, 512)),
+])
+def test_rule_takes_clays_solved_matrices_dense(profile, shape):
+    """Through the unrolled form the k=8 m=4 d=11 matrix is 4,096
+    traced terms a parity row and its first encode does not finish in
+    minutes: that the default path builds and encodes here is the
+    rule at work."""
+    clay = factory(profile)
+    D, _ = clay._affine_decode(tuple(range(clay.k, clay.k + clay.m)),
+                               tuple(range(clay.k)))
+    assert D.shape == shape
+    assert K._lowering(D) is K._apply_mxu
+    data = _rand(1, clay.k, clay.sub_chunk_count * 128, seed=5)
+    stacked = data.reshape(1, clay.k * clay.sub_chunk_count, 128)
+    want = R.encode_ref(D, stacked).reshape(1, clay.m, -1)
+    np.testing.assert_array_equal(clay.encode_chunks(data), want)
+
+
+@pytest.mark.parametrize("side", ["unrolled", "dense"])
+def test_make_encoder_each_side_of_the_threshold(side):
+    k = K._UNROLL_MAX_ENTRIES // 8 + (side == "dense")   # 8 rows
+    mat = np.random.default_rng(6).integers(0, 256, (8, k), np.uint8)
+    assert K._lowering(mat) is (K._apply_bitlinear if side == "unrolled"
+                                else K._apply_mxu)
+    data = _rand(2, k, 128, seed=7)
+    np.testing.assert_array_equal(np.asarray(K.make_encoder(mat)(data)),
+                                  R.encode_ref(mat, data))
 
 
 def test_make_encoder_caches():
@@ -97,8 +126,8 @@ def test_make_encoder_caches():
     # bucketing wrapper is a thin lambda over that shared program
     assert K.make_encoder(mat, bucket_batch=False) \
         is K.make_encoder(mat.copy(), bucket_batch=False)
-    assert K._make_jitted(mat.tobytes(), 2, 4, K.DEFAULT_IMPL) \
-        is K._make_jitted(mat.copy().tobytes(), 2, 4, K.DEFAULT_IMPL)
+    assert K._make_jitted(mat.tobytes(), 2, 4) \
+        is K._make_jitted(mat.copy().tobytes(), 2, 4)
 
 
 def test_bucketed_encoder_matches_exact():

@@ -14,7 +14,7 @@ from cluster_helpers import corpus, make_cluster
 
 def ec_be(k=4, m=2, chunk=256):
     cluster = ShardSet()
-    be = ECBackend(f"plugin=tpu_rs k={k} m={m} impl=bitlinear", "1.0",
+    be = ECBackend(f"plugin=tpu_rs k={k} m={m}", "1.0",
                    list(range(k + m)), cluster, chunk_size=chunk)
     return be, cluster
 

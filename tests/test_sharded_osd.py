@@ -22,7 +22,7 @@ from ceph_tpu.utils.encoding import Decoder, Encoder
 def sharded_cluster():
     c = StandaloneCluster(
         n_osds=4, pg_num=4, op_shards=2, msgr_workers=2,
-        profile="plugin=tpu_rs k=2 m=1 impl=bitlinear")
+        profile="plugin=tpu_rs k=2 m=1")
     c.wait_for_clean(timeout=30)
     yield c
     c.shutdown()
@@ -173,7 +173,7 @@ class TestHostEncodeParity:
         from ceph_tpu.osd.ecbackend import ECBackend, ShardSet
         if not EB._host_crc_available():
             pytest.skip("native codec/hw-crc unavailable")
-        profile = "plugin=tpu_rs k=4 m=2 impl=bitlinear"
+        profile = "plugin=tpu_rs k=4 m=2"
         be = ECBackend(profile, "1.0", list(range(6)), ShardSet(),
                        chunk_size=256)
         rng = np.random.default_rng(7)

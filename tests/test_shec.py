@@ -127,7 +127,7 @@ def test_want_available_passthrough():
 
 def test_device_impl_matches_ref():
     ref = make(4, 3, 2)
-    dev = Shec({"k": "4", "m": "3", "c": "2", "impl": "bitlinear"})
+    dev = Shec({"k": "4", "m": "3", "c": "2"})
     rng = np.random.default_rng(9)
     data = rng.integers(0, 256, size=(2, 4, 256), dtype=np.uint8)
     np.testing.assert_array_equal(ref.encode_chunks(data),

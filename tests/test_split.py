@@ -13,7 +13,7 @@ from ceph_tpu.osd.cluster import SimCluster
 
 
 def make(n_osds=12, pg_num=4, **kw):
-    kw.setdefault("profile", "plugin=tpu_rs k=4 m=2 impl=bitlinear")
+    kw.setdefault("profile", "plugin=tpu_rs k=4 m=2")
     c = SimCluster(n_osds=n_osds, pg_num=pg_num, **kw)
     return c, Objecter(c)
 

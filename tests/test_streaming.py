@@ -22,9 +22,8 @@ class TestTiledEncoder:
     def test_matches_oneshot_and_oracle(self):
         mat = reed_sol_van_matrix(K, M)
         d = data(L=1 << 15)
-        tiled = np.asarray(make_tiled_encoder(mat, "bitlinear",
-                                              tile=1 << 12)(d))
-        oneshot = np.asarray(make_encoder(mat, "bitlinear")(d))
+        tiled = np.asarray(make_tiled_encoder(mat, tile=1 << 12)(d))
+        oneshot = np.asarray(make_encoder(mat)(d))
         assert np.array_equal(tiled, oneshot)
         want = np.stack([encode_ref(mat, d[b]) for b in range(len(d))])
         assert np.array_equal(tiled, want)
@@ -32,14 +31,14 @@ class TestTiledEncoder:
     def test_rejects_ragged_length(self):
         mat = reed_sol_van_matrix(K, M)
         with pytest.raises(ValueError, match="multiple"):
-            make_tiled_encoder(mat, "bitlinear", tile=1 << 12)(
+            make_tiled_encoder(mat, tile=1 << 12)(
                 data(L=(1 << 12) + 100))
 
 
 class TestStreamingCodec:
     def test_encode_matches_oracle_exact_tiles(self):
         mat = reed_sol_van_matrix(K, M)
-        sc = StreamingCodec(mat, "bitlinear", tile=1 << 13)
+        sc = StreamingCodec(mat, tile=1 << 13)
         d = data(L=1 << 15, seed=1)
         got = sc.encode(d)
         want = np.stack([encode_ref(mat, d[b]) for b in range(len(d))])
@@ -47,7 +46,7 @@ class TestStreamingCodec:
 
     def test_ragged_tail_exact(self):
         mat = reed_sol_van_matrix(K, M)
-        sc = StreamingCodec(mat, "bitlinear", tile=1 << 12)
+        sc = StreamingCodec(mat, tile=1 << 12)
         d = data(L=(1 << 12) * 3 + 777, seed=2)
         got = sc.encode(d)
         want = np.stack([encode_ref(mat, d[b]) for b in range(len(d))])
@@ -55,7 +54,7 @@ class TestStreamingCodec:
 
     def test_single_small_object(self):
         mat = reed_sol_van_matrix(K, M)
-        sc = StreamingCodec(mat, "bitlinear", tile=1 << 12)
+        sc = StreamingCodec(mat, tile=1 << 12)
         d = data(B=1, L=100, seed=3)
         got = sc.encode(d)
         want = encode_ref(mat, d[0])[None]
@@ -65,22 +64,20 @@ class TestStreamingCodec:
         # decode is the same streamed matmul with a decode matrix
         mat = reed_sol_van_matrix(K, M)
         d = data(L=(1 << 12) * 2 + 19, seed=4)
-        parity = StreamingCodec(mat, "bitlinear",
-                                tile=1 << 12).encode(d)
+        parity = StreamingCodec(mat, tile=1 << 12).encode(d)
         erasures = [1, K]  # one data, one parity shard
         survivors = [i for i in range(K + M) if i not in erasures][:K]
         D = decode_matrix(mat, erasures, K, survivors)
         full = np.concatenate([d, parity], axis=1)
         surv = full[:, survivors]
-        rebuilt = StreamingCodec(D, "bitlinear",
-                                 tile=1 << 12).encode(surv)
+        rebuilt = StreamingCodec(D, tile=1 << 12).encode(surv)
         assert np.array_equal(rebuilt, full[:, erasures])
 
     def test_larger_than_tile_budget(self):
         # 3 MiB chunks through 256 KiB tiles: 12 tiles, depth 2 ->
         # never more than 2 tiles in flight; output byte-exact
         mat = reed_sol_van_matrix(K, M)
-        sc = StreamingCodec(mat, "bitlinear", tile=1 << 18, depth=2)
+        sc = StreamingCodec(mat, tile=1 << 18, depth=2)
         d = data(B=1, L=3 << 20, seed=5)
         got = sc.encode(d)
         want = encode_ref(mat, d[0])[None]

@@ -161,7 +161,7 @@ def test_thrash_transient_smoke(seed, tmp_path):
     probe, so the zero-byte check provably fired."""
     th = Thrasher(seed, store="mem", rounds=1, ops=4,
                   transient_fraction=0.9, n_osds=7,
-                  profile="plugin=tpu_rs k=2 m=3 impl=bitlinear")
+                  profile="plugin=tpu_rs k=2 m=3")
     report = th.run()
     assert report["transient_kills"] > 0, report
     # the zero-byte claim fired — or was provably skipped because the
@@ -184,7 +184,7 @@ def test_thrash_transient_matrix(seed, store, fraction, tmp_path):
     invariants as the smoke, checked after every heal."""
     th = Thrasher(seed, store=store, rounds=2, ops=5,
                   transient_fraction=fraction, n_osds=7,
-                  profile="plugin=tpu_rs k=2 m=3 impl=bitlinear",
+                  profile="plugin=tpu_rs k=2 m=3",
                   store_dir=str(tmp_path / "osds")
                   if store == "tin" else None)
     report = th.run()

@@ -50,27 +50,14 @@ def _compile(fn, one_chip, *shapes_dtypes):
     return jax.jit(fn).lower(*args).compile()
 
 
-@pytest.mark.parametrize("impl", ["bitlinear", "mxu", "logexp"])
-def test_rs_encode_compiles_at_32_objects(one_chip, coder, impl):
-    from ceph_tpu.ops.rs_kernels import make_encoder
-    enc = make_encoder(coder.matrix, impl, bucket_batch=False)
+@pytest.mark.parametrize("lowering", ["_apply_bitlinear", "_apply_mxu"])
+def test_rs_encode_compiles_at_32_objects(one_chip, coder, lowering):
+    import functools
+
+    from ceph_tpu.ops import rs_kernels
+    enc = functools.partial(getattr(rs_kernels, lowering), coder.matrix)
     out = _compile(enc, one_chip, ((32, K, SHARD), np.uint8))
     assert out.memory_analysis().temp_size_in_bytes < 2048 * MiB
-
-
-def test_pallas_kernel_compiles_without_interpret(one_chip, coder):
-    """The kernel itself, on packed uint32 slabs, at 32 objects: 1 s and
-    no scratch. (`apply_matrix_pallas` around it packs uint8 into
-    uint32 through a minor dim of 4; that relayout, not the kernel,
-    is the 55-80 s and 2.5 GiB its whole program costs — ROADMAP C2.)"""
-    from ceph_tpu.ops import pallas_gf
-    n_slabs = SHARD // 4 // pallas_gf._LANES
-    kernel = pallas_gf._build(coder.matrix.tobytes(), M, K, n_slabs,
-                              pallas_gf._SUBLANES, False)
-    out = _compile(kernel, one_chip,
-                   ((32, K, n_slabs, pallas_gf._LANES), np.uint32))
-    assert "tpu_custom_call" in out.as_text()
-    assert out.memory_analysis().temp_size_in_bytes < 16 * MiB
 
 
 def _no_gather(compiled):
@@ -92,8 +79,8 @@ def test_crc32c_rows_of_one_object_fit(one_chip):
 
 def _fused_write(one_chip, coder, bucket):
     from ceph_tpu.osd.ecbackend import ECBackend
-    fn = ECBackend._fused_write_fn(coder.matrix.tobytes(), M, K,
-                                   coder.impl, SHARD, bucket)
+    fn = ECBackend._fused_write_fn(coder.matrix.tobytes(), M, K, SHARD,
+                                   bucket)
     return _compile(fn, one_chip, ((bucket, K, SHARD), np.uint8))
 
 

@@ -144,7 +144,7 @@ class TestLiveTwoTenantSmoke:
             slo="client_observed_p99 < 1ms over 60s")
         c = StandaloneCluster(
             n_osds=3, pg_num=2, cephx=True, secret=os.urandom(32),
-            profile="plugin=tpu_rs k=2 m=1 impl=bitlinear",
+            profile="plugin=tpu_rs k=2 m=1",
             chunk_size=1024, op_timeout=6.0 * _lf())
         try:
             c.wait_for_clean(timeout=40 * _lf())
@@ -199,7 +199,7 @@ class TestWorkloadBenchLive:
         workload_bench.main([
             "--duration", "4", "--seed", "3",
             "--num-osds", "4", "--pg-num", "2",
-            "--profile", "plugin=tpu_rs k=2 m=1 impl=bitlinear",
+            "--profile", "plugin=tpu_rs k=2 m=1",
             "--chunk-size", "2048", "--json",
             "--out", str(out_path)])
         out = json.loads(capsys.readouterr().out)

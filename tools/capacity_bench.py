@@ -158,7 +158,7 @@ def cell_backfillfull_recovery(secret, seed):
     rng = np.random.default_rng(seed)
     c = StandaloneCluster(n_osds=7, pg_num=4, op_timeout=3.0,
                           cephx=True, secret=secret,
-                          profile="plugin=tpu_rs k=2 m=3 impl=bitlinear")
+                          profile="plugin=tpu_rs k=2 m=3")
     try:
         c.wait_for_clean(timeout=30)
         cl = c.client()

@@ -3,8 +3,8 @@
 Mirrors src/test/erasure-code/ceph_erasure_code_benchmark.cc
 (ErasureCodeBench::{setup,run,encode,decode}; CLI: --plugin --parameter
 k=.. m=.. --size --iterations --workload encode|decode --erasures),
-extended with TPU batching knobs (--batch, --impl) since the unit of work
-here is a batch of objects, not one buffer.
+extended with a TPU batching knob (--batch) since the unit of work here
+is a batch of objects, not one buffer.
 
 Examples:
   python tools/ec_benchmark.py --plugin tpu_rs -P k=8 -P m=3 \
@@ -45,15 +45,13 @@ def parse_args(argv=None):
                     help="stream host-resident chunks through the device "
                          "in tiles of this many bytes (the >HBM object "
                          "path; plain RS only)")
-    ap.add_argument("--impl", default=None,
-                    help="kernel lowering: bitlinear | mxu | logexp | auto")
     ap.add_argument("--json", action="store_true", help="emit one JSON line")
     return ap.parse_args(argv)
 
 
 def run_bench(plugin: str, profile: dict, size: int, batch: int,
               iterations: int, workload: str, erasures: int,
-              impl: str | None, stream_tile: int = 0) -> dict:
+              stream_tile: int = 0) -> dict:
     """Returns {seconds, gbps, bytes_per_iter, ...}. Timing covers only the
     codec region, like ErasureCodeBench::encode/decode (buffers prepared
     outside the loop, one warmup launch excluded for jit compile)."""
@@ -61,7 +59,7 @@ def run_bench(plugin: str, profile: dict, size: int, batch: int,
 
     from ceph_tpu.ec import registry
     from ceph_tpu.gf.numpy_ref import decode_matrix
-    from ceph_tpu.ops.rs_kernels import DEFAULT_IMPL, make_encoder
+    from ceph_tpu.ops.rs_kernels import make_encoder
 
     prof = dict(profile)
     if plugin is not None:
@@ -71,9 +69,6 @@ def run_bench(plugin: str, profile: dict, size: int, batch: int,
         prof["plugin"] = plugin
     prof.setdefault("plugin", "tpu_rs")
     plugin = prof["plugin"]
-    if impl and impl != "auto":
-        prof["impl"] = impl
-    impl_used = prof.get("impl", DEFAULT_IMPL)
     try:
         coder = registry.factory(prof)
     except ValueError as e:
@@ -110,14 +105,14 @@ def run_bench(plugin: str, profile: dict, size: int, batch: int,
             # timing includes host<->device transfers: that IS the
             # workload being measured.
             from ceph_tpu.ops.streaming import StreamingCodec
-            sc = StreamingCodec(mat, impl_used, tile=stream_tile)
+            sc = StreamingCodec(mat, tile=stream_tile)
             out_buf = sc.encode(data)  # warmup / compile
             t0 = time.perf_counter()
             for _ in range(iterations):
                 sc.encode(data, out=out_buf)
             dt = time.perf_counter() - t0
         else:
-            fn = make_encoder(mat, impl_used, bucket_batch=False)
+            fn = make_encoder(mat, bucket_batch=False)
             operand = jax.device_put(data)
             fn(operand).block_until_ready()  # warmup / compile
             t0 = time.perf_counter()
@@ -128,7 +123,6 @@ def run_bench(plugin: str, profile: dict, size: int, batch: int,
     else:
         # layered / non-MDS plugins (clay, lrc, shec): time the full
         # plugin path, including their own recovery planning
-        impl_used = getattr(coder, "impl", impl_used)
         if workload == "encode":
             run = lambda: coder.encode_chunks(data)  # noqa: E731
         else:
@@ -157,7 +151,6 @@ def run_bench(plugin: str, profile: dict, size: int, batch: int,
         "plugin": plugin, "k": k, "m": m, "chunk_size": cs,
         "object_size": size, "batch": batch, "iterations": iterations,
         "workload": workload, "erasures": erasures if workload == "decode" else 0,
-        "impl": impl_used,
         "seconds": dt,
         "bytes_per_iter": payload,
         "gbps": payload * iterations / dt / 1e9,
@@ -172,34 +165,16 @@ def main(argv=None) -> None:
         profile = profile_from_string(" ".join(args.parameter))
     except ValueError as e:
         raise SystemExit(f"--parameter: {e}")
-    plugin_name = args.plugin or profile.get("plugin", "tpu_rs")
-    from ceph_tpu.ec import registry
-    from ceph_tpu.ec.rs import ReedSolomon
-    try:
-        fac = registry.get_factory(plugin_name)
-    except ValueError:
-        fac = None
-    plain_rs = isinstance(fac, type) and issubclass(fac, ReedSolomon)
-    if args.impl and args.impl != "auto":
-        impls = [args.impl]
-    elif plain_rs:
-        impls = ["bitlinear", "mxu"]
-    else:
-        impls = [None]  # layered plugins pick their own kernel impl
-    results = [run_bench(args.plugin, profile, args.size, args.batch,
-                         args.iterations, args.workload, args.erasures, i,
-                         stream_tile=args.stream_tile)
-               for i in impls]
-    best = max(results, key=lambda r: r["gbps"])
+    r = run_bench(args.plugin, profile, args.size, args.batch,
+                  args.iterations, args.workload, args.erasures,
+                  stream_tile=args.stream_tile)
     if args.json:
-        print(json.dumps(best))
+        print(json.dumps(r))
     else:
-        for r in results:
-            star = "*" if r is best else " "
-            print(f"{star} {r['workload']} {r['plugin']} k={r['k']} m={r['m']} "
-                  f"impl={r['impl']}: {r['seconds']:.3f}s for "
-                  f"{r['iterations']}x{r['bytes_per_iter'] / 1e6:.1f} MB "
-                  f"-> {r['gbps']:.2f} GB/s [{r['backend']}]")
+        print(f"{r['workload']} {r['plugin']} k={r['k']} m={r['m']}: "
+              f"{r['seconds']:.3f}s for "
+              f"{r['iterations']}x{r['bytes_per_iter'] / 1e6:.1f} MB "
+              f"-> {r['gbps']:.2f} GB/s [{r['backend']}]")
 
 
 if __name__ == "__main__":

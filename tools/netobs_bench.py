@@ -63,7 +63,7 @@ def _boot(secret, n_osds=4, pg_num=4):
     c = StandaloneCluster(n_osds=n_osds, pg_num=pg_num,
                           hb_interval=0.25, hb_grace=2.0,
                           op_timeout=5.0, cephx=True, secret=secret,
-                          profile="plugin=tpu_rs k=2 m=1 impl=bitlinear")
+                          profile="plugin=tpu_rs k=2 m=1")
     c.wait_for_clean(timeout=40)
     cl = c.client()
     cl.config_set("mgr_report_interval", 0.5)
@@ -268,7 +268,7 @@ def main(argv=None) -> None:
         "config": {"seed": args.seed, "cephx": True, "secure": True,
                    "hb_interval_s": 0.25, "hb_grace_s": 2.0,
                    "mgr_report_interval_s": 0.5,
-                   "profile": "plugin=tpu_rs k=2 m=1 impl=bitlinear"},
+                   "profile": "plugin=tpu_rs k=2 m=1"},
         "cells": {"link_degrade": ld,
                   "helper_avoidance": ha,
                   "overhead_guard": og},

@@ -57,7 +57,7 @@ def cmd_demo(args) -> None:
           f"{summary['bytes']} bytes -> {path}")
 
     dst = SimCluster(n_osds=12, pg_num=8,
-                     profile="plugin=tpu_rs k=8 m=3 impl=bitlinear",
+                     profile="plugin=tpu_rs k=8 m=3",
                      chunk_size=128)
     res = import_objects(dst, path)
     print(f"imported into fresh cluster (source profile "

@@ -40,7 +40,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 SCHEMA = "profile_r19/1"
 N_OSDS = 3
 PG_NUM = 2
-PROFILE = "plugin=tpu_rs k=2 m=1 impl=bitlinear"
+PROFILE = "plugin=tpu_rs k=2 m=1"
 
 
 def _delta_block(osds, before: dict, wall_s: float) -> dict:

@@ -228,7 +228,7 @@ def main(argv=None) -> None:
     except Exception:   # noqa: BLE001 — no compiler: jax paths serve
         pass
 
-    profile = (args.profile or "plugin=tpu_rs k=4 m=2 impl=bitlinear") \
+    profile = (args.profile or "plugin=tpu_rs k=4 m=2") \
         if args.pool == "ec" else "replicated size=3"
     shutdown = None
     if args.transport == "standalone":

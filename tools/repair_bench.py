@@ -40,7 +40,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 SCHEMA = "repair_r17/1"
 
-PROFILE = "plugin=tpu_rs k=2 m=3 impl=bitlinear"
+PROFILE = "plugin=tpu_rs k=2 m=3"
 N_OSDS = 8
 PG_NUM = 4
 M = 3

@@ -48,7 +48,7 @@ def run_cell(seed: int, store: str, rounds: int, ops: int,
         kwargs["transient_fraction"] = transient_fraction
         kwargs["n_osds"] = n_osds if n_osds is not None else 7
         kwargs["profile"] = profile or \
-            "plugin=tpu_rs k=2 m=3 impl=bitlinear"
+            "plugin=tpu_rs k=2 m=3"
     elif n_osds is not None:
         kwargs["n_osds"] = n_osds
     th = Thrasher(seed, store=store, rounds=rounds, ops=ops,

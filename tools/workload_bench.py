@@ -82,7 +82,7 @@ def main(argv=None) -> None:
     ap.add_argument("--num-osds", type=int, default=6)
     ap.add_argument("--pg-num", type=int, default=4)
     ap.add_argument("--profile",
-                    default="plugin=tpu_rs k=4 m=2 impl=bitlinear")
+                    default="plugin=tpu_rs k=4 m=2")
     ap.add_argument("--chunk-size", type=int, default=4096)
     ap.add_argument("--history-interval", type=float, default=0.5)
     ap.add_argument("--no-kill", action="store_true",
