@@ -487,13 +487,13 @@ class Clay(ErasureCode):
         """(B, nin, sub) -> (B, nout, sub) via the cached GF matrix."""
         if self.ref_oracle:
             return encode_ref(D, stacked)
-        from ..ops.rs_kernels import make_encoder
+        from ..ops.rs_kernels import make_host_encoder
         fid = id(D)
         fn = self._fn_cache.get(fid)
         if fn is None:
-            fn = make_encoder(D)
+            fn = make_host_encoder(D)
             self._fn_cache[fid] = fn
-        return np.asarray(fn(stacked))
+        return fn(stacked)
 
     def _split(self, chunk: np.ndarray) -> np.ndarray:
         """(..., L) chunk -> (..., q^t, sub) sub-chunks."""

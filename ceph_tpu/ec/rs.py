@@ -10,6 +10,16 @@ Encode: parity = C (GF@) data on device with a static matrix.
 Decode: invert the surviving k x k submatrix on host (tiny, like
 jerasure_matrix_decode does) and run the same static-matrix device kernel
 with the decode matrix; decode matrices are cached per erasure pattern.
+
+Two faces of one matrix. `encode_chunks` and `decode_chunks` take host
+rows and return host rows: they run the HOST FACE
+(`rs_kernels.make_host_encoder`), where the bytes cross the link as
+uint32 words both ways and the result's copy to the host is a plain
+copy (as uint8 `(B, m, L)` the parity left the device tiled with the
+batch in m's place, and de-tiling it on the host was 84 of a 108 ms
+call at 32 x 4 MiB: PERF.md, PR 30). `batch_decoder` hands out the
+DEVICE-RESIDENT program (`make_encoder`) that the served path composes
+with its crc programs; its shape, dtype and HLO are what they were.
 """
 
 from __future__ import annotations
@@ -19,7 +29,7 @@ from typing import Mapping, Sequence
 import numpy as np
 
 from ..gf.numpy_ref import decode_matrix
-from ..ops.rs_kernels import make_encoder
+from ..ops.rs_kernels import make_encoder, make_host_encoder
 from .interface import ErasureCode
 from .matrices import coding_matrix
 from .registry import register
@@ -36,11 +46,11 @@ class ReedSolomon(ErasureCode):
         if self.k < 1 or self.m < 1 or self.k + self.m > 256:
             raise ValueError(f"bad geometry k={self.k} m={self.m} (w=8)")
         self.matrix = coding_matrix(technique, self.k, self.m)
-        self._encode_fn = make_encoder(self.matrix)
+        self._encode_host = make_host_encoder(self.matrix)
         self._decode_cache: dict[tuple[tuple[int, ...], tuple[int, ...]], tuple] = {}
 
     def encode_chunks(self, data: np.ndarray) -> np.ndarray:
-        return np.asarray(self._encode_fn(np.asarray(data, np.uint8)))
+        return self._encode_host(data)
 
     def delta_matrix(self, touched):
         # exact: the parity-delta matrix IS the coding matrix's
@@ -56,7 +66,8 @@ class ReedSolomon(ErasureCode):
         hit = self._decode_cache.get(key)
         if hit is None:
             D = decode_matrix(self.matrix, list(erasures), self.k, list(survivors))
-            hit = (make_encoder(D), survivors)
+            # the device-resident program and the host face
+            hit = (make_encoder(D), make_host_encoder(D))
             self._decode_cache[key] = hit
         return hit
 
@@ -69,8 +80,7 @@ class ReedSolomon(ErasureCode):
         survivors = tuple(survivors)[:self.k]
         if len(survivors) < self.k:
             return None
-        fn, _ = self._decoder_for(erasures, survivors)
-        return fn
+        return self._decoder_for(erasures, survivors)[0]
 
     def decode_program_key(self, erasures: Sequence[int],
                            survivors: Sequence[int]):
@@ -90,12 +100,13 @@ class ReedSolomon(ErasureCode):
         if len(survivors) < self.k:
             raise ValueError(
                 f"need {self.k} chunks to decode, have {len(survivors)}")
-        fn, surv = self._decoder_for(erasures, survivors)
-        stack = np.stack([np.asarray(chunks[s], np.uint8) for s in surv], axis=-2)
+        _, host_fn = self._decoder_for(erasures, survivors)
+        stack = np.stack([np.asarray(chunks[s], np.uint8) for s in survivors],
+                         axis=-2)
         squeeze = stack.ndim == 2
         if squeeze:
             stack = stack[None]
-        rec = np.asarray(fn(stack))  # (B, E, L)
+        rec = host_fn(stack)  # (B, E, L)
         if squeeze:
             rec = rec[0]
         return {e: rec[..., i, :] for i, e in enumerate(erasures)}

@@ -124,15 +124,16 @@ class Shec(ErasureCode):
         self._encode_fn = self._matrix_fn(self.matrix)
 
     def _matrix_fn(self, X: np.ndarray):
-        """(B, cols, L) -> X (GF@) it: the device program, or for
-        impl=ref the numpy oracle."""
+        """Host (B, cols, L) -> host X (GF@) it: the matrix's host face
+        (rs_kernels.make_host_encoder), or for impl=ref the numpy
+        oracle."""
         if self.ref_oracle:
             from functools import partial
 
             from ..gf.numpy_ref import encode_ref
             return partial(encode_ref, X)
-        from ..ops.rs_kernels import make_encoder
-        return make_encoder(X)
+        from ..ops.rs_kernels import make_host_encoder
+        return make_host_encoder(X)
 
     def _verify_durability(self) -> None:
         n = self.k + self.m
@@ -239,7 +240,7 @@ class Shec(ErasureCode):
     # -- codec --------------------------------------------------------------
 
     def encode_chunks(self, data: np.ndarray) -> np.ndarray:
-        return np.asarray(self._encode_fn(np.asarray(data, np.uint8)))
+        return self._encode_fn(np.asarray(data, np.uint8))
 
     def _decoder_for(self, want: tuple[int, ...], surv: tuple[int, ...]):
         key = (want, surv)
@@ -263,7 +264,7 @@ class Shec(ErasureCode):
         if squeeze:
             arrs = [a[None] for a in arrs]
         stack = np.stack(arrs, axis=-2)
-        rec = np.asarray(fn(stack))
+        rec = fn(stack)
         if squeeze:
             rec = rec[0]
         return {w: rec[..., i, :] for i, w in enumerate(want)}

@@ -24,7 +24,7 @@ Wire format (little-endian, one length-prefixed frame per op):
 The matrix travels with every request, so the server is stateless per
 connection and exotic host-constructed techniques work unchanged
 (mirrors ec_create_with_matrix on the C side). Encoder closures are
-cached per matrix via ops.rs_kernels.make_encoder's lru cache.
+cached per matrix via ops.rs_kernels.make_host_encoder's lru cache.
 """
 
 from __future__ import annotations
@@ -151,16 +151,16 @@ class ECRuntimeServer:
         stack = payload.reshape(batch, k, chunk_len)
 
         from ..gf.numpy_ref import decode_matrix
-        from ..ops.rs_kernels import make_encoder
+        from ..ops.rs_kernels import make_host_encoder
         if op == OP_ENCODE:
-            fn = make_encoder(matrix)
+            fn = make_host_encoder(matrix)
         elif op == OP_DECODE:
             D = decode_matrix(matrix, [int(e) for e in erasures], k,
                               [int(s) for s in survivors])
-            fn = make_encoder(D)
+            fn = make_host_encoder(D)
         else:
             raise ValueError(f"unknown op {op}")
-        return np.ascontiguousarray(np.asarray(fn(stack))).tobytes()
+        return fn(stack).tobytes()
 
 
 def serve_forever(path: str) -> None:

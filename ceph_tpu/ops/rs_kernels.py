@@ -27,6 +27,28 @@ alone (`_lowering`, `_UNROLL_MAX_ENTRIES`):
       XOR), re-pack to bytes. Program size does not grow with the
       matrix: the form of Clay's solved plane matrices (256 x 512 at
       k=8 m=4 d=11), which the unrolled form cannot compile in minutes.
+
+Two faces of one matrix, and both lowerings serve both:
+
+  device-resident (`make_encoder`, `apply_matrix`) — uint8 (B, k, L) on
+      the device in, uint8 (B, m, L) on the device out: what the served
+      path composes with its crc programs in one launch
+      (`ECBackend._fused_write_fn`, the degraded read's decode,
+      recovery, `ops/streaming`).
+
+  host to host (`make_host_encoder`) — host rows in, host rows out:
+      `encode_chunks` / `decode_chunks` of the RS, LRC, SHEC and Clay
+      coders and `native/server.py`. The bytes cross the link as uint32
+      WORDS both ways (a free view on the host), the lowerings run on
+      the words themselves (`_apply_bitlinear_words`,
+      `_apply_mxu_words`: four bytes a lane, no byte leaves its place),
+      and the parity comes back as a flat (N, 128) uint32 array whose
+      device layout is byte for byte row-major host memory. A uint8
+      result leaves the device tiled, with the batch where m = 3 should
+      be, and the runtime's host threads undo that at 0.6 GB/s; bytes
+      cast to words on the device cost more than they save (the cast
+      wants a minor dimension of 4: 73.5 GB of padding, or a uint8
+      relayout at 1.4 GB/s). PERF.md, PR 30, has every form tried.
 """
 
 from __future__ import annotations
@@ -41,12 +63,25 @@ from ..gf.tables import bit_powers, matrix_to_bitmatrix
 
 Array = jax.Array
 
+#: the low bit of each of a word's four bytes
+_BYTE_LANES = np.uint32(0x01010101)
+
 
 def _check(data: Array, k: int) -> None:
     if data.ndim != 3:
         raise ValueError(f"data must be (batch, k, L) uint8, got {data.shape}")
     if data.shape[1] != k:
         raise ValueError(f"data has {data.shape[1]} shards, matrix expects {k}")
+
+
+def _is_words(data: Array) -> bool:
+    """Bytes (uint8, the device-resident callers' form) or the host
+    face's words (uint32: four consecutive bytes of a row a lane)."""
+    if data.dtype == jnp.uint8:
+        return False
+    if data.dtype == jnp.uint32:
+        return True
+    raise ValueError(f"data must be uint8 bytes or uint32 words, got {data.dtype}")
 
 
 # ---------------------------------------------------------------- bitlinear
@@ -56,6 +91,8 @@ def _apply_bitlinear(matrix: np.ndarray, data: Array) -> Array:
     m, k = matrix.shape
     _check(data, k)
     P = bit_powers()[matrix]  # (m, k, 8) uint8 numpy constants
+    if _is_words(data):
+        return _apply_bitlinear_words(P, data)
     acc = None
     for j in range(k):
         dj = data[:, j, :]  # (B, L)
@@ -73,6 +110,31 @@ def _apply_bitlinear(matrix: np.ndarray, data: Array) -> Array:
     return acc
 
 
+def _apply_bitlinear_words(P: np.ndarray, words: Array) -> Array:
+    """The same terms on four bytes a lane: bit b of each byte is taken
+    with one AND, spread to a 0x00/0xFF byte mask with no carry between
+    bytes, and ANDed with the constant in all four. One accumulator a
+    parity row, stacked at the end: as one (B, m, W) broadcast term,
+    the bytes' way, the result takes the batch-second-minor layout and
+    the flat form costs a relayout of 8.3 ms a 128 MiB call on the chip
+    (16.1 against 5.4 ms of device time, PERF.md, PR 30)."""
+    m, k, _ = P.shape
+    acc = [None] * m
+    for j in range(k):
+        dj = words[:, j, :]  # (B, W)
+        for b in range(8):
+            if not P[:, j, b].any():
+                continue
+            bits = (dj >> b) & _BYTE_LANES
+            mask = (bits << 8) - bits
+            for i in range(m):
+                if P[i, j, b]:
+                    term = mask & (P[i, j, b] * _BYTE_LANES)
+                    acc[i] = term if acc[i] is None else acc[i] ^ term
+    zero = jnp.zeros_like(words[:, 0, :])
+    return jnp.stack([zero if a is None else a for a in acc], axis=1)
+
+
 # ---------------------------------------------------------------- mxu
 
 def _apply_mxu(matrix: np.ndarray, data: Array) -> Array:
@@ -81,6 +143,8 @@ def _apply_mxu(matrix: np.ndarray, data: Array) -> Array:
     _check(data, k)
     B, _, L = data.shape
     bm = matrix_to_bitmatrix(matrix)  # (m*8, k*8) in {0,1}
+    if _is_words(data):
+        return _apply_mxu_words(bm, data)
     shifts = jnp.arange(8, dtype=jnp.uint8)
     bits = (data[:, :, None, :] >> shifts[None, None, :, None]) & 1  # (B,k,8,L)
     x = bits.reshape(B, k * 8, L).astype(jnp.int8)
@@ -93,6 +157,28 @@ def _apply_mxu(matrix: np.ndarray, data: Array) -> Array:
     )  # (m*8, B, L)
     pbits = (pbits & 1).astype(jnp.uint8).transpose(1, 0, 2).reshape(B, m, 8, L)
     return jnp.bitwise_xor.reduce(pbits << shifts[None, None, :, None], axis=2)
+
+
+def _apply_mxu_words(bm: np.ndarray, words: Array) -> Array:
+    """The dense form on words: bit s of byte q of a word is bit 8q+s of
+    the lane, so the planes come out of the words by shifts as they do
+    out of bytes, a byte position more along the free axis, and the
+    parity bits go back in by the same shifts. No byte is moved."""
+    B, k, W = words.shape
+    m = bm.shape[0] // 8
+    # [s, q] = 8q + s: planes contract over (k, s); (q, W) is free
+    shifts = (jnp.arange(8, dtype=jnp.uint32)[:, None]
+              + 8 * jnp.arange(4, dtype=jnp.uint32)[None, :])
+    bits = (words[:, :, None, None, :] >> shifts[None, None, :, :, None]) & 1
+    x = bits.reshape(B, k * 8, 4 * W).astype(jnp.int8)
+    pbits = jax.lax.dot_general(
+        jnp.asarray(bm, dtype=jnp.int8), x,
+        dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.int32,
+    )  # (m*8, B, 4*W)
+    pbits = (pbits & 1).astype(jnp.uint32).transpose(1, 0, 2)
+    pbits = pbits.reshape(B, m, 8, 4, W) << shifts[None, None, :, :, None]
+    return jnp.bitwise_xor.reduce(pbits, axis=(2, 3))
 
 
 # ---------------------------------------------------------------- the rule
@@ -130,6 +216,25 @@ def apply_matrix(matrix: np.ndarray, data: Array) -> Array:
 def _make_jitted(matrix_bytes: bytes, m: int, k: int):
     matrix = np.frombuffer(matrix_bytes, dtype=np.uint8).reshape(m, k)
     return jax.jit(functools.partial(_lowering(matrix), matrix))
+
+
+#: bytes of one row of the flat transfer form: 128 words
+_FLAT_ROW = 512
+
+
+@functools.lru_cache(maxsize=128)
+def _make_jitted_words(matrix_bytes: bytes, m: int, k: int):
+    """The host face's program: words in, the parity's words out as
+    (N, 128) uint32, whose (8, 128) tiles lie in device memory in
+    row-major order, so the copy to the host has nothing to de-tile or
+    transpose. (A 1-D result is as linear but compiles in 29 s where
+    this takes 2: described-chip compiles, PERF.md, PR 30.)"""
+    matrix = np.frombuffer(matrix_bytes, dtype=np.uint8).reshape(m, k)
+    lowering = _lowering(matrix)
+
+    def words_program(words):
+        return lowering(matrix, words).reshape(-1, _FLAT_ROW // 4)
+    return jax.jit(words_program)
 
 
 def pow2_bucket(n: int) -> int:
@@ -177,3 +282,47 @@ def make_encoder(matrix: np.ndarray, bucket_batch: bool = True):
         return jitted
     return lambda data: run_bucketed(jitted,
                                      jnp.asarray(data, jnp.uint8))
+
+
+def make_host_encoder(matrix: np.ndarray):
+    """The host-to-host face of the matrix's program: host uint8
+    (B, k, L) in, host uint8 (B, m, L) out, C-contiguous, every byte on
+    the host when the call returns (`encode_chunks`, `decode_chunks`).
+
+    Both ways the bytes travel as uint32 words. The rows go in as the
+    free view `data.view(uint32)`, (B, k, L/4), the program multiplies on
+    the words themselves (four bytes a lane; a byte never changes its
+    place in a word, so no byte order is assumed), and the parity comes
+    back as ONE flat (N, 128) uint32 array, which the host views as bytes
+    again. A uint8 result of this shape leaves the device in layout
+    `{2,0,1:T(8,128)(4,1)}` (m = 3 does not fill a tile, so the batch
+    takes its place) and the runtime's host threads de-tile and
+    transpose it: 84 of a 108 ms call at 32 x 4 MiB, against 12 ms for
+    the same bytes as words (PERF.md, PR 30). Turning bytes into words
+    on the device costs more than it saves (XLA relayouts uint8 at
+    1.4 GB/s), which is why the multiply runs on words and not before a
+    cast.
+
+    One path for every shape: a row length that is no multiple of 512
+    bytes (128 words, a row of the flat form) is zero-padded here and
+    the pad sliced off the view (the one case that copies on the host:
+    zero columns give zero parity); the batch is bucketed to a power of
+    two as `make_encoder` does, and the bucket's spare rows are sliced
+    off the view, not on the device. Device-resident callers keep
+    `make_encoder`: its program, shape and dtype are unchanged."""
+    matrix = np.ascontiguousarray(matrix, dtype=np.uint8)
+    m, k = matrix.shape
+    jitted = _make_jitted_words(matrix.tobytes(), m, k)
+
+    def encode(data) -> np.ndarray:
+        data = np.asarray(data, np.uint8)
+        _check(data, k)
+        L = data.shape[2]
+        tail = -L % _FLAT_ROW
+        if tail:
+            data = np.pad(data, ((0, 0), (0, 0), (0, tail)))
+        words, B = pad_to_bucket(np.ascontiguousarray(data).view(np.uint32))
+        flat = np.asarray(jitted(words))
+        parity = flat.view(np.uint8).reshape(words.shape[0], m, L + tail)
+        return np.ascontiguousarray(parity[:B, :, :L])
+    return encode

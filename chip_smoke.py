@@ -50,7 +50,9 @@ def check_decodes(decode, chunks: np.ndarray) -> None:
 def codec_phase(n_objects: int, shard_bytes: int) -> None:
     """The pool's profile as the cells state it: one encode launch over
     n_objects, parity equal to the numpy oracle, two degraded decodes
-    equal to the originals (its matrices take the unrolled lowering).
+    equal to the originals (its matrices take the unrolled lowering),
+    and the host clock of one more `encode_chunks` call of the same
+    shape, warm: host rows in, parity on the host (the host face).
     Then one encode through a matrix on the dense side of the rule
     (Clay k=4 m=2 d=5's solved 16 x 32) against the codec's numpy
     oracle, so both lowerings stay proved on the chip."""
@@ -70,8 +72,12 @@ def codec_phase(n_objects: int, shard_bytes: int) -> None:
         got = coder.decode_chunks(lost, {s: chunks[:, s, :] for s in surv})
         return np.stack([got[e] for e in lost], axis=1)
     check_decodes(decode, chunks)
+    t1 = time.perf_counter()
+    coder.encode_chunks(data)
+    t_warm = time.perf_counter() - t1
     phase("codec[rs]", t0, objects=n_objects, shard_bytes=shard_bytes,
-          first_encode_s=round(t_enc, 2))
+          first_encode_s=round(t_enc, 2),
+          warm_encode_ms=round(t_warm * 1e3, 2))
 
     t0 = time.perf_counter()
     clay, clay_data = "plugin=clay k=4 m=2 d=5", data[:, :4, :]

@@ -138,3 +138,122 @@ def test_bucketed_encoder_matches_exact():
         a = np.asarray(K.make_encoder(mat)(d))               # bucketed
         b = np.asarray(K.make_encoder(mat, bucket_batch=False)(d))
         assert np.array_equal(a, b), B
+
+
+# ------------------------------------------------------------ the host face
+
+def _face_matrix(which):
+    """The pool's 3 x 8 coding matrix, a 1 x k row of it, one of its
+    decode matrices (two erasures, a parity row among the helpers)."""
+    mat = reed_sol_van_matrix(8, 3)
+    if which == "row":
+        return mat[1:2]
+    if which == "decode":
+        return R.decode_matrix(mat, [0, 9], 8, [1, 2, 3, 4, 5, 6, 7, 8])
+    return mat
+
+
+@pytest.mark.parametrize("B", [1, 3, 32])
+@pytest.mark.parametrize("L", [1, 3, 4, 7, 512, 4096 + 1])
+@pytest.mark.parametrize("which", ["pool", "row", "decode"])
+def test_host_face_matches_oracle(which, L, B):
+    """Host rows in, host rows out, byte for byte the numpy oracle: odd
+    totals, the pad to a whole row of words and the slice off the view,
+    the batch's bucket and its slice are all met."""
+    mat = _face_matrix(which)
+    data = _rand(B, mat.shape[1], L, seed=L + B)
+    got = K.make_host_encoder(mat)(data)
+    assert isinstance(got, np.ndarray) and got.dtype == np.uint8
+    assert got.shape == (B, mat.shape[0], L)
+    assert got.flags.c_contiguous
+    np.testing.assert_array_equal(got, R.encode_ref(mat, data))
+
+
+@pytest.mark.parametrize("lowering", LOWERINGS, ids=_ids)
+@pytest.mark.parametrize("k,m", [(8, 3), (4, 2), (10, 4)])
+def test_lowerings_on_words_match_bytes(lowering, k, m):
+    """uint32 words (four bytes a lane) through either lowering give the
+    words of what the bytes give: no byte leaves its place in a word."""
+    mat = reed_sol_van_matrix(k, m)
+    data = _rand(3, k, 256, seed=8)
+    got = np.asarray(lowering(mat, data.view(np.uint32)))
+    assert got.dtype == np.uint32 and got.shape == (3, m, 64)
+    np.testing.assert_array_equal(got.view(np.uint8), R.encode_ref(mat, data))
+
+
+def test_lowerings_refuse_other_lane_widths():
+    mat = reed_sol_van_matrix(4, 2)
+    for lowering in LOWERINGS:
+        with pytest.raises(ValueError, match="uint8 bytes or uint32 words"):
+            lowering(mat, np.zeros((1, 4, 8), np.uint16))
+
+
+def test_host_face_dense_side():
+    """A matrix on the dense side of the rule through the same face."""
+    mat = reed_sol_van_matrix(20, 8)
+    assert K._lowering(mat) is K._apply_mxu
+    data = _rand(2, 20, 516, seed=9)
+    np.testing.assert_array_equal(K.make_host_encoder(mat)(data),
+                                  R.encode_ref(mat, data))
+
+
+def test_host_face_takes_strided_rows_and_refuses_bad_shapes():
+    mat = reed_sol_van_matrix(4, 2)
+    face = K.make_host_encoder(mat)
+    wide = _rand(2, 4, 1024, seed=10)
+    np.testing.assert_array_equal(face(wide[:, :, ::2]),
+                                  R.encode_ref(mat, wide[:, :, ::2]))
+    with pytest.raises(ValueError, match="matrix expects 4"):
+        face(_rand(1, 3, 8))
+    with pytest.raises(ValueError, match=r"\(batch, k, L\)"):
+        face(_rand(1, 4, 8)[0])
+
+
+def test_host_face_compiles_once_a_shape():
+    mat = reed_sol_van_matrix(5, 2)
+    before = K._make_jitted_words.cache_info()
+    face = K.make_host_encoder(mat)
+    jitted = K._make_jitted_words(mat.tobytes(), 2, 5)
+    info = K._make_jitted_words.cache_info()
+    assert (info.misses, info.hits) == (before.misses + 1, before.hits + 1)
+    # L 500 and 512 share a row of words, B 3 and 4 a bucket: one program
+    for B, L in [(3, 500), (4, 512), (3, 512), (4, 500)]:
+        data = _rand(B, 5, L, seed=11)
+        np.testing.assert_array_equal(face(data), R.encode_ref(mat, data))
+    assert jitted._cache_size() == 1
+    face(_rand(5, 5, 512))                     # bucket 8: a second program
+    assert jitted._cache_size() == 2
+    # the device-resident program is another function with its own cache
+    assert K._make_jitted(mat.tobytes(), 2, 5) is not jitted
+
+
+@pytest.mark.parametrize("erased", [(0, 9), (3,)])
+def test_decode_chunks_round_trip_through_the_face(erased):
+    coder = factory("plugin=jerasure technique=reed_sol_van k=8 m=3")
+    data = _rand(3, 8, 1027, seed=12)
+    parity = coder.encode_chunks(data)
+    np.testing.assert_array_equal(parity, R.encode_ref(coder.matrix, data))
+    chunks = np.concatenate([data, parity], axis=1)
+    have = {i: chunks[:, i, :] for i in range(11) if i not in erased}
+    got = coder.decode_chunks(list(erased), have)
+    for e in erased:
+        assert got[e].dtype == np.uint8 and got[e].shape == (3, 1027)
+        np.testing.assert_array_equal(got[e], chunks[:, e, :])
+    # one row a chunk (no batch axis) takes the same face
+    flat = coder.decode_chunks(list(erased),
+                               {i: v[0] for i, v in have.items()})
+    for e in erased:
+        np.testing.assert_array_equal(flat[e], chunks[0, e, :])
+    # the device-resident decoder is untouched: uint8 (B, E, L) on device
+    surv = tuple(i for i in range(11) if i not in erased)[:8]
+    dev = coder.batch_decoder(erased, surv)(chunks[:, list(surv), :])
+    assert dev.dtype == np.uint8 and dev.shape == (3, len(erased), 1027)
+    np.testing.assert_array_equal(np.asarray(dev), chunks[:, list(erased), :])
+
+
+def test_host_face_zero_and_identity_rows():
+    # a zero row has no term at all: its words are zeros, not missing
+    mat = np.array([[0, 0, 0], [1, 0, 0], [2, 3, 0]], dtype=np.uint8)
+    data = _rand(2, 3, 130, seed=13)
+    np.testing.assert_array_equal(K.make_host_encoder(mat)(data),
+                                  R.encode_ref(mat, data))

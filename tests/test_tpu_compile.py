@@ -43,10 +43,14 @@ def coder():
     return factory(f"plugin=jerasure technique=reed_sol_van k={K} m={M}")
 
 
+def _struct(one_chip, shape, dtype):
+    import jax
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
 def _compile(fn, one_chip, *shapes_dtypes):
     import jax
-    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
-            for s, d in shapes_dtypes]
+    args = [_struct(one_chip, s, d) for s, d in shapes_dtypes]
     return jax.jit(fn).lower(*args).compile()
 
 
@@ -58,6 +62,26 @@ def test_rs_encode_compiles_at_32_objects(one_chip, coder, lowering):
     enc = functools.partial(getattr(rs_kernels, lowering), coder.matrix)
     out = _compile(enc, one_chip, ((32, K, SHARD), np.uint8))
     assert out.memory_analysis().temp_size_in_bytes < 2048 * MiB
+
+
+def test_host_face_program_is_words_in_a_flat_row_major_result_out(one_chip,
+                                                                   coder):
+    """What `encode_chunks` launches on 32 x 4 MiB: words in, the
+    parity out as (N, 128) uint32 in layout {1,0:T(8,128)}, the one
+    form the copy to the host neither de-tiles nor transposes. (The
+    uint8 (32, 3, L) parity leaves as {2,0,1:T(8,128)(4,1)}; a cast of
+    it to words on the device asks for 73.5 GB: PERF.md, PR 30.)"""
+    import re
+
+    from ceph_tpu.ops import rs_kernels
+    fn = rs_kernels._make_jitted_words(coder.matrix.tobytes(), M, K)
+    out = fn.lower(_struct(one_chip, (32, K, SHARD // 4),
+                           np.uint32)).compile()
+    layout = re.search(r"entry_computation_layout=\{(.*?)\}\}",
+                       out.as_text()).group(1)
+    assert layout.endswith("->u32[98304,128]{1,0:T(8,128)"), layout
+    assert _no_gather(out)
+    assert out.memory_analysis().temp_size_in_bytes < 3072 * MiB
 
 
 def _no_gather(compiled):
