@@ -68,6 +68,15 @@ CATEGORY_OF = {
     "ecbackend.read.verify.fetch": "encode",
     "ecbackend.read.decode": "encode",
     "ecbackend.read.unstripe": "encode",
+    "ecbackend.rmw": "encode",
+    "ecbackend.rmw.prefetch": "wire",
+    "ecbackend.rmw.delta.stage": "encode",
+    "ecbackend.rmw.delta.launch": "encode",
+    "ecbackend.rmw.delta.fetch": "encode",
+    "ecbackend.rmw.journal": "wire",
+    "ecbackend.rmw.apply": "wire",
+    "ecbackend.rmw.full": "encode",
+    "osd.persist_meta": "wire",
     "ecbackend.recover.stage": "encode",
     "ecbackend.recover.launch": "encode",
     "ecbackend.recover.fetch": "encode",

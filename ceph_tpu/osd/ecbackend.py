@@ -150,6 +150,11 @@ def ec_perf_counters():
             .add_u64_counter("rmw_delta_launches",
                              "fused delta-encode launches (device or "
                              "native host)")
+            .add_u64_counter("rmw_host_delta_launches",
+                             "of those, the ones computed on the "
+                             "host (native codec, or a codec's "
+                             "generic parity_delta): 0 where the "
+                             "fused device program served them all")
             .add_u64_counter("rmw_wire_bytes",
                              "journal + delta payload bytes shipped "
                              "to participating shards (the RMW "
@@ -601,7 +606,8 @@ class ECBackend(PGBackend):
         if full_ops:
             self.perf.inc("rmw_full_fallbacks",
                           len({n for n, _o, _d in full_ops}))
-            self._write_ranges_full(full_ops, dead_osds)
+            with span("ecbackend.rmw.full"):
+                self._write_ranges_full(full_ops, dead_osds)
 
     def _write_ranges_full(self,
                            ops: list[tuple[str, int, bytes | np.ndarray]],
@@ -906,7 +912,8 @@ class ECBackend(PGBackend):
                     data_c.ctypes.data_as(_ctypes.c_char_p),
                     parity.ctypes.data_as(_ctypes.c_char_p), wl, B)
                 if rc == 0:
-                    self.perf.inc("rmw_delta_launches")
+                    self.perf.inc_many((("rmw_delta_launches", 1),
+                                        ("rmw_host_delta_launches", 1)))
                     rows = np.concatenate([deltas, parity], axis=1)
                     crcs = _native.native_crc32c_rows(
                         0, np.ascontiguousarray(rows).reshape(
@@ -927,14 +934,20 @@ class ECBackend(PGBackend):
                 (("rmw_delta_launches", 1),
                  ("program_cache_hits", ci1.hits - ci0.hits),
                  ("program_cache_misses", ci1.misses - ci0.misses)))
-            padded = deltas
-            if bucket != B:
-                padded = np.zeros((bucket, t, wl), np.uint8)
-                padded[:B] = deltas
-            parity_d, crcs_d = fn(padded)
-            parity, crcs = jax.device_get((parity_d, crcs_d))
+            # launch is the host's own work (the dispatch with its
+            # H2D); fetch is the wait for the device
+            with span("ecbackend.rmw.delta.stage"):
+                padded = deltas
+                if bucket != B:
+                    padded = np.zeros((bucket, t, wl), np.uint8)
+                    padded[:B] = deltas
+            with span("ecbackend.rmw.delta.launch"):
+                parity_d, crcs_d = fn(padded)
+            with span("ecbackend.rmw.delta.fetch"):
+                parity, crcs = jax.device_get((parity_d, crcs_d))
             return (np.asarray(parity)[:B], np.asarray(crcs)[:B])
-        self.perf.inc("rmw_delta_launches")
+        self.perf.inc_many((("rmw_delta_launches", 1),
+                            ("rmw_host_delta_launches", 1)))
         parity = self.coder.parity_delta(touched, deltas)
         rows = np.concatenate([deltas, parity], axis=1)
         crcs = _rows_crc0(rows.reshape(B * (t + self.m), wl)).reshape(
@@ -1070,7 +1083,8 @@ class ECBackend(PGBackend):
             _n, _w, _os, _ns, _osl, _nsl, touched, _sp, a, b = job
             by_shape.setdefault((touched, b - a), []).append(job)
         for (touched, wl), group in by_shape.items():
-            self._delta_group(touched, wl, group)
+            with span("ecbackend.rmw"):
+                self._delta_group(touched, wl, group)
 
     def _delta_group(self, touched: tuple, wl: int, group) -> None:
         t = len(touched)
@@ -1086,29 +1100,31 @@ class ECBackend(PGBackend):
         # verifies) and pre-image sub-ranges arrive together, one
         # frame per participant shard instead of 1+m sequential
         # getattrs + a read RTT per span per job
-        old_crcs, prereads = self._rmw_prefetch(touched, group)
-        for bi, job in enumerate(group):
-            name, writes, old_size, _ns, osl, _nsl, _t, spans, a, _b \
-                = job
-            pure_append = all(lo >= old_size
-                              for _c, _c0, _ln, lo in spans)
-            for col, c0, ln, lo in spans:
-                off, arr = next((o, w) for o, w in writes
-                                if o <= lo and lo + ln <= o + len(w))
-                newb = arr[lo - off:lo - off + ln]
-                row = deltas[bi, col_of[col]]
-                if lo >= old_size:
-                    # append into padding: the pre-image is zeros by
-                    # the layout rule — no read phase
-                    row[c0 - a:c0 - a + ln] = newb
-                    continue
-                got = prereads[bi][(self.data_slots[col], c0, ln)]
-                oldb = np.zeros(ln, np.uint8)
-                oldb[:len(got)] = got
-                preread += ln
-                row[c0 - a:c0 - a + ln] = np.asarray(newb) ^ oldb
-            if pure_append:
-                append_fast += 1
+        with span("ecbackend.rmw.prefetch"):
+            old_crcs, prereads = self._rmw_prefetch(touched, group)
+        with span("ecbackend.rmw.delta.stage"):
+            for bi, job in enumerate(group):
+                name, writes, old_size, _ns, osl, _nsl, _t, spans, a, _b \
+                    = job
+                pure_append = all(lo >= old_size
+                                  for _c, _c0, _ln, lo in spans)
+                for col, c0, ln, lo in spans:
+                    off, arr = next((o, w) for o, w in writes
+                                    if o <= lo and lo + ln <= o + len(w))
+                    newb = arr[lo - off:lo - off + ln]
+                    row = deltas[bi, col_of[col]]
+                    if lo >= old_size:
+                        # append into padding: the pre-image is zeros
+                        # by the layout rule — no read phase
+                        row[c0 - a:c0 - a + ln] = newb
+                        continue
+                    got = prereads[bi][(self.data_slots[col], c0, ln)]
+                    oldb = np.zeros(ln, np.uint8)
+                    oldb[:len(got)] = got
+                    preread += ln
+                    row[c0 - a:c0 - a + ln] = np.asarray(newb) ^ oldb
+                if pure_append:
+                    append_fast += 1
         parity, crcs = self._delta_parity_crcs(touched, deltas)
         self.perf.inc_many((("rmw_preread_bytes", preread),
                             ("rmw_append_fast", append_fast)))
@@ -1147,8 +1163,9 @@ class ECBackend(PGBackend):
                 # this job through the full path (rare — e.g. a
                 # legacy object written before hinfo discipline)
                 self.perf.inc("rmw_full_fallbacks")
-                self._write_ranges_full(
-                    [(name, o, w) for o, w in job[1]], None)
+                with span("ecbackend.rmw.full"):
+                    self._write_ranges_full(
+                        [(name, o, w) for o, w in job[1]], None)
                 continue
             self._rmw_seq += 1
             seq = self._rmw_seq
@@ -1208,7 +1225,8 @@ class ECBackend(PGBackend):
                     if idx == 0:
                         hook("mid_prepare")
             else:
-                self._fanout_txns(list(shard_prep.items()))
+                with span("ecbackend.rmw.journal"):
+                    self._fanout_txns(list(shard_prep.items()))
             self.perf.inc("journal_entries",
                           sum(len(v) for v in keys_of.values()))
             if hook is not None:
@@ -1219,7 +1237,8 @@ class ECBackend(PGBackend):
                     if idx == 0:
                         hook("mid_apply")
             else:
-                self._fanout_txns(list(shard_apply.items()))
+                with span("ecbackend.rmw.apply"):
+                    self._fanout_txns(list(shard_apply.items()))
             if hook is not None:
                 hook("after_apply")
         except (ConnectionError, OSError):

@@ -110,6 +110,11 @@ declare_span_names(
     "ecbackend.read.verify.fetch", "ecbackend.read.decode",
     "ecbackend.read.decode.stage", "ecbackend.read.decode.launch",
     "ecbackend.read.decode.fetch", "ecbackend.read.unstripe",
+    "ecbackend.rmw", "ecbackend.rmw.prefetch",
+    "ecbackend.rmw.delta.stage", "ecbackend.rmw.delta.launch",
+    "ecbackend.rmw.delta.fetch", "ecbackend.rmw.journal",
+    "ecbackend.rmw.apply", "ecbackend.rmw.full",
+    "osd.persist_meta",
     "pgbackend.crcs.stage", "pgbackend.crcs.launch",
     "pgbackend.crcs.fetch",
     "ecbackend.recover.stage", "ecbackend.recover.launch",
@@ -2178,35 +2183,36 @@ class OSDDaemon:
         entries inside ObjectStore::Transaction). Clears the delta key
         in the same transaction — the base subsumes it (see
         _meta_extra for the delta scheme)."""
-        be = self.backends[ps]
-        blob = self._encode_meta(ps)
-        self._meta_delta[ps] = ([], be.pg_log.head)
-        # fan the omap txns out PIPELINED: transmit to every live
-        # shard first, then wait each ack — one overlapped round trip
-        # instead of len(acting) sequential ones (failure handling
-        # unchanged: an unreachable shard is suspected, not fatal)
-        waits: list[tuple[int, object]] = []
-        dead = self._dead()
-        for s, osd in enumerate(be.acting):
-            if osd in dead:
-                continue
-            t = Transaction().omap_set(shard_cid(be.pg, s), "__pg_meta__",
-                                       {PG_META_KEY: blob,
-                                        PG_META_DELTA_KEY: b""})
-            st = be.cluster.osd(osd)
-            submit = getattr(st, "queue_transaction_async", None)
-            try:
-                if submit is not None:
-                    waits.append((osd, submit(t)))
-                else:
-                    st.queue_transaction(t)
-            except (ConnectionError, OSError):
-                self.suspect.add(osd)
-        for osd, h in waits:
-            try:
-                h.result()
-            except (ConnectionError, OSError):
-                self.suspect.add(osd)
+        with span("osd.persist_meta"):
+            be = self.backends[ps]
+            blob = self._encode_meta(ps)
+            self._meta_delta[ps] = ([], be.pg_log.head)
+            # fan the omap txns out PIPELINED: transmit to every live
+            # shard first, then wait each ack — one overlapped round trip
+            # instead of len(acting) sequential ones (failure handling
+            # unchanged: an unreachable shard is suspected, not fatal)
+            waits: list[tuple[int, object]] = []
+            dead = self._dead()
+            for s, osd in enumerate(be.acting):
+                if osd in dead:
+                    continue
+                t = Transaction().omap_set(shard_cid(be.pg, s), "__pg_meta__",
+                                           {PG_META_KEY: blob,
+                                            PG_META_DELTA_KEY: b""})
+                st = be.cluster.osd(osd)
+                submit = getattr(st, "queue_transaction_async", None)
+                try:
+                    if submit is not None:
+                        waits.append((osd, submit(t)))
+                    else:
+                        st.queue_transaction(t)
+                except (ConnectionError, OSError):
+                    self.suspect.add(osd)
+            for osd, h in waits:
+                try:
+                    h.result()
+                except (ConnectionError, OSError):
+                    self.suspect.add(osd)
 
     def _encode_meta_delta(self, ps: int) -> bytes:
         """The bounded per-write metadata record: entries appended

@@ -52,6 +52,10 @@ _now = time.perf_counter
 #: finished spans of the live capture(s) plus every `xla.compile`.
 #: Appends are GIL-atomic; readers copy (`span_log`).
 _LOG: collections.deque = collections.deque(maxlen=65536)
+#: records the full log has pushed out at its old end, since the process
+#: began (`span_log_dropped`): a log that wrapped is seen, not read
+_dropped = [0]
+_drop_lock = threading.Lock()
 _tls = threading.local()             # .stack: this thread's open spans
 
 
@@ -59,6 +63,9 @@ def _log(name: str, start: float, dur: float, self_s: float,
          trace_id: int | None = None, nbytes: int | None = None,
          **more) -> None:
     """One record: start and dur in perf_counter seconds."""
+    if len(_LOG) == getattr(_LOG, "maxlen", None):
+        with _drop_lock:
+            _dropped[0] += 1
     _LOG.append({"name": name, "start": start, "dur": dur, "self": self_s,
                  "trace_id": trace_id, "nbytes": nbytes, **more})
 
@@ -179,6 +186,13 @@ def span_log(since: float | None = None,
             and (until is None or r["start"] + r["dur"] <= until)]
 
 
+def span_log_dropped() -> int:
+    """Records the log has dropped since the process began: it keeps the
+    newest `_LOG.maxlen`, and a reader of a stretch takes the count
+    before and after it."""
+    return _dropped[0]
+
+
 def stage_table(records, ops: int) -> dict[str, dict]:
     """Self time summed by span name: name -> {count, self_s,
     self_ms_per_op}. `ops` is the number of operations the records
@@ -194,7 +208,8 @@ def stage_table(records, ops: int) -> dict[str, dict]:
     return table
 
 
-_session: list = [None, None, 0.0]   # [ProfilerSession, log_dir, t_start]
+# [ProfilerSession, log_dir, t_start, records dropped before it]
+_session: list = [None, None, 0.0, 0]
 
 
 def start_trace(log_dir: str) -> bool:
@@ -217,7 +232,7 @@ def start_trace(log_dir: str) -> bool:
             opts = jax.profiler.ProfileOptions()
             opts.python_tracer_level = 0
             sess = _profiler.ProfilerSession(opts)
-        _session[:] = [sess, log_dir, t_start]
+        _session[:] = [sess, log_dir, t_start, span_log_dropped()]
         return True
     except Exception:
         return False
@@ -226,8 +241,10 @@ def start_trace(log_dir: str) -> bool:
 def stop_trace() -> dict | None:
     """End the capture and write it under its directory. Returns the
     capture's stage table (`stage_table` of what the log gained, an
-    op being one `osd.op`), or None when no capture could be stopped."""
-    sess, log_dir, t_start = _session
+    op being one `osd.op`) with `dropped`, the records the log lost
+    since the capture began (not 0: the table is short of them), or
+    None when no capture could be stopped."""
+    sess, log_dir, t_start, dropped_before = _session
     _session[0] = None
     try:
         if sess is not None:
@@ -239,6 +256,7 @@ def stop_trace() -> dict | None:
     records = span_log(since=t_start)
     ops = sum(1 for r in records if r["name"] == "osd.op")
     return {"dir": log_dir, "ops": ops,
+            "dropped": span_log_dropped() - dropped_before,
             "stages": stage_table(records, ops)}
 
 

@@ -16,6 +16,10 @@ A read's gather (PR 28) is one `readv` frame a remote slot with the
 hinfo in the answer: the sub-op kinds the OSDs serve, `gather_rounds`
 and `gather_frames`, a slot planned around, rot still caught, and the
 peer-latency EWMA fed by the pipelined reads.
+A partial overwrite (PR 31) leaves `ecbackend.rmw`, its six children and
+`osd.persist_meta`, one case each, and nothing with no session;
+`rmw_host_delta_launches` tells the host's delta launches from the
+device's; the log counts the records it drops.
 """
 
 import os
@@ -211,6 +215,59 @@ class TestSpanLogUnits:
         assert stop_trace() is None          # nothing left to stop
 
 
+    # before the live cluster below switches the CPU backend's host
+    # shortcut off for the rest of the module
+    @pytest.mark.parametrize("branch,profile", [
+        ("native host codec", "plugin=tpu_rs k=4 m=2"),
+        ("fused device program", "plugin=tpu_rs k=4 m=2"),
+        ("generic parity_delta", "plugin=clay k=4 m=2")])
+    def test_rmw_host_delta_launches_rises_on_the_host_branches_only(
+            self, branch, profile, monkeypatch):
+        from ceph_tpu.osd import ecbackend
+        from ceph_tpu.osd.ecbackend import ECBackend, ShardSet
+        if branch == "native host codec":
+            if not ecbackend._host_crc_available():
+                pytest.skip("no native host codec built here")
+        else:
+            monkeypatch.setattr(ecbackend, "_host_crc_available",
+                                lambda: False)
+        n = 6
+        be = ECBackend(profile, "1.0", list(range(n)), ShardSet(),
+                       chunk_size=512)
+        be.write_objects({"o": os.urandom(be.sinfo.stripe_width * 2)})
+        be.write_ranges([("o", 16, os.urandom(64))])
+        got = {k: int(be.perf.get(k)) for k in
+               ("rmw_ops", "rmw_delta_launches", "rmw_host_delta_launches",
+                "rmw_full_fallbacks")}
+        assert got == {"rmw_ops": 1, "rmw_delta_launches": 1,
+                       "rmw_host_delta_launches":
+                           0 if branch == "fused device program" else 1,
+                       "rmw_full_fallbacks": 0}
+        assert "rmw_host_delta_launches" in be.perf.dump()   # `perf dump`
+
+    def test_a_full_log_counts_the_records_it_drops(self, monkeypatch,
+                                                    tmp_path):
+        import collections
+        monkeypatch.setattr(tracing, "_LOG", collections.deque(maxlen=4))
+        before = tracing.span_log_dropped()
+        assert start_trace(str(tmp_path / "cap"))
+        for i in range(7):
+            with span("osd.op"):
+                pass
+        # the newest four are kept; `trace stop`'s table says three of
+        # the capture's records are gone
+        assert len(span_log()) == 4
+        assert tracing.span_log_dropped() == before + 3
+        got = stop_trace()
+        assert got["dropped"] == 3 and got["ops"] == 4
+        # a capture that fits drops none
+        monkeypatch.setattr(tracing, "_LOG", collections.deque(maxlen=64))
+        assert start_trace(str(tmp_path / "cap2"))
+        with span("osd.op"):
+            pass
+        assert stop_trace()["dropped"] == 0
+
+
 # -- live cluster ------------------------------------------------------------
 
 @pytest.fixture(scope="module")
@@ -337,6 +394,83 @@ class TestLiveSpanLog:
         assert got["stages"]["ecbackend.write.fanout"]["self_ms_per_op"] > 0
         again = admin_command(cluster.asok_path(d.name), "trace stop")
         assert again == {"stopped": False}
+
+
+# -- a partial overwrite (PR 31) ------------------------------------------------
+
+RMW_STAGES = ("ecbackend.rmw", "ecbackend.rmw.prefetch",
+              "ecbackend.rmw.delta.stage", "ecbackend.rmw.delta.launch",
+              "ecbackend.rmw.delta.fetch", "ecbackend.rmw.journal",
+              "ecbackend.rmw.apply", "osd.persist_meta")
+
+
+class TestOverwriteSpansAndCounters:
+    """`Client.write_at` inside one stripe of a stored object: the
+    parity-delta RMW on the fused device program (the module's cluster
+    has the CPU backend's host shortcut off), then the PG's metadata
+    persist."""
+
+    def test_no_session_a_write_at_logs_nothing(self, cluster, client):
+        client.write({"over-dark": b"o" * 3000})
+        t0 = time.perf_counter()
+        client.write_at("over-dark", 40, b"n" * 100)
+        assert client.read("over-dark")[:200] \
+            == b"o" * 40 + b"n" * 100 + b"o" * 60
+        assert _mine(t0) == {}
+
+    @pytest.mark.parametrize("stage", RMW_STAGES)
+    def test_a_traced_write_at_leaves_the_rmw_s_stage(self, cluster,
+                                                      client, session,
+                                                      stage):
+        client.write({"over": b"o" * 3000})
+        client.write_at("over", 8, b"w" * 16)    # the column's program
+        t0 = time.perf_counter()
+        before = {k: _ec(cluster, k) for k in
+                  ("rmw_ops", "rmw_delta_launches",
+                   "rmw_host_delta_launches", "rmw_full_fallbacks")}
+        client.write_at("over", 40, b"n" * 100)
+        got = _mine(t0)
+        assert {k: _ec(cluster, k) - v for k, v in before.items()} == {
+            "rmw_ops": 1, "rmw_delta_launches": 1,
+            "rmw_host_delta_launches": 0, "rmw_full_fallbacks": 0}
+        assert is_span_declared(stage) and stage in got, sorted(got)
+        assert "ecbackend.rmw.full" not in got
+        for rec in got[stage]:
+            assert -1e-9 <= rec["self"] <= rec["dur"] + 1e-9
+        (rmw,), (op,) = got["ecbackend.rmw"], got["osd.op"]
+        tid = got["client.op"][-1]["trace_id"]
+        assert rmw["trace_id"] == op["trace_id"] == tid
+        if stage == "ecbackend.rmw":
+            # the six children cover it: its self time is what is left
+            kids = sum(r["dur"] for name in RMW_STAGES[1:7]
+                       for r in got[name])
+            assert rmw["self"] == pytest.approx(rmw["dur"] - kids)
+            # the rows' delta and the pad to the bucket: two stage spans
+            assert len(got["ecbackend.rmw.delta.stage"]) == 2
+        elif stage == "osd.persist_meta":
+            # after the RMW, inside the op, its sends among its children
+            (meta,) = got[stage]
+            assert rmw["start"] + rmw["dur"] <= meta["start"] + 1e-6
+            assert meta["start"] + meta["dur"] <= op["start"] + op["dur"] + 1e-6
+            assert meta["self"] < meta["dur"]
+        else:
+            (rec,) = got[stage][-1:]
+            assert rmw["start"] <= rec["start"]
+            assert rec["start"] + rec["dur"] <= rmw["start"] + rmw["dur"] + 1e-6
+
+    def test_a_write_the_delta_path_refuses_leaves_the_full_span(
+            self, cluster, client, session):
+        """3000 bytes over k=2 in 256-byte units: a write of 600 bytes
+        spans a whole stripe, and the ladder shows where it happens."""
+        client.write({"over-wide": b"o" * 3000})
+        t0 = time.perf_counter()
+        fallbacks = _ec(cluster, "rmw_full_fallbacks")
+        client.write_at("over-wide", 100, b"n" * 600)
+        got = _mine(t0)
+        assert _ec(cluster, "rmw_full_fallbacks") == fallbacks + 1
+        assert is_span_declared("ecbackend.rmw.full")
+        assert len(got["ecbackend.rmw.full"]) == 1
+        assert "ecbackend.rmw" not in got and "osd.persist_meta" in got
 
 
 # -- a read that rebuilds a row ------------------------------------------------

@@ -141,3 +141,19 @@ def test_recover_program_compiles_at_the_staged_batch(one_chip, coder):
     assert _no_gather(out)
     # 1,687 MiB with the gathers (PR 26 read 1,010)
     assert out.memory_analysis().temp_size_in_bytes < 1687 * MiB
+
+
+@pytest.mark.parametrize("column", [0, 7])
+def test_fused_delta_compiles_at_one_4k_block(one_chip, coder, column):
+    """What a 4 KiB overwrite launches (configuration rbd_ec_k8m3_12osd,
+    stripe unit 4096): the 3 x 1 column of the coding matrix on one
+    4 KiB delta row and the zero-seed crc of the four rows, (1, 1, 4096)
+    uint8 in, (1, 3, 4096) uint8 and (1, 4) uint32 out. A thousandth of
+    the served write's shape: no gather, and scratch under a MiB."""
+    from ceph_tpu.osd.ecbackend import ECBackend
+    D = np.ascontiguousarray(coder.delta_matrix((column,)), np.uint8)
+    assert D.shape == (M, 1)
+    fn = ECBackend._fused_delta_fn(D.tobytes(), M, 1, 4096, 1)
+    out = fn.lower(_struct(one_chip, (1, 1, 4096), np.uint8)).compile()
+    assert _no_gather(out)
+    assert out.memory_analysis().temp_size_in_bytes < 1 * MiB
