@@ -185,6 +185,15 @@ def ec_perf_counters():
                              "padding (appends: no read phase at all)")
             .add_u64_counter("journal_entries",
                              "stripe-journal intents logged")
+            .add_u64_counter("meta_rides",
+                             "PG metadata records (bounded delta or "
+                             "full base) carried on a write's own "
+                             "fan-out: a full write's transactions, "
+                             "an RMW's apply round")
+            .add_u64_counter("meta_persist_rounds",
+                             "PG metadata persists sent as a fan-out "
+                             "round of their own (the full base to "
+                             "every live shard)")
             .add_u64_counter("journal_replay_forward",
                              "journaled RMWs rolled forward on replay")
             .add_u64_counter("journal_replay_rollback",
@@ -589,7 +598,8 @@ class ECBackend(PGBackend):
         return window
 
     def write_ranges(self, ops: list[tuple[str, int, bytes | np.ndarray]],
-                     dead_osds: set[int] | None = None) -> None:
+                     dead_osds: set[int] | None = None,
+                     shard_txn_extra=None) -> list[int]:
         """Batched RMW dispatcher: every (name, offset, bytes) op goes
         to the PARITY-DELTA fast path when the stripe is clean (all
         shards live + caught up, write within one stripe, touched data
@@ -598,16 +608,28 @@ class ECBackend(PGBackend):
         per-PG stripe journal — and ladders to the full-stripe RMW
         (`_write_ranges_full`, the pre-r16 path) otherwise: degraded
         or stale stripes, object creation, stripe-spanning or
-        overlapping writes, vector-code geometry changes."""
+        overlapping writes, vector-code geometry changes.
+
+        shard_txn_extra: write_objects' factory. The delta path calls
+        it once a wave and puts its ops on every shard's transaction
+        of the APPLY round (see _delta_commit); the full path does not
+        use it, so a caller that rides its metadata on the hook still
+        has to persist what a full-path op logged. Returns the slots
+        that hold no byte of the wave and did not acknowledge their
+        extra-ops-only transaction (the caller's to suspect; a
+        participant's failure raises)."""
         dead = dead_osds or set()
         delta_jobs, full_ops = self._partition_rmw(ops, dead)
+        unacked: list[int] = []
         if delta_jobs:
-            self._write_ranges_delta(delta_jobs)
+            unacked = self._write_ranges_delta(delta_jobs,
+                                               shard_txn_extra)
         if full_ops:
             self.perf.inc("rmw_full_fallbacks",
                           len({n for n, _o, _d in full_ops}))
             with span("ecbackend.rmw.full"):
                 self._write_ranges_full(full_ops, dead_osds)
+        return unacked
 
     def _write_ranges_full(self,
                            ops: list[tuple[str, int, bytes | np.ndarray]],
@@ -756,9 +778,11 @@ class ECBackend(PGBackend):
         e.u64(new_size).u64(osl).u64(nsl)
         e.u64(a).blob(delta)
         e.u32(new_crc)
-        e.u64(version)                  # the PG-log version this RMW
-        #                                 creates: replay drops entries
-        #                                 a later write superseded
+        e.u64(version)                  # the lowest version of `name`
+        #                                 that supersedes this intent
+        #                                 (_delta_commit): replay drops
+        #                                 the entry once the name has
+        #                                 reached it
         return e.bytes()
 
     @staticmethod
@@ -1071,22 +1095,28 @@ class ECBackend(PGBackend):
             prereads.append(pre)
         return old_crcs, prereads
 
-    def _write_ranges_delta(self, jobs) -> None:
+    def _write_ranges_delta(self, jobs,
+                            shard_txn_extra=None) -> list[int]:
         """Execute delta-eligible RMW jobs: build the delta rows
         (reading only the touched sub-ranges' pre-image — none at all
         for appends into padding), one fused delta-encode launch per
         (touched-columns, window) group, then the journaled two-phase
         shard update. Jobs whose stored hinfo refuses the incremental
-        update reroute through the full path."""
+        update reroute through the full path. Returns write_ranges'
+        unacknowledged slots."""
         by_shape: dict[tuple, list] = {}
         for job in jobs:
             _n, _w, _os, _ns, _osl, _nsl, touched, _sp, a, b = job
             by_shape.setdefault((touched, b - a), []).append(job)
+        unacked: list[int] = []
         for (touched, wl), group in by_shape.items():
             with span("ecbackend.rmw"):
-                self._delta_group(touched, wl, group)
+                unacked += self._delta_group(touched, wl, group,
+                                             shard_txn_extra)
+        return unacked
 
-    def _delta_group(self, touched: tuple, wl: int, group) -> None:
+    def _delta_group(self, touched: tuple, wl: int, group,
+                     shard_txn_extra=None) -> list[int]:
         t = len(touched)
         col_of = {c: i for i, c in enumerate(touched)}
         parity_slots = [self.chunk_mapping[self.k + j]
@@ -1128,12 +1158,14 @@ class ECBackend(PGBackend):
         parity, crcs = self._delta_parity_crcs(touched, deltas)
         self.perf.inc_many((("rmw_preread_bytes", preread),
                             ("rmw_append_fast", append_fast)))
-        self._delta_commit(touched, wl, group, deltas, parity, crcs,
-                           parity_slots, old_crcs=old_crcs)
+        return self._delta_commit(touched, wl, group, deltas, parity,
+                                  crcs, parity_slots, old_crcs=old_crcs,
+                                  shard_txn_extra=shard_txn_extra)
 
     def _delta_commit(self, touched: tuple, wl: int, group,
                       deltas, parity, crcs, parity_slots,
-                      old_crcs: list | None = None) -> None:
+                      old_crcs: list | None = None,
+                      shard_txn_extra=None) -> list[int]:
         """The journaled two-phase shard update of one delta batch:
         intent entries (delta payload + new hinfo) durably on every
         participating shard, then the atomic per-shard apply (XOR +
@@ -1141,9 +1173,23 @@ class ECBackend(PGBackend):
         `old_crcs` carries the prefetched per-job hinfo bases from
         _rmw_prefetch (None entries reroute through the full path);
         absent, the per-job sync getattr loop serves (bare-backend
-        callers)."""
+        callers).
+
+        With `shard_txn_extra` (write_objects' factory) the wave is
+        logged BEFORE the rounds, the factory is called once for the
+        wave's names, and its ops go on every shard's transaction of
+        the apply round: atomic with the XOR on a participant, alone
+        on the other shards (the ref's MOSDECSubOpWrite carries the
+        log entries to the shards that get no bytes too). The intents
+        are durable on every participant before any shard holds a
+        record that names the new version, so a crash in the apply
+        round replays forward; a wave that fails leaves log entries no
+        shard applied, and the caller's retry logs past them. Returns
+        the non-participants whose transaction was not acknowledged
+        (never a participant: that raises)."""
         t = len(touched)
         hook = self._rmw_crash_hook
+        rides = shard_txn_extra is not None
         # per job: rows per slot, new crcs per slot, participants
         waves = []       # (job, seq, {slot: (row|None, new_crc)})
         wire = 0
@@ -1170,8 +1216,16 @@ class ECBackend(PGBackend):
             self._rmw_seq += 1
             seq = self._rmw_seq
             # the PG-log version this job will create (jobs log in
-            # wave order right after the apply fan-out)
+            # wave order: after the apply fan-out, or before the
+            # rounds where the extra ops ride) and, from it, the
+            # lowest version of the name that supersedes the intent.
+            # Logged after the apply, a failed wave's version is the
+            # retry's too, so the name AT that version supersedes;
+            # logged first it is this wave's alone, a shard's
+            # metadata may name it while its neighbour still holds
+            # the intent, and only a later one supersedes.
             pred_version = self.pg_log.head + len(waves) + 1
+            superseded_at = pred_version + (1 if rides else 0)
             plan: dict[int, tuple] = {}
             for ti, c in enumerate(touched):
                 s = self.data_slots[c]
@@ -1189,7 +1243,7 @@ class ECBackend(PGBackend):
                 delta_b = b"" if row is None else row.tobytes()
                 entry = self._encode_jentry(
                     seq, name, s, parts, new_size, osl, nsl, a,
-                    delta_b, new_crc, pred_version)
+                    delta_b, new_crc, superseded_at)
                 cid = shard_cid(self.pg, s)
                 shard_prep.setdefault(s, Transaction()).omap_set(
                     cid, self.JOURNAL_OBJ,
@@ -1206,13 +1260,27 @@ class ECBackend(PGBackend):
                 wire += len(entry) + len(delta_b)
             waves.append((job, seq, plan, parts))
         if not waves:
-            return
+            return []
         for s, at in shard_apply.items():
             cid = shard_cid(self.pg, s)
             at.omap_set(cid, self.JOURNAL_OBJ,
                         {self._J_APPLIED:
                          _struct.pack("<Q", max_seq_of[s])})
             at.omap_rmkeys(cid, self.JOURNAL_OBJ, keys_of[s])
+        live = list(range(self.n))
+
+        def log_wave():
+            for job, _seq, _plan, _parts in waves:
+                self.object_sizes[job[0]] = job[3]
+                self._log_write(job[0], live)
+        extra_only: list[int] = []
+        if rides:
+            log_wave()
+            add = shard_txn_extra([job[0] for job, *_ in waves])
+            extra_only = [s for s in live if s not in shard_apply]
+            for s in live:
+                add(s, shard_apply.setdefault(s, Transaction()))
+        unacked: list[int] = []
         try:
             if hook is not None:
                 hook("before_prepare")
@@ -1238,7 +1306,8 @@ class ECBackend(PGBackend):
                         hook("mid_apply")
             else:
                 with span("ecbackend.rmw.apply"):
-                    self._fanout_txns(list(shard_apply.items()))
+                    unacked = self._fanout_txns(
+                        list(shard_apply.items()), optional=extra_only)
             if hook is not None:
                 hook("after_apply")
         except (ConnectionError, OSError):
@@ -1256,17 +1325,21 @@ class ECBackend(PGBackend):
                             self.JOURNAL_OBJ, keys))
                 except (ConnectionError, OSError, KeyError):
                     pass
+            if rides:
+                # logged first: the entries stay (the retry logs past
+                # them), the sizes are the stores' again
+                for job, _seq, _plan, _parts in waves:
+                    self.object_sizes[job[0]] = job[2]
             raise
-        live = list(range(self.n))
-        ios = 0
-        for job, _seq, _plan, parts in waves:
-            name = job[0]
-            self.object_sizes[name] = job[3]
-            self._log_write(name, live)
-            ios += len(parts)
+        if not rides:
+            log_wave()
+        # a shard io moves object bytes: the participants', not a
+        # transaction that holds the extra ops alone
+        ios = sum(len(parts) for _job, _seq, _plan, parts in waves)
         self.perf.inc_many((("rmw_ops", len(waves)),
                             ("rmw_shard_ios", ios),
                             ("rmw_wire_bytes", wire)))
+        return unacked
 
     def stripe_journal_replay(self, dead_osds: set[int] | None = None
                               ) -> dict:
@@ -1338,7 +1411,10 @@ class ECBackend(PGBackend):
             name = ent0["name"]
             # superseded entries (a later write — e.g. the degraded
             # full-path retry of this very RMW — already bumped the
-            # object's version) must never re-fold their delta
+            # object's version) must never re-fold their delta; the
+            # entry states the version that supersedes it, so metadata
+            # that rode this RMW's own apply round and names its
+            # version does not
             roll = (applied_any or all_logged) \
                 and name in self.object_sizes \
                 and ent0["version"] > self.object_versions.get(name, 0)

@@ -110,7 +110,7 @@ class PGBackend:
                    default=0)
         return [s for s in shards if self.shard_applied[s] >= need]
 
-    def _fanout_txns(self, items) -> None:
+    def _fanout_txns(self, items, optional=()) -> list[int]:
         """Apply [(shard, Transaction)] across the acting set,
         PIPELINED where the store supports it (RemoteStore at the wire
         tier): every txn is transmitted before any ack is awaited, so
@@ -118,27 +118,31 @@ class PGBackend:
         len(items) sequential ones (the reference dispatches its
         MOSDECSubOpWrite sub-ops in parallel too). Durability point
         unchanged — this returns only after EVERY shard acked, and a
-        shard failure raises exactly like the sequential loop did.
+        shard failure raises exactly like the sequential loop did,
+        except on a shard in `optional` (one whose transaction moves
+        no object byte): those that failed are returned instead.
         In-process stores (MemStore/TinStore) take the sync path."""
         waits: list = []
-        first_err: BaseException | None = None
+        errs: list[tuple[int, BaseException]] = []
         for shard, t in items:
             st = self._store(shard)
             submit = getattr(st, "queue_transaction_async", None)
             try:
                 if submit is not None:
-                    waits.append(submit(t))
+                    waits.append((shard, submit(t)))
                 else:
                     st.queue_transaction(t)
             except (ConnectionError, OSError) as e:
-                first_err = first_err or e
-        for h in waits:
+                errs.append((shard, e))
+        for shard, h in waits:
             try:
                 h.result()
             except (ConnectionError, OSError) as e:
-                first_err = first_err or e
-        if first_err is not None:
-            raise first_err
+                errs.append((shard, e))
+        for shard, e in errs:
+            if shard not in optional:
+                raise e
+        return [shard for shard, _e in errs]
 
     def _check_min_size(self, live: list[int]) -> None:
         """Writes need >= min_live receiving slots or the PG goes
@@ -205,18 +209,21 @@ class PGBackend:
         raise NotImplementedError
 
     def write_at(self, name: str, offset: int, data,
-                 dead_osds: set[int] | None = None) -> None:
-        self.write_ranges([(name, offset, data)], dead_osds)
+                 dead_osds: set[int] | None = None, **kw):
+        """One range; `kw` and the result are write_ranges' (an
+        ECBackend's takes write_objects' `shard_txn_extra`)."""
+        return self.write_ranges([(name, offset, data)], dead_osds, **kw)
 
-    def append_objects(self, appends, dead_osds=None) -> None:
+    def append_objects(self, appends, dead_osds=None, **kw):
         """Append streams: each name's bytes land at its current tail
         (creating absent objects at offset 0). On an EC pool a tail
         landing inside the padded stripe is the RMW append fast path:
         the pre-image is zeros by the layout rule, so no read phase
-        and only the tail data shard + m parity shards move."""
-        self.write_ranges(
+        and only the tail data shard + m parity shards move. `kw` and
+        the result are write_ranges'."""
+        return self.write_ranges(
             [(name, self.object_sizes.get(name, 0), data)
-             for name, data in appends.items()], dead_osds)
+             for name, data in appends.items()], dead_osds, **kw)
 
     def read_objects(self, names, dead_osds=None) -> dict[str, np.ndarray]:
         raise NotImplementedError

@@ -2178,11 +2178,15 @@ class OSDDaemon:
                                  ensure_collections=ensure_collections)
 
     def _persist_meta(self, ps: int) -> None:
-        """Ship the PG's FULL metadata to every live shard as omap
-        (the pg_log-rides-with-the-transaction discipline, ref: PGLog
-        entries inside ObjectStore::Transaction). Clears the delta key
-        in the same transaction — the base subsumes it (see
+        """Ship the PG's FULL metadata to every live shard as omap, in
+        a fan-out round of its own: what a mutation pays whose
+        metadata rode no fan-out of the bytes (a replicated pool's
+        writes, an EC pool's full-path RMW, remove, rollback, repair,
+        cls, snap trim, recovery, activation; a write or a delta-path
+        RMW on an EC pool rides _meta_extra instead). Clears the delta
+        key in the same transaction — the base subsumes it (see
         _meta_extra for the delta scheme)."""
+        self.ec_perf.inc("meta_persist_rounds")
         with span("osd.persist_meta"):
             be = self.backends[ps]
             blob = self._encode_meta(ps)
@@ -2213,6 +2217,64 @@ class OSDDaemon:
                     h.result()
                 except (ConnectionError, OSError):
                     self.suspect.add(osd)
+
+    def _meta_extra(self, ps: int, wave_names):
+        """write_objects' / write_ranges' `shard_txn_extra` factory
+        (bound to `ps`): the PG metadata rides the fan-out that moves
+        the wave's bytes (the pg_log-inside-the-transaction
+        discipline): one wave persists bytes AND the metadata that
+        proves them, where a separate _persist_meta pass is a round
+        more, to every live shard. Steady state ships a BOUNDED DELTA
+        (entries since the last full blob + applied cursors,
+        O(window)); the full O(objects-in-PG) base goes out every
+        _META_DELTA_MAX entries — without this, per-write metadata
+        cost grows linearly with PG object count and the write path
+        degrades quadratically over a sustained workload. Snap-era
+        state (snapsets/births beyond era 0) isn't delta-encoded: any
+        pool with snaps takes the full base every time, keeping COW
+        restore semantics byte-identical. `osd.persist_meta` spans
+        the encode: what the metadata costs an op that rides."""
+        be = self.backends[ps]
+        with span("osd.persist_meta"):
+            ent, base_head = self._meta_delta.get(ps, ([], -1))
+            ent = ent + [(n, be.object_versions[n], be.object_sizes[n])
+                         for n in wave_names]
+            full = (base_head < 0
+                    or len(ent) >= _META_DELTA_MAX
+                    or self.osdmap.pools[1].snap_seq > 0
+                    or self.snapsets.get(ps)
+                    or self.obj_kv.get(ps))
+            if full:
+                blob = self._encode_meta(ps)
+                self._meta_delta[ps] = ([], be.pg_log.head)
+                kv = {PG_META_KEY: blob, PG_META_DELTA_KEY: b""}
+            else:
+                self._meta_delta[ps] = (ent, base_head)
+                kv = {PG_META_DELTA_KEY: self._encode_meta_delta(ps)}
+        self.ec_perf.inc("meta_rides")
+
+        def add(shard, t):
+            t.omap_set(shard_cid(be.pg, shard), "__pg_meta__", kv)
+        return add
+
+    def _meta_rode(self, ps: int) -> bool:
+        """Whether the records _meta_extra made cover the PG log to
+        its head: every entry since the last full base is in the delta
+        window. False where a mutation logged without the factory (a
+        full-path RMW, any write of a replicated pool): the caller
+        then owes a _persist_meta."""
+        ent, base_head = self._meta_delta.get(ps, ([], -1))
+        return base_head >= 0 and \
+            base_head + len(ent) == self.backends[ps].pg_log.head
+
+    def _meta_rider(self, ps: int, be) -> dict:
+        """The keyword that rides the PG's metadata on a write's own
+        fan-out, where the backend has the hook: an ECBackend's
+        write_objects and write_ranges."""
+        if not isinstance(be, ECBackend):
+            return {}
+        return {"shard_txn_extra":
+                lambda wave_names: self._meta_extra(ps, wave_names)}
 
     def _encode_meta_delta(self, ps: int) -> bytes:
         """The bounded per-write metadata record: entries appended
@@ -2256,8 +2318,10 @@ class OSDDaemon:
 
     def _encode_meta(self, ps: int) -> bytes:
         """v4 envelope: the v3 body, zlib-wrapped. The blob ships to
-        every live shard on EVERY write (it rides the write fan-out
-        txn) and grows with the PG's object count — deflating the
+        every live shard whenever a full base goes out — every
+        _META_DELTA_MAX entries where writes ride _meta_extra, every
+        write where snaps or object kv are live, every _persist_meta —
+        and grows with the PG's object count: deflating the
         name/int-table body ~4-5x keeps the metadata bytes a small
         fraction of the data bytes at bench scale. compat=4: the
         body layout moved, so a pre-v4 reader must refuse (its
@@ -4039,46 +4103,7 @@ class OSDDaemon:
             self._check_snapc(d.u64())
             objs = d.mapping(Decoder.string, Decoder.blob)
             self._snap_guard(ps, be, objs)
-
-            def _meta_extra(wave_names):
-                # the PG metadata rides the write fan-out transaction
-                # itself (the pg_log-inside-the-transaction
-                # discipline): one wave persists bytes AND the
-                # metadata that proves them, halving the write path's
-                # frame count vs the old separate _persist_meta pass.
-                # Steady state ships a BOUNDED DELTA (entries since
-                # the last full blob + applied cursors, O(window));
-                # the full O(objects-in-PG) base goes out every
-                # _META_DELTA_MAX entries — without this, per-write
-                # metadata cost grows linearly with PG object count
-                # and the write path degrades quadratically over a
-                # sustained workload. Snap-era state (snapsets/births
-                # beyond era 0) isn't delta-encoded: any pool with
-                # snaps takes the full-persist path every time,
-                # keeping COW restore semantics byte-identical.
-                ent, base_head = self._meta_delta.get(ps, ([], -1))
-                ent = ent + [(n, be.object_versions[n],
-                              be.object_sizes[n]) for n in wave_names]
-                full = (base_head < 0
-                        or len(ent) >= _META_DELTA_MAX
-                        or self.osdmap.pools[1].snap_seq > 0
-                        or self.snapsets.get(ps)
-                        or self.obj_kv.get(ps))
-                if full:
-                    blob = self._encode_meta(ps)
-                    self._meta_delta[ps] = ([], be.pg_log.head)
-                    kv = {PG_META_KEY: blob, PG_META_DELTA_KEY: b""}
-                else:
-                    self._meta_delta[ps] = (ent, base_head)
-                    kv = {PG_META_DELTA_KEY:
-                          self._encode_meta_delta(ps)}
-
-                def add(shard, t):
-                    t.omap_set(shard_cid(be.pg, shard),
-                               "__pg_meta__", kv)
-                return add
-            fused = isinstance(be, ECBackend)
-            kw = {"shard_txn_extra": _meta_extra} if fused else {}
+            kw = self._meta_rider(ps, be)
             try:
                 be.write_objects(objs, dead_osds=self._dead(),
                                  **kw)
@@ -4088,21 +4113,25 @@ class OSDDaemon:
                 self._mark_suspects(be)
                 be.write_objects(objs, dead_osds=self._dead(),
                                  **kw)
-            if not fused:
+            if not self._meta_rode(ps):
                 self._persist_meta(ps)
             return b""
         if kind in ("write_at", "append"):
             # partial-stripe writes (r16): the backend routes each op
             # through the parity-delta RMW fast path (journaled, only
-            # touched + parity shards move) or the full-stripe ladder
+            # touched + parity shards move; the metadata rides its
+            # apply round) or the full-stripe ladder (which logs
+            # without the factory: _persist_meta's round follows)
             self._check_snapc(d.u64())
             trips = d.list(lambda dd: (dd.string(), dd.u64(),
                                        dd.blob()))
             self._snap_guard(ps, be, [n for n, _o, _b in trips])
             ops = [(n, be.object_sizes.get(n, 0) if kind == "append"
                     else off, blob) for n, off, blob in trips]
+            kw = self._meta_rider(ps, be)
             try:
-                be.write_ranges(ops, dead_osds=self._dead())
+                unacked = be.write_ranges(ops, dead_osds=self._dead(),
+                                          **kw)
             except (ConnectionError, OSError):
                 # a shard holder died mid-fan-out: suspect it and
                 # retry once degraded — the delta path refuses a
@@ -4110,8 +4139,13 @@ class OSDDaemon:
                 # RMW (and the journal's abort + superseded-version
                 # guard keep any half-logged intents inert)
                 self._mark_suspects(be)
-                be.write_ranges(ops, dead_osds=self._dead())
-            self._persist_meta(ps)
+                unacked = be.write_ranges(ops, dead_osds=self._dead(),
+                                          **kw)
+            # a shard that holds none of the op's bytes and did not
+            # take its metadata: suspected, as _persist_meta does
+            self.suspect.update(be.acting[s] for s in unacked or ())
+            if not self._meta_rode(ps):
+                self._persist_meta(ps)
             return b""
         if kind == "remove":
             self._check_snapc(d.u64())

@@ -351,6 +351,138 @@ class TestStripeJournalCrashMatrix:
             assert not (fr["errors"] or fr["extent_errors"]
                         or fr["bad_objects"]), (osd, fr)
 
+    @pytest.mark.parametrize("phase", PHASES)
+    def test_sigkill_with_the_metadata_riding_never_tears_nor_outranks(
+            self, phase, tmp_path):
+        """The same matrix with write_objects' `shard_txn_extra` passed
+        (PR 32): the wave is logged before the rounds and a record of
+        the PG's metadata rides every shard's apply transaction. The
+        post-crash primary knows what the freshest record on the
+        remounted stores says, no more: the stripe still resolves old
+        or new by the same table, and no shard's record names a
+        version its bytes do not hold. At `mid_apply` shard 0, which
+        moves no byte, holds the new version's record while all four
+        participants still hold their intents: the replay has to roll
+        forward under metadata that already names the write."""
+        import json
+        from ceph_tpu.osd.pglog import PGLog
+        root = str(tmp_path)
+        cluster = _tin_cluster(root)
+        be = ECBackend("plugin=tpu_rs k=4 m=2", "1.0",
+                       list(range(6)), cluster, chunk_size=256)
+
+        def riding(names):
+            rec = json.dumps({
+                "sizes": be.object_sizes, "versions": be.object_versions,
+                "applied": be.shard_applied,
+                "log": list(be.pg_log._entries)}).encode()
+            return lambda shard, t: t.omap_set(
+                shard_cid(be.pg, shard), "__pg_meta__", {b"rec": rec})
+        rng = np.random.default_rng(32)
+        base = rng.integers(0, 256, 3000, np.uint8)
+        be.write_objects({"o": base}, shard_txn_extra=riding)
+        patch = rng.integers(0, 256, 100, np.uint8)
+        new = base.copy()
+        new[500:600] = patch
+
+        def hook(p):
+            if p == phase:
+                for st in cluster.stores.values():
+                    st.crash()
+                raise _SimulatedKill(p)
+        be._rmw_crash_hook = hook
+        with pytest.raises(_SimulatedKill):
+            be.write_at("o", 500, patch, shard_txn_extra=riding)
+        for st in cluster.stores.values():
+            st.remount()
+        recs = [json.loads(dict(cluster.osd(s).omap_iter(
+            shard_cid("1.0", s), "__pg_meta__"))[b"rec"])
+            for s in range(6)]
+        heads = [rec["log"][-1][0] for rec in recs]
+        best = recs[int(np.argmax(heads))]
+        be2 = ECBackend("plugin=tpu_rs k=4 m=2", "1.0",
+                        list(range(6)), cluster, chunk_size=256,
+                        ensure_collections=False)
+        be2.object_sizes = dict(best["sizes"])
+        be2.object_versions = dict(best["versions"])
+        be2.shard_applied = list(best["applied"])
+        be2.pg_log = PGLog()
+        for v, name in best["log"]:
+            be2.pg_log.append_entry(v, name)
+        rep = be2.stripe_journal_replay()
+        got = be2.read_object("o")
+        state = "new" if np.array_equal(got, new) else \
+            "old" if np.array_equal(got, base) else "torn"
+        want = {"before_prepare": "old", "mid_prepare": "old",
+                "after_prepare": "new", "mid_apply": "new",
+                "after_apply": "new"}[phase]
+        assert state == want, (phase, state, rep, heads)
+        # version 1 is the full write, 2 the overwrite
+        assert max(heads) <= (2 if state == "new" else 1), heads
+        assert heads == {"mid_apply": [2, 1, 1, 1, 1, 1],
+                         "after_apply": [2] * 6}.get(phase, [1] * 6)
+        _assert_stores_match_oracle(be2, "o", new if state == "new"
+                                    else base)
+        assert be2.deep_scrub()["inconsistent"] == []
+        assert be2.stripe_journal_replay()["entries"] == 0
+
+    @pytest.mark.parametrize("lost", ["non-participant", "participant"])
+    def test_a_shard_that_does_not_acknowledge_the_apply_round(self,
+                                                               lost):
+        """With the metadata riding, the apply round reaches every
+        shard. One that moves no byte and does not acknowledge is
+        handed back to the caller (the daemon suspects it, as its
+        metadata persist does) and the write stands; a participant's
+        failure raises as it always did, the wave's log entries stay
+        (the retry logs past them) and the sizes are the stores'."""
+        be, cluster = _make("plugin=tpu_rs k=4 m=2", 256)
+        rng = np.random.default_rng(33)
+        base = rng.integers(0, 256, 3000, np.uint8)
+        be.write_objects({"o": base})
+        slot = 0 if lost == "non-participant" else 4   # 1, 2, 4, 5 move
+        store = cluster.osd(slot)
+        keep, calls = store.queue_transaction, []
+
+        def flaky(txn, *a, **kw):
+            calls.append(txn)
+            if len(calls) == (1 if slot == 0 else 2):  # its apply txn
+                raise ConnectionError("gone")
+            return keep(txn, *a, **kw)
+        store.queue_transaction = flaky
+        records = []
+
+        def riding(names):
+            records.append(list(names))
+            return lambda shard, t: t.omap_set(
+                shard_cid(be.pg, shard), "__pg_meta__", {b"rec": b"r"})
+        patch = rng.integers(0, 256, 100, np.uint8)
+        if lost == "non-participant":
+            assert be.write_at("o", 500, patch,
+                               shard_txn_extra=riding) == [0]
+            new = base.copy()
+            new[500:600] = patch
+            np.testing.assert_array_equal(be.read_object("o"), new)
+            # columns 1 and 2 and the two parity slots moved bytes
+            assert int(be.perf.get("rmw_shard_ios")) == 4
+        else:
+            with pytest.raises(ConnectionError):
+                be.append_objects({"o": patch}, shard_txn_extra=riding)
+            assert be.object_sizes["o"] == 3000
+            assert be.pg_log.head == be.object_versions["o"] == 2
+            # the retry, degraded: the full path, one version on
+            be.append_objects({"o": patch}, dead_osds={4},
+                              shard_txn_extra=riding)
+            assert be.pg_log.head == be.object_versions["o"] == 3
+            np.testing.assert_array_equal(
+                be.read_object("o", dead_osds={4}),
+                np.concatenate([base, patch]))
+            # whatever the failed wave left in the journal is inert
+            assert be.stripe_journal_replay(dead_osds={4})["forward"] == 0
+            np.testing.assert_array_equal(
+                be.read_object("o", dead_osds={4}),
+                np.concatenate([base, patch]))
+        assert records == [["o"]]
+
     def test_replay_seq_reanchors_past_crash(self, tmp_path):
         """New RMWs after a replay must not reuse journal sequence
         numbers an old watermark already covers (a reused seq would

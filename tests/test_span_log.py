@@ -17,7 +17,8 @@ hinfo in the answer: the sub-op kinds the OSDs serve, `gather_rounds`
 and `gather_frames`, a slot planned around, rot still caught, and the
 peer-latency EWMA fed by the pipelined reads.
 A partial overwrite (PR 31) leaves `ecbackend.rmw`, its six children and
-`osd.persist_meta`, one case each, and nothing with no session;
+`osd.persist_meta` (PR 32: the record's encode, inside the RMW, sent on
+its one apply round), one case each, and nothing with no session;
 `rmw_host_delta_launches` tells the host's delta launches from the
 device's; the log counts the records it drops.
 """
@@ -407,8 +408,8 @@ RMW_STAGES = ("ecbackend.rmw", "ecbackend.rmw.prefetch",
 class TestOverwriteSpansAndCounters:
     """`Client.write_at` inside one stripe of a stored object: the
     parity-delta RMW on the fused device program (the module's cluster
-    has the CPU backend's host shortcut off), then the PG's metadata
-    persist."""
+    has the CPU backend's host shortcut off), the PG's metadata record
+    on its apply round (PR 32)."""
 
     def test_no_session_a_write_at_logs_nothing(self, cluster, client):
         client.write({"over-dark": b"o" * 3000})
@@ -427,12 +428,14 @@ class TestOverwriteSpansAndCounters:
         t0 = time.perf_counter()
         before = {k: _ec(cluster, k) for k in
                   ("rmw_ops", "rmw_delta_launches",
-                   "rmw_host_delta_launches", "rmw_full_fallbacks")}
+                   "rmw_host_delta_launches", "rmw_full_fallbacks",
+                   "meta_rides", "meta_persist_rounds")}
         client.write_at("over", 40, b"n" * 100)
         got = _mine(t0)
         assert {k: _ec(cluster, k) - v for k, v in before.items()} == {
             "rmw_ops": 1, "rmw_delta_launches": 1,
-            "rmw_host_delta_launches": 0, "rmw_full_fallbacks": 0}
+            "rmw_host_delta_launches": 0, "rmw_full_fallbacks": 0,
+            "meta_rides": 1, "meta_persist_rounds": 0}
         assert is_span_declared(stage) and stage in got, sorted(got)
         assert "ecbackend.rmw.full" not in got
         for rec in got[stage]:
@@ -441,18 +444,25 @@ class TestOverwriteSpansAndCounters:
         tid = got["client.op"][-1]["trace_id"]
         assert rmw["trace_id"] == op["trace_id"] == tid
         if stage == "ecbackend.rmw":
-            # the six children cover it: its self time is what is left
-            kids = sum(r["dur"] for name in RMW_STAGES[1:7]
+            # the six children and the metadata record's encode cover
+            # it: its self time is what is left
+            kids = sum(r["dur"] for name in RMW_STAGES[1:]
                        for r in got[name])
             assert rmw["self"] == pytest.approx(rmw["dur"] - kids)
             # the rows' delta and the pad to the bucket: two stage spans
             assert len(got["ecbackend.rmw.delta.stage"]) == 2
+            # three rounds, one of each
+            assert [len(got[f"ecbackend.rmw.{r}"]) for r in
+                    ("prefetch", "journal", "apply")] == [1, 1, 1]
         elif stage == "osd.persist_meta":
-            # after the RMW, inside the op, its sends among its children
-            (meta,) = got[stage]
-            assert rmw["start"] + rmw["dur"] <= meta["start"] + 1e-6
-            assert meta["start"] + meta["dur"] <= op["start"] + op["dur"] + 1e-6
-            assert meta["self"] < meta["dur"]
+            # one record: the encode, inside the RMW and before its
+            # journal round; no send among its children, the apply
+            # round carries it
+            (meta,), (journal,) = got[stage], got["ecbackend.rmw.journal"]
+            assert rmw["start"] <= meta["start"]
+            assert meta["start"] + meta["dur"] <= journal["start"] + 1e-6
+            assert meta["self"] == pytest.approx(meta["dur"])
+            assert meta["trace_id"] == tid
         else:
             (rec,) = got[stage][-1:]
             assert rmw["start"] <= rec["start"]
