@@ -137,6 +137,15 @@ class Tunables:
     choose_total_tries: int = 7
 
 
+#: upstream's erasure-code rule carries `step set_choose_tries 100`
+#: (docs, "CRUSH gives up too soon": indep placement of k+m slots on
+#: few more than k+m OSDs decides late slots in late rounds). The rule
+#: grammar here has no such step, so a cluster that builds an EC pool's
+#: map states it as the map's tunable; the mapper's retry loop runs
+#: only while a slot is undecided, so unused rounds cost nothing.
+EC_RULE_CHOOSE_TRIES = 100
+
+
 @dataclass
 class Bucket:
     id: int                      # negative

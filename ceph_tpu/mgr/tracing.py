@@ -77,11 +77,15 @@ CATEGORY_OF = {
     "ecbackend.rmw.apply": "wire",
     "ecbackend.rmw.full": "encode",
     "osd.persist_meta": "wire",
-    "ecbackend.recover.stage": "encode",
-    "ecbackend.recover.launch": "encode",
-    "ecbackend.recover.fetch": "encode",
-    "ecbackend.recover.batch": "encode",
-    "ecbackend.recover.writeback": "store",
+    "osd.recovery_round": "queue",
+    "recovery.reserve.wait": "queue",
+    "recovery.grant": "queue",
+    "recovery.pull": "wire",
+    "recovery.stage": "encode",
+    "recovery.launch": "encode",
+    "recovery.fetch": "encode",
+    "recovery.push": "store",
+    "recovery.settle": "store",
     "store.apply": "store",
     "store.commit": "store",
     "store.read": "store",

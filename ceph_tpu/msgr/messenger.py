@@ -65,8 +65,10 @@ frame order needs no cross-thread coordination. The contract:
   the lock gather-flushes the whole queue in ONE sendmsg (many frames
   per syscall). A socket that won't drain arms EVENT_WRITE and the
   reactor resumes from the exact byte. Senders block on a byte-budget
-  backpressure cap (never reactor threads — they may hold frames
-  other connections are waiting on).
+  backpressure cap in `Messenger.send`, before the per-peer order
+  lock (never reactor threads — they may hold frames other
+  connections are waiting on; never a replay, which runs inside that
+  lock and resends what the unacked queue holds already).
 * DISPATCH is fast by default (the ms_fast_dispatch role): handlers
   run inline on the reactor, so they must never wait for another
   frame of the SAME messenger to make progress. Handlers that block
@@ -594,27 +596,37 @@ class _Conn:
 
     # -- write queue (reactor-bound conns) ------------------------------------
 
-    def _enqueue_locked(self, parts: list) -> None:
-        """Append wire-ready parts and flush opportunistically. Caller
-        holds wlock. Blocks on the byte budget — except on reactor
-        threads, which must never wait on another conn's drain."""
-        if not self.alive:
-            raise ConnectionError("connection closed")
-        if (self._wq_bytes > _WQ_HIGH
-                and not getattr(_TLS, "in_reactor", False)):
+    def await_budget(self) -> None:
+        """Wait out the write queue's byte budget holding nothing but
+        the queue's own lock (which the wait lets go). The one place
+        that waits: `Messenger.send` calls this BEFORE it takes the
+        per-peer order lock. A sender parked on the budget inside that
+        lock parks the reactor behind it (a pong or a reply to the same
+        peer takes the lock too), and the reactor is who drains the
+        queue. Reactor threads never wait on another conn's drain; a
+        replay (inside the peer lock by need) resends what the unacked
+        queue holds already, and an ack is eight bytes: neither waits."""
+        with self.wlock:
+            if (self._wq_bytes <= _WQ_HIGH
+                    or getattr(_TLS, "in_reactor", False)):
+                return
             t0 = _time_mod.perf_counter()
             while self.alive and self._wq_bytes > _WQ_HIGH // 2:
                 self._wcond.wait(0.2)
             dt = _time_mod.perf_counter() - t0
-            if self.perf is not None:
-                self.perf.inc("writeq_stalls")
-                self.perf.tinc("writeq_stall_time", dt)
-            if self.flow is not None:
-                with self.flow_lock:
-                    self.flow["stalls"] += 1
-                    self.flow["stall_time_s"] += dt
-            if not self.alive:
-                raise ConnectionError("connection closed")
+        if self.perf is not None:
+            self.perf.inc("writeq_stalls")
+            self.perf.tinc("writeq_stall_time", dt)
+        if self.flow is not None:
+            with self.flow_lock:
+                self.flow["stalls"] += 1
+                self.flow["stall_time_s"] += dt
+
+    def _enqueue_locked(self, parts: list) -> None:
+        """Append wire-ready parts and flush opportunistically. Caller
+        holds wlock. Never waits: the byte budget is `await_budget`'s."""
+        if not self.alive:
+            raise ConnectionError("connection closed")
         for p in parts:
             if len(p):
                 self._wq.append(memoryview(p))
@@ -1277,6 +1289,12 @@ class Messenger:
         if victim is not None and victim.alive:
             self._inject_fired += 1
             victim.close()
+        # the byte budget first, under no lock of this messenger: see
+        # `_Conn.await_budget`
+        with self._lock:
+            conn = self._conns.get(peer)
+        if conn is not None and conn.alive:
+            conn.await_budget()
         with self._plock(peer):
             with self._lock:
                 seq = self._out_seq.get(peer, 0) + 1

@@ -21,8 +21,8 @@ from __future__ import annotations
 
 import numpy as np
 
-from ..crush.map import (CRUSH_ITEM_NONE, Tunables, build_hierarchy, ec_rule,
-                         replicated_rule)
+from ..crush.map import (CRUSH_ITEM_NONE, EC_RULE_CHOOSE_TRIES, Tunables,
+                         build_hierarchy, ec_rule, replicated_rule)
 from ..utils.log import g_log
 from ..utils.perf_counters import PerfCountersBuilder
 from .ecbackend import ECBackend, ShardSet
@@ -64,7 +64,6 @@ class SimCluster:
         # several OSDs are out; the vectorized mapper's while_loop
         # early-exits, so unused rounds cost nothing
         crush.tunables = Tunables(choose_total_tries=51)
-        self.osdmap = OSDMap(crush)
         self.cluster = ShardSet()
         # store backend switch (the store_test.cc parameterization):
         # "mem" = RAM MemStore (process death keeps bytes by fiat);
@@ -144,6 +143,9 @@ class SimCluster:
             self.m = coder.get_coding_chunk_count()
             min_size = self.pool_size - self.m
             ec_rule(crush, 1, choose_type=choose_type)
+            # as upstream's EC rule (crush/map.py)
+            crush.tunables = Tunables(
+                choose_total_tries=EC_RULE_CHOOSE_TRIES)
         else:
             self.pool_size = int(prof.get("size", 3))
             min_size = int(prof.get("min_size",
@@ -158,6 +160,9 @@ class SimCluster:
                 f"{self.pool_size}; add osds/hosts/racks (e.g. "
                 f"hosts_per_rack=) or pick a finer domain")
         self.pool_min_size = min_size
+        # built once the rule and its tries stand: the mappers read the
+        # tunable when they are made
+        self.osdmap = OSDMap(crush)
         self.osdmap.add_pool(PGPool(1, pg_num=pg_num, size=self.pool_size,
                                     min_size=min_size,
                                     crush_rule=1,

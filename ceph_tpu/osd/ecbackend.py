@@ -85,7 +85,29 @@ def ec_perf_counters():
                              "read-path rebuilds computed on the "
                              "host (a codec's impl=ref numpy oracle)")
             .add_u64_counter("recover_launches",
-                             "fused recovery launches")
+                             "recovery decode launches (fused device "
+                             "program, or a codec's generic path)")
+            .add_u64_counter("recover_host_launches",
+                             "recovery decodes outside the fused "
+                             "device program: a codec without a "
+                             "static decode plan (`decode_chunks` a "
+                             "batch), and the re-decode of objects "
+                             "whose helper rows failed hinfo "
+                             "(`_recover_fallback`): 0 where the "
+                             "fused program rebuilt every object")
+            .add_u64_counter("recover_programs_ready",
+                             "planned PGs whose recover program was "
+                             "built (compiled or loaded for the "
+                             "grant's shape) before a reservation was "
+                             "asked for")
+            .add_u64("recover_programs_pending",
+                     "planned PGs whose recover program is still "
+                     "being built in the background")
+            .add_u64("recover_grant_bytes_max",
+                     "most helper bytes one recovery launch staged "
+                     "(high-water mark; bounded by "
+                     "osd_recovery_max_active x "
+                     "osd_recovery_max_chunk on the wire tier)")
             .add_u64_counter("program_cache_hits",
                              "compiled-program cache hits")
             .add_u64_counter("program_cache_misses",
@@ -135,7 +157,8 @@ def ec_perf_counters():
                           "read-path crc verify wall time (stage, "
                           "launch, blocking fetch)", hist=True)
             .add_time_avg("recover_stage_time",
-                          "recovery host staging (producer thread)")
+                          "recovery's pull: the helper rows read into "
+                          "the stage buffer (`recovery.pull`)")
             .add_time_avg("recover_launch_time",
                           "recovery launch enqueue + async D2H start",
                           hist=True)
@@ -1815,7 +1838,25 @@ class ECBackend(PGBackend):
         group, Clay single-loss reads only the repair planes (the
         runner ships sub-chunk ranges), SHEC/RS rank by the optional
         per-helper `helper_costs` (slot -> cost; the daemon feeds its
-        complaint memory + peer-latency EWMAs)."""
+        complaint memory + peer-latency EWMAs).
+
+        A call to a replacement OSD can fail (a timeout on a busy
+        host) after the slots were re-pointed: they are put back, or
+        the caller's next look would find acting as the map has it,
+        nothing lost, and a PG that reads clean with a shard that was
+        never rebuilt."""
+        was = list(self.acting)
+        try:
+            return self._plan_recovery(
+                lost_shards, replacement_osds, verify_hinfo, names,
+                helper_exclude, helper_costs)
+        except BaseException:
+            self.acting[:] = was
+            raise
+
+    def _plan_recovery(self, lost_shards, replacement_osds, verify_hinfo,
+                       names, helper_exclude, helper_costs
+                       ) -> "_RecoveryPlan":
         lost = sorted(set(lost_shards))
         if len(lost) > self.m:
             raise ValueError(f"{len(lost)} lost shards exceeds m={self.m}")
@@ -1948,6 +1989,7 @@ class ECBackend(PGBackend):
             stacks = {s: np.stack([self._store(s).read(
                 shard_cid(self.pg, s), n) for n in names_])
                 for s in alt_need}
+            self.perf.inc("recover_host_launches")
             alt_rec = self.coder.decode_chunks(lost, stacks)
             for li, s in enumerate(lost):
                 rec_s = np.asarray(alt_rec[s])
@@ -2121,8 +2163,13 @@ _RECOVER_PROGRAMS_LOCK = _threading.Lock()
 RECOVERY_FETCH_BYTES = 8 << 20
 
 
-#: helper bytes one fused recovery launch stages (pow2 objects, one at
-#: least). The launch's device scratch is ~13x what it stages at 512 KiB
+#: the CEILING on the helper bytes one fused recovery launch stages
+#: (pow2 objects, one at least). What sizes a launch is the runner's
+#: byte budget where it has one: on the wire tier
+#: osd_recovery_max_active x osd_recovery_max_chunk (24 MiB by default:
+#: 4 objects of 4 MiB at k=8), the budget of the push window; this
+#: constant bounds larger settings and the runners without a window
+#: (`recover_shards`, tools). The launch's device scratch is ~13x what it stages at 512 KiB
 #: rows (compiled for a described v5e: 1.7 GiB at 128 MiB staged,
 #: 6.2 GiB at the 512 MiB that osd_recovery_batch=128 objects of 4 MiB
 #: would stage), two launches are in flight per runner, and every
@@ -2344,7 +2391,7 @@ class _RecoveryPlan:
     __slots__ = ("be", "lost", "helper", "survivors", "verify",
                  "full_plan", "provided", "counters", "names_by_len",
                  "dec_fn", "group_key", "remaining", "done",
-                 "repair", "range_planes", "sub_count")
+                 "repair", "range_planes", "sub_count", "_counted")
 
     def __init__(self, be, lost, helper, survivors, verify, full_plan,
                  provided):
@@ -2356,6 +2403,7 @@ class _RecoveryPlan:
         self.full_plan = full_plan
         self.provided = provided
         self.counters = {"objects": 0, "bytes": 0, "hinfo_failures": 0}
+        self._counted = dict(self.counters)   # what the perf counters have
         self.names_by_len: dict[int, list[str]] = {}
         self.dec_fn = None
         self.group_key = None
@@ -2379,6 +2427,14 @@ class _RecoveryPlan:
         return (len(self.range_planes) * s,
                 coalesce_ranges((z * s, s) for z in self.range_planes))
 
+    def count(self) -> None:
+        """Fold what was rebuilt since the last call into the perf
+        counters: once a batch, so that `recovered_objects` rises while
+        a long round runs and not in one step at its end."""
+        new = {k: v - self._counted[k] for k, v in self.counters.items()}
+        self._counted = dict(self.counters)
+        self.be._count_recovery(new)
+
     def finish(self) -> None:
         """Count the work done; advance applied cursors only when every
         planned name landed (a partial round must not defeat the
@@ -2389,7 +2445,7 @@ class _RecoveryPlan:
         if not self.remaining:
             self.be._mark_caught_up(self.lost, self.full_plan,
                                     self.provided)
-        self.be._count_recovery(self.counters)
+        self.count()
 
 
 class RecoveryRunner:
@@ -2405,6 +2461,16 @@ class RecoveryRunner:
     ride the same pipeline side by side with their own programs. The
     batch dim is pow2-bucketed like the write path (ragged tails would
     compile one program per size).
+
+    What sizes a batch: as many objects (a power of two, one at least)
+    as stage `push_window_bytes` of helper rows — the one byte budget of
+    a grant, which on the wire tier is osd_recovery_max_active x
+    osd_recovery_max_chunk and also bounds the push window — under the
+    ceilings `batch` (osd_recovery_batch) and RECOVERY_STAGE_BYTES; a
+    runner without a budget (`recover_shards`, tools) takes the
+    ceilings. So a grant of the wire tier holds its primary's worker,
+    the daemon lock and the PG locks for a few objects' worth of pull,
+    launch and push, whatever the backlog.
 
     Pipelining: launches dispatch async with copy_to_host_async, one
     batch ahead (results stream back under the next batch's staging);
@@ -2440,7 +2506,9 @@ class RecoveryRunner:
                       "push_stalls": 0, "push_max_inflight_bytes": 0,
                       "skipped_stale": 0,
                       "host_crc": self._host_crc}
+        from ..ops.rs_kernels import pow2_bucket
         self._batches: list = []
+        self._buckets: dict[int, int] = {}   # batch index -> launch rows
         groups: dict = {}
         order: list = []
         for plan in self.plans:
@@ -2464,10 +2532,18 @@ class RecoveryRunner:
             pairs = groups[key]
             proto = pairs[0][0]
             rl, _ranges = proto.row_ranges(key[1])
-            fit = RECOVERY_STAGE_BYTES // max(1, len(proto.helper) * rl)
+            budget = min(RECOVERY_STAGE_BYTES,
+                         self._push_bytes_cap or RECOVERY_STAGE_BYTES)
+            fit = budget // max(1, len(proto.helper) * rl)
             per = min(self.batch, 1 << max(0, fit.bit_length() - 1))
+            # one launch shape a group: a group of several batches
+            # launches its short tail padded to the whole batch (zeros
+            # cost a launch milliseconds; a program of its own for the
+            # tail's bucket costs a compile)
+            bucket = pow2_bucket(min(per, len(pairs)))
             for i in range(0, len(pairs), per):
                 sub = pairs[i:i + per]
+                self._buckets[len(self._batches)] = bucket
                 self._batches.append(("fused", proto, key[1], sub))
         self._bi = 0
         self._pending: list = []
@@ -2489,6 +2565,11 @@ class RecoveryRunner:
             sl, pairs = self._pending[0][0], self._pending[0][2]
             return sl * len(pairs)
         return 1
+
+    def next_stage_bytes(self) -> int:
+        """Helper bytes the next step is to stage: 0 where it only
+        completes a launch already made."""
+        return self.next_cost() if self._bi < len(self._batches) else 0
 
     def next_helper_osds(self) -> list[int]:
         """Distinct source OSD ids the NEXT batch pulls helper rows
@@ -2518,7 +2599,7 @@ class RecoveryRunner:
             if kind == "generic":
                 self._run_generic(plan, sl, payload)
             else:
-                self._launch(sl, payload)
+                self._launch(sl, payload, self._buckets[self._bi - 1])
                 if len(self._pending) >= 2:
                     self._complete(self._pending.pop(0))
         elif self._pending:
@@ -2526,6 +2607,29 @@ class RecoveryRunner:
         else:
             return False
         return self._bi < len(self._batches) or bool(self._pending)
+
+    def prepare(self) -> None:
+        """Build every program the round will launch, at the shape it
+        will launch it, before the first `step()`: one pass of zeros
+        through each (program, planned batch shape), so that jax holds
+        the compiled program and no launch compiles. The caller holds no
+        lock (a wire-tier step runs under the daemon lock and every
+        member PG's lock, and a program takes seconds to compile)."""
+        import jax
+        seen = set()
+        for bi, (kind, proto, sl, _pairs) in enumerate(self._batches):
+            if kind != "fused":
+                continue
+            rl, _ranges = proto.row_ranges(sl)
+            shape = (self._buckets[bi], len(proto.helper), rl)
+            program = self._program(proto)
+            if (id(program), shape) in seen:
+                continue
+            seen.add((id(program), shape))
+            stack = self._stage_buffer(*shape)
+            jax.block_until_ready(
+                program(stack) if self._host_crc
+                else program(stack, np.zeros(shape[0], np.uint32)))
 
     def run(self) -> None:
         while self.step():
@@ -2625,10 +2729,11 @@ class RecoveryRunner:
             self._stage_bufs[key] = buf
         return buf
 
-    def _launch(self, sl: int, pairs) -> None:
+    def _launch(self, sl: int, pairs, bucket: int) -> None:
+        """Stage and dispatch one fused batch, `bucket` rows wide: the
+        planned shape that `prepare()` built, whatever names were
+        skipped since."""
         import jax
-
-        from ..ops.rs_kernels import pow2_bucket
         proto = pairs[0][0]
         helper = proto.helper
         H = len(helper)
@@ -2649,25 +2754,25 @@ class RecoveryRunner:
         if not live:
             return
         B = len(live)
-        bucket = pow2_bucket(B)
         stack = self._stage_buffer(bucket, H, rl)
         exp = np.zeros((B, H), dtype=np.uint32)
-        with span("ecbackend.recover.stage", counters=self.perf,
-                  key="recover_stage_time"):
+        wire = B * H * rl
+        # helper rows in: one readv frame a (PG, helper shard), all on
+        # the wire before any answer is taken
+        with span("recovery.pull", counters=self.perf,
+                  key="recover_stage_time", nbytes=wire):
             pre_bad = self._stage(live, sl, rl, stack, exp,
                                   proto.verify)
-        wire = B * H * rl
         self.stats["helper_bytes_on_wire"] += wire
         self.perf.inc("recover_wire_bytes", wire)
-        if bucket != B:
-            stack[B:] = 0
-        program = self._program(proto)
-        self.perf.inc("recover_launches")
-        with span("ecbackend.recover.launch", counters=self.perf,
-                  key="recover_launch_time"):
-            if self._host_crc:
-                handles = program(stack)
-            else:
+        if wire > self.perf.get("recover_grant_bytes_max"):
+            self.perf.set("recover_grant_bytes_max", wire)
+        with span("recovery.stage"):
+            if bucket != B:
+                stack[B:] = 0
+            program = self._program(proto)
+            expfold = None
+            if not self._host_crc:
                 expfold = np.zeros(bucket, dtype=np.uint32)
                 if proto.verify:
                     expfold[:B] = _expected_fold_crcs(exp, rl)
@@ -2675,7 +2780,12 @@ class RecoveryRunner:
                     # raw CRC is just the seed shifted through rl zero
                     # bytes — match it so padding never "fails"
                     expfold[B:] = _fold_seed_const(rl)
-                handles = program(stack, expfold)
+        self.perf.inc("recover_launches")
+        # nbytes: the helper rows this launch decodes, B objects of H
+        with span("recovery.launch", counters=self.perf,
+                  key="recover_launch_time", nbytes=wire):
+            handles = program(stack) if self._host_crc \
+                else program(stack, expfold)
             for h in handles:
                 try:
                     h.copy_to_host_async()
@@ -2828,7 +2938,7 @@ class RecoveryRunner:
         sl, rl, live, handles, exp, pre_bad = entry
         B = len(live)
         proto = live[0][0]
-        with span("ecbackend.recover.fetch", counters=self.perf,
+        with span("recovery.fetch", counters=self.perf,
                   key="recover_fetch_time"):
             got = jax.device_get(handles)
         if self._host_crc:
@@ -2868,7 +2978,7 @@ class RecoveryRunner:
                 if bad:
                     plan.counters["hinfo_failures"] += len(bad)
                     bad_by_plan.setdefault(id(plan), {})[name] = bad
-        with span("ecbackend.recover.writeback", counters=self.perf,
+        with span("recovery.push", counters=self.perf,
                   key="recover_writeback_time"):
             for plan, r0, names in self._segments(live):
                 nb = len(names)
@@ -2900,6 +3010,7 @@ class RecoveryRunner:
                         seg_rebuilt[keep], seg_crcs[keep], sl,
                         plan.counters, window=self)
                 plan.remaining.difference_update(names)
+                plan.count()
 
     # -- generic path (codecs without a static decode plan) ----------------
 
@@ -2918,7 +3029,8 @@ class RecoveryRunner:
         names = live
         if not names:
             return
-        self.perf.inc("recover_launches")
+        self.perf.inc_many((("recover_launches", 1),
+                            ("recover_host_launches", 1)))
         self.stats["batches"] += 1
         self.stats["generic_batches"] += 1
         wire = len(plan.helper) * sl * len(names)
@@ -2950,3 +3062,4 @@ class RecoveryRunner:
         be._writeback_rebuilt(plan.lost, names, rebuilt_all, crcs, sl,
                               plan.counters, window=self)
         plan.remaining.difference_update(names)
+        plan.count()

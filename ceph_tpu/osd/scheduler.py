@@ -294,8 +294,20 @@ class MClockScheduler:
         if not q.busy:
             # idle->busy: tags restart from now — no banked credit, and
             # no arrival penalty (dmclock assigns the first request
-            # R = max(now, ...) = now)
-            r_tag = now if p.reservation else float("inf")
+            # R = max(now, ...) = now). Where the shard is overloaded
+            # (reservations it cannot meet: a busy class's R tags run
+            # behind the clock and keep their credit, below), "now" for
+            # a class that comes back is the busy classes' virtual
+            # time, the R tag the most-lagging of them was last served
+            # at (start-time fair queuing): with the wall clock's, a
+            # class whose queue ran empty for a moment (a client's,
+            # between two ops) would wait out the whole credit of one
+            # that never idles (a recovery round, which re-enqueues
+            # itself): seconds, growing with the round's length
+            vt = min((c.r_prev for c in self._classes.values()
+                      if c.busy and c is not q and c.profile.reservation),
+                     default=now)
+            r_tag = min(now, vt) if p.reservation else float("inf")
             l_tag = now
             p_tag = now + cost / p.weight
         else:

@@ -117,9 +117,9 @@ declare_span_names(
     "osd.persist_meta",
     "pgbackend.crcs.stage", "pgbackend.crcs.launch",
     "pgbackend.crcs.fetch",
-    "ecbackend.recover.stage", "ecbackend.recover.launch",
-    "ecbackend.recover.fetch", "ecbackend.recover.writeback",
-    "ecbackend.recover.batch",
+    "recovery.reserve.wait", "recovery.grant", "recovery.pull",
+    "recovery.stage", "recovery.launch", "recovery.fetch",
+    "recovery.push", "recovery.settle",
 )
 
 
@@ -261,6 +261,39 @@ class MOSDAlive(Message):
     def decode_payload(cls, d: Decoder) -> "MOSDAlive":
         d.start(1)
         m = cls(d.i32(), d.u64())
+        d.finish()
+        return m
+
+
+@register_message
+class MBackfillReserve(Message):
+    """One frame of the backfill reservation exchange between a PG's
+    primary and an OSD its plan moves bytes to or from (ref:
+    MBackfillReserve / MRecoveryReserve REQUEST / GRANT / RELEASE;
+    upstream's REVOKE and REJECT_TOOFULL have no user here: the
+    backfillfull gate parks a plan before it is made). `op` REQUEST,
+    primary -> target: a slot for PG `ps`, queued by `prio` (the plan's
+    risk key; PG id last) where the target's `osd_max_backfills` slots
+    are taken; `epoch` is the map the plan was made under. GRANT,
+    target -> primary. RELEASE, primary -> target: the PG is done,
+    failed or re-planned."""
+
+    type_id = 0x4F
+    REQUEST, GRANT, RELEASE = 0, 1, 2
+
+    def __init__(self, op: int, ps: int, primary: int, epoch: int = 0,
+                 prio: tuple = (0, 0.0)):
+        self.op, self.ps, self.primary = op, ps, primary
+        self.epoch, self.prio = epoch, (int(prio[0]), float(prio[1]))
+
+    def encode_payload(self, e: Encoder) -> None:
+        (e.start(1, 1).u8(self.op).u32(self.ps).i32(self.primary)
+         .u32(self.epoch).i32(self.prio[0]).f64(self.prio[1]).finish())
+
+    @classmethod
+    def decode_payload(cls, d: Decoder) -> "MBackfillReserve":
+        d.start(1)
+        m = cls(d.u8(), d.u32(), d.i32(), d.u32(), (d.i32(), d.f64()))
         d.finish()
         return m
 
@@ -1165,54 +1198,83 @@ class _PgClsView:
         return self._d.obj_kv.setdefault(self._ps, {})
 
 
-class _RecoveryRound:
-    """One mClock-governed pass of the cross-PG recovery runner: every
-    grant executes ONE fused batch under the daemon lock then yields
-    (re-enqueues itself, after osd_recovery_sleep), so client ops
-    interleave between batches instead of waiting out the whole
-    rebuild. The runner's push window is sized by the recovery
-    reservation knobs: osd_recovery_max_active in-flight pushes,
-    osd_recovery_max_active * osd_recovery_max_chunk bytes."""
+class _Backfill:
+    """One planned PG on its way from the map that re-pointed its slot
+    to clean (ref: PeeringState's WaitLocalBackfillReserved ->
+    WaitRemoteBackfillReserved -> Backfilling). `OSDDaemon._recovering`
+    holds it for all of that time; the PG is degraded and serves
+    meanwhile, and no client op ever waits for a reservation.
 
-    def __init__(self, daemon: "OSDDaemon", entries):
-        from .ecbackend import RecoveryRunner
+    building   its recover program is being built in the background
+    ready      built; waits for one of the primary's
+               `osd_max_backfills` local slots
+    reserving  holds a local slot; asks the OSDs of `targets` for a
+               remote one, one after the other in ascending id (one
+               order for every PG: no two can wait for each other)
+    reserved   every one granted
+    running    member of a `_RecoveryRound`
+    done       settled, failed or replaced: its slots are given back
+
+    All of it moves under the daemon's `_bf_lock` (a leaf lock)."""
+
+    __slots__ = ("ps", "plan", "dead", "prio", "epoch", "targets",
+                 "state", "waiting", "t_asked", "failed")
+
+    def __init__(self, ps: int, plan, dead: set, prio: tuple,
+                 epoch: int, targets: set):
+        self.ps, self.plan, self.dead = ps, plan, dead
+        self.prio, self.epoch = prio, epoch
+        # every OSD the plan moves bytes to or from but this one: the
+        # new members of the lost slots and the helpers
+        self.targets = set(targets)
+        self.state = "building"
+        self.waiting: list[int] = []      # not yet granted, ascending
+        self.t_asked = 0.0
+        self.failed = False
+
+    def lost_of(self, ps: int) -> list[int]:
+        return self.plan.lost
+
+    def holds_slot(self) -> bool:
+        return self.state in ("reserving", "reserved", "running")
+
+
+class _RecoveryRound:
+    """One mClock-governed pass of the cross-PG recovery runner over
+    the PGs that hold their backfill reservations together (one PG at
+    `osd_max_backfills` 1): every grant executes ONE fused batch under
+    the daemon lock and the member PGs' locks, then yields (re-enqueues
+    itself, after osd_recovery_sleep), so client ops interleave between
+    batches instead of waiting out the whole rebuild.
+
+    What sizes a grant: osd_recovery_max_active x
+    osd_recovery_max_chunk bytes of helper rows (24 MiB by default: 4
+    objects of 4 MiB at k=8, the power of two below 6), the one budget
+    that also bounds the runner's push window (osd_recovery_max_active
+    frames in flight); RECOVERY_STAGE_BYTES and osd_recovery_batch are
+    ceilings for larger settings. The round's device programs were
+    built when its PGs were planned (`OSDDaemon._backfill_build`), so
+    no grant compiles."""
+
+    def __init__(self, daemon: "OSDDaemon", backfills):
         self.d = daemon
-        self.entries = entries            # [(ps, plan, dead osd ids)]
-        self.plans = {ps: plan for ps, plan, _ in entries}
+        self.backfills = list(backfills)  # `_Backfill`s, reserved
+        self.plans = {bf.ps: bf.plan for bf in self.backfills}
         self.dead: set[int] = set()
-        for _ps, _plan, dead in entries:
-            self.dead |= dead
-        cfg = daemon.config
-        max_active = int(cfg["osd_recovery_max_active"])
-        # r17: the integrity mode resolves through config (auto keeps
-        # the pre-r17 native-detect; 'device' forces the fused
-        # decode+fold on-device; 'host' asserts the native crc path
-        # when the lib is present — the storm bench verifies rebuilt
-        # bytes against the full-decode oracle in both modes)
-        from .ecbackend import _host_crc_available
-        integ = str(cfg["osd_recovery_integrity"]).lower()
-        host_crc = (False if integ == "device"
-                    else True if integ == "host"
-                    and _host_crc_available() else None)
-        self.runner = RecoveryRunner(
-            [plan for _ps, plan, _dead in entries],
-            batch=int(cfg["osd_recovery_batch"]),
-            perf=daemon.ec_perf,
-            push_window_ops=max_active,
-            push_window_bytes=max_active
-            * int(cfg["osd_recovery_max_chunk"]),
-            host_crc=host_crc)
+        for bf in self.backfills:
+            self.dead |= bf.dead
+        self.runner = daemon._recovery_runner(list(self.plans.values()))
         self.failed = False
         # r15: recovery rounds get their own sampled trace context
-        # (rate-gated) — every fused batch then records its stage/
-        # launch/fetch/writeback spans, and the readv/readv_ranges
+        # (rate-gated) — every fused batch then records its pull/
+        # stage/launch/fetch/push spans, and the readv/readv_ranges
         # helper pulls carry the context to their sources, whose
         # osd.subop spans land under the same trace.
         from ..utils.flight_recorder import (TraceContext, coin,
                                              new_trace_id)
         self.trace_ctx = None
         try:
-            rate = float(cfg["osd_trace_recovery_sample_rate"])
+            rate = float(daemon.config["osd_trace_recovery_sample_rate"])
         except (KeyError, ValueError):
             rate = 0.0
         if coin(rate):
@@ -1236,15 +1298,21 @@ class _RecoveryRound:
 
     def __call__(self) -> None:
         # each grant executes one fused batch under the round's trace
-        # context (if sampled): the stage/launch/fetch/writeback spans
-        # and the helper pulls' osd.subop spans all land in one trace
+        # context (if sampled): the pull/stage/launch/fetch/push spans
+        # and the helper pulls' osd.subop spans all land in one trace.
+        # `osd.recovery_round` is the flight recorder's record of the
+        # grant (with its PGs); `recovery.grant` the profiler's and
+        # the span log's, `nbytes` the helper bytes it is to stage
         from ..utils.flight_recorder import activate, trace_span
         with activate(self.trace_ctx,
                       self.d.flight if self.trace_ctx is not None
                       else None):
             with trace_span("osd.recovery_round",
                             pgs=sorted(self.plans)):
-                self._grant()
+                with span("recovery.grant",
+                          nbytes=self.runner.next_stage_bytes()):
+                    self.d.perf.inc("recovery_grants")
+                    self._grant()
 
     def _domain_throttle(self) -> float:
         """r17 per-failure-domain repair budget: the next batch's
@@ -1304,8 +1372,9 @@ class _RecoveryRound:
                     if self.runner.step():
                         pass                # yield below
                     else:
-                        self.runner.finish()
-                        self._settle_locked()
+                        with span("recovery.settle"):
+                            self.runner.finish()
+                            self._settle_locked()
                         return
                 finally:
                     for lk in reversed(locks):
@@ -1323,6 +1392,7 @@ class _RecoveryRound:
                 # the capacity plane can see recovery being starved
                 d.repair_policy._count("repair_enospc_parked")
             d.c.log(f"{d.name}: recovery round deferred: {e}")
+            d._backfills_over(self.backfills, failed=True)
             return
         sleep = float(d.config["osd_recovery_sleep"])
         if sleep > 0 and not d._stop.is_set():
@@ -1342,8 +1412,9 @@ class _RecoveryRound:
         d = self.d
         d.suspect -= self.dead
         now_m = time.monotonic()
-        for ps, _plan, _dead in self.entries:
-            if d._recovering.get(ps) is self:
+        for bf in self.backfills:
+            ps = bf.ps
+            if d._recovering.get(ps) is bf:
                 d._recovering.pop(ps, None)
             # r17 exposure accounting: the stripe left m-1 when its
             # rebuild landed — close its time-at-m-1 interval
@@ -1355,6 +1426,7 @@ class _RecoveryRound:
                         f"deferred: {e}")
         d.perf.inc("recovery_rounds")
         d._note_repair_gauges()
+        d._backfills_over(self.backfills)
 
 
 class _OpShard:
@@ -1588,7 +1660,15 @@ class OSDDaemon:
         # they mutate (always AFTER self._lock — one global order)
         self._pg_locks: dict[int, threading.RLock] = {}
         self._pg_locks_guard = threading.Lock()
-        self._recovering: dict[int, "_RecoveryRound"] = {}
+        # ps -> the PG's `_Backfill` from its plan to its settle (None
+        # for the moment between a plan and its registration)
+        self._recovering: dict[int, "_Backfill | None"] = {}
+        # backfill reservation: `_bf_lock` guards every `_Backfill`'s
+        # state and, as a target, the (primary, ps) -> (prio.., epoch)
+        # slots held and requests queued here
+        self._bf_lock = threading.Lock()
+        self._bf_held: dict[tuple, tuple] = {}
+        self._bf_queued: dict[tuple, tuple] = {}
         # r21: PGs whose rebuild is parked because a replacement
         # target sits at/over backfillfull (one counter tick per
         # park transition, not per reconcile beat)
@@ -1618,6 +1698,8 @@ class OSDDaemon:
         m.register_handler(MStoreOp.type_id, self._on_store_op)
         m.register_handler(MOSDOp.type_id, self._on_client_op)
         m.register_handler(MOSDPing.type_id, self._on_ping)
+        m.register_handler(MBackfillReserve.type_id,
+                           self._on_backfill_reserve)
         m.register_handler(MOSDPingReply.type_id, self._on_pong)
         # map folds run a full reconcile (meta gathers, shard moves —
         # BLOCKING remote rpc): queued dispatch, never on a reactor,
@@ -2813,23 +2895,32 @@ class OSDDaemon:
         """Map changed: adopt/recover the PGs this daemon primaries
         (the PeeringState Get* exchange outcome, driven from the
         authoritative persisted metadata). Recovery is PLANNED here but
-        EXECUTED by the mClock worker: every primaried PG's plan joins
-        ONE cross-PG round whose fused batches interleave with client
-        ops (the pre-r10 tree ran one blocking recover_shards per PG
-        inside this loop, holding the daemon lock for the whole
+        EXECUTED by the mClock worker, a PG at a time as its backfill
+        reservations are granted (`_backfill_planned`: the plan's
+        program is built in the background, then the PG takes one of
+        this primary's `osd_max_backfills` local slots and asks its
+        targets for theirs); the PGs that hold reservations together
+        share ONE cross-PG round whose fused batches interleave with
+        client ops (the pre-r10 tree ran one blocking recover_shards
+        per PG inside this loop, holding the daemon lock for the whole
         rebuild)."""
         new_plans: list[tuple[int, object, set[int]]] = []
         for ps in range(self.c.pg_num):
             # per-PG lock INSIDE the daemon lock (one global order):
             # client ops of this PG are excluded while its backend/
             # meta move; other PGs' ops keep flowing
+            planned = len(new_plans)
             with self._pg_lock(ps):
                 self._reconcile_pg(ps, new_plans)
+            # a PG's recover program starts to build the moment the PG
+            # is planned, not when the last PG of this map is
+            self._backfill_planned(new_plans[planned:])
         if new_plans:
             # r17 risk order: most exposed stripes first (fewest
             # surviving redundancy shards), r14 helper cost second,
-            # PG id last — the runner drains batches in plan order,
-            # so this IS the exposure schedule. 'pgid' keeps the
+            # PG id last — local slots are taken and the targets'
+            # queues drained in this order, so this IS the exposure
+            # schedule. 'pgid' keeps the
             # pre-r17 order selectable (the exposure A/B the bench
             # measures) but still counts the inversions it ships.
             from .repairpolicy import order_plans
@@ -2842,12 +2933,308 @@ class OSDDaemon:
                 self.repair_policy.note_exposure(
                     ps, self._plan_redundancy(ps, plan) <= 1,
                     now=now_m)
-            rnd = _RecoveryRound(self, new_plans)
-            for ps, _plan, _dead in new_plans:
-                self._recovering[ps] = rnd
-            self._sched_enqueue("background_recovery", rnd,
-                                rnd.next_cost(), shard=rnd.shard())
+        self._backfill_on_map()
         self._note_repair_gauges()
+
+    # -- backfill reservation (osd_max_backfills) ------------------------------
+    #
+    # ref: OSD::local_reserver / remote_reserver (AsyncReserver) and
+    # the MBackfillReserve / MRecoveryReserve exchange. The option's
+    # text: "the maximum number of backfills allowed to or from a
+    # single OSD". Local: this primary rebuilds at most
+    # `osd_max_backfills` of its PGs at once. Remote: an OSD that a
+    # plan moves bytes to (the new member of a lost slot) or from (a
+    # helper: the rebuild pulls k rows an object from k OSDs, where a
+    # replicated backfill's one source is its primary) serves at most
+    # that many PGs at once, whoever their primaries are — every shard
+    # of the PG but the primary's own, as upstream's recovery
+    # reservation asks every member of acting_recovery_backfill. Such
+    # an OSD queues the rest by the plan's priority, gives a slot back
+    # on the primary's RELEASE, and on a map in which the primary is
+    # down or the PG no longer names it. A primary asks in ascending
+    # OSD id, one at a time, so no two PGs can each hold what the
+    # other waits for. State moves under `_bf_lock`, a leaf lock: the handlers run
+    # on the messenger's reactor and must never wait for the daemon
+    # lock, which a map fold holds across blocking calls.
+
+    def _recovery_runner(self, plans: list):
+        """The RecoveryRunner of `plans` under this daemon's settings:
+        one place, so that the program built when a PG is planned is
+        the one its round launches."""
+        from .ecbackend import RecoveryRunner, _host_crc_available
+        cfg = self.config
+        max_active = int(cfg["osd_recovery_max_active"])
+        # r17: the integrity mode resolves through config (auto keeps
+        # the pre-r17 native-detect; 'device' forces the fused
+        # decode+fold on-device; 'host' asserts the native crc path
+        # when the lib is present — the storm bench verifies rebuilt
+        # bytes against the full-decode oracle in both modes)
+        integ = str(cfg["osd_recovery_integrity"]).lower()
+        host_crc = (False if integ == "device"
+                    else True if integ == "host"
+                    and _host_crc_available() else None)
+        return RecoveryRunner(
+            plans, batch=int(cfg["osd_recovery_batch"]),
+            perf=self.ec_perf, push_window_ops=max_active,
+            push_window_bytes=max_active
+            * int(cfg["osd_recovery_max_chunk"]),
+            host_crc=host_crc)
+
+    def _backfill_planned(self, new_plans: list) -> None:
+        """`_reconcile` planned these PGs (ordered): each becomes a
+        `_Backfill` whose recover program is built at once, in the
+        background, off the shard worker and outside every lock: a
+        grant runs under the daemon lock and the PG's, each PG loses
+        other slots than its neighbour and so has a program of its
+        own, and a program takes seconds to compile. A PG asks for its
+        reservation only when its program is ready. Caller holds the
+        daemon lock."""
+        from .repairpolicy import plan_helper_cost
+        by_pgid = str(self.config["osd_repair_queue_order"]) != "risk"
+        with self._bf_lock:
+            for ps, plan, dead in new_plans:
+                prio = (0, 0.0) if by_pgid else (
+                    self._plan_redundancy(ps, plan),
+                    plan_helper_cost(plan))
+                be = self.backends[ps]
+                targets = {int(be.acting[s])
+                           for s in (*plan.lost, *plan.helper)} \
+                    - {self.osd_id}
+                bf = _Backfill(ps, plan, dead, prio,
+                               int(self.osdmap.epoch), targets)
+                self._recovering[ps] = bf
+                self._bf_note_pending()
+                threading.Thread(
+                    target=self._backfill_build, args=(bf,), daemon=True,
+                    name=f"{self.name}-recover-build-{ps}").start()
+
+    def _backfill_replanned(self, ps: int) -> None:
+        """`_reconcile_pg` plans PG `ps` anew: the marker of a pending
+        round goes up in the same locked breath as the acting
+        mutation, and what the PG held for its old plan comes back
+        (a round still running it gives its own back at its end)."""
+        old = self._recovering.get(ps)
+        self._recovering[ps] = None
+        if isinstance(old, _Backfill) and old.state != "running":
+            self._backfills_over([old], failed=True, pump=False)
+
+    def _bf_note_pending(self) -> None:
+        """Caller holds `_bf_lock`."""
+        self.ec_perf.set("recover_programs_pending", sum(
+            1 for bf in list(self._recovering.values())
+            if isinstance(bf, _Backfill) and bf.state == "building"))
+
+    def _backfill_build(self, bf: "_Backfill") -> None:
+        """Build `bf`'s recover program for the shape its grants will
+        launch (a throw-away runner's `prepare()`: the compiled program
+        lands in the process-wide caches the round's runner reads),
+        then let the PG ask for its reservation."""
+        try:
+            self._recovery_runner([bf.plan]).prepare()
+        except Exception as e:   # noqa: BLE001 — a program that cannot
+            # be built here is built, or fails, at its launch
+            self.c.log(f"{self.name}: pg 1.{bf.ps} recover program "
+                       f"not built ahead: {e!r}")
+        with self._bf_lock:
+            if bf.state == "building":
+                bf.state = "ready"
+                self.ec_perf.inc("recover_programs_ready")
+            self._bf_note_pending()
+        self._backfill_pump()
+
+    def _backfill_pump(self) -> None:
+        """Hand free local slots to the ready PGs, best priority first,
+        and ask their targets; start a round for the PGs whose targets
+        have all granted. Called whenever a PG becomes ready, a grant
+        arrives or a slot comes back; takes `_bf_lock` only."""
+        if self._stop.is_set():
+            return
+        sends, start = [], []
+        with self._bf_lock:
+            mine = [bf for bf in list(self._recovering.values())
+                    if isinstance(bf, _Backfill)]
+            held = sum(bf.holds_slot() for bf in mine)
+            cap = int(self.config["osd_max_backfills"])
+            for bf in sorted((b for b in mine if b.state == "ready"),
+                             key=lambda b: (b.prio, b.ps)):
+                if held >= cap:
+                    break
+                held += 1
+                bf.state = "reserving"
+                bf.waiting = sorted(bf.targets)
+                bf.t_asked = time.perf_counter()
+                sends += [(t, bf) for t in bf.waiting[:1]]
+            for bf in mine:
+                if bf.state == "reserving" and not bf.waiting:
+                    bf.state = "reserved"
+                    waited = time.perf_counter() - bf.t_asked
+                    self.perf.tinc("backfill_reserve_wait_time", waited)
+                    record_wait("recovery.reserve.wait", bf.t_asked,
+                                waited)
+                if bf.state == "reserved":
+                    bf.state = "running"
+                    start.append(bf)
+        for target, bf in sends:
+            self._backfill_send(target, MBackfillReserve.REQUEST, bf)
+        if start:
+            try:
+                _RecoveryRound(self, start)._requeue()
+            except Exception as e:   # noqa: BLE001 — keep the reactor
+                self.c.log(f"{self.name}: recovery round of pgs "
+                           f"{[bf.ps for bf in start]} not started: "
+                           f"{e!r}")
+                self._backfills_over(start, failed=True)
+
+    def _backfill_send(self, target: int, op: int,
+                       bf: "_Backfill") -> None:
+        try:
+            self.msgr.send(f"osd.{target}", MBackfillReserve(
+                op, bf.ps, self.osd_id, bf.epoch, bf.prio))
+        except (KeyError, OSError, ConnectionError) as e:
+            # a REQUEST is sent again by the next reconcile; a target
+            # that misses a RELEASE frees the slot on its next map
+            self.c.log(f"{self.name}: backfill reserve op {op} of pg "
+                       f"1.{bf.ps} to osd.{target} not sent: {e}")
+
+    def _backfills_over(self, backfills, failed: bool = False,
+                        pump: bool = True) -> None:
+        """These PGs are settled, failed or re-planned: give their
+        slots back, the targets' by a RELEASE, and let the next PG in.
+        A failed one stays in `_recovering` with `failed` set: the next
+        reconcile plans it anew."""
+        sends = []
+        with self._bf_lock:
+            for bf in backfills:
+                if bf.holds_slot():
+                    # not the slots a newer plan of the PG now holds or
+                    # waits for under the same (primary, pg) key
+                    cur = self._recovering.get(bf.ps)
+                    kept = cur.targets if cur is not bf \
+                        and isinstance(cur, _Backfill) \
+                        and cur.state != "done" else set()
+                    asked = bf.targets - set(bf.waiting[1:])
+                    sends += [(t, bf) for t in sorted(asked - kept)]
+                if bf.state != "done":
+                    bf.state, bf.failed = "done", failed
+            self._bf_note_pending()
+        for target, bf in sends:
+            self._backfill_send(target, MBackfillReserve.RELEASE, bf)
+        if pump:
+            self._backfill_pump()
+
+    def _backfill_on_map(self) -> None:
+        """Every reconcile (a map fold, every fourth heartbeat): as a
+        target, drop the slots and the queued requests of primaries
+        the map shows down and of PGs that no longer name this OSD;
+        as a primary, ask again where a grant is overdue (a frame lost
+        with its connection) and let waiting PGs in."""
+        osdmap = self.osdmap
+        if osdmap is None:
+            return
+        grants = []
+        with self._bf_lock:
+            for key in [*self._bf_held, *self._bf_queued]:
+                primary, ps = key
+                epoch = (self._bf_held.get(key)
+                         or self._bf_queued.get(key))[2]
+                gone = not osdmap.osd_up[primary] or (
+                    osdmap.epoch >= epoch
+                    and self.osd_id not in self._acting(ps))
+                if gone:
+                    self._bf_held.pop(key, None)
+                    self._bf_queued.pop(key, None)
+            grants = self._bf_grant_next()
+            now = time.perf_counter()
+            again = [(t, bf) for bf in list(self._recovering.values())
+                     if isinstance(bf, _Backfill)
+                     and bf.state == "reserving"
+                     and now - bf.t_asked > 2.0
+                     for t in bf.waiting[:1]]
+        self._bf_send_grants(grants)
+        for target, bf in again:
+            self._backfill_send(target, MBackfillReserve.REQUEST, bf)
+        self._backfill_pump()
+
+    def _bf_grant_next(self) -> list:
+        """Fill this target's free slots from its queue, best priority
+        first (PG id last). Caller holds `_bf_lock`. Returns the
+        (primary, ps) pairs to send a GRANT for."""
+        out = []
+        cap = int(self.config["osd_max_backfills"])
+        while self._bf_queued and len(self._bf_held) < cap:
+            key = min(self._bf_queued,
+                      key=lambda k: (self._bf_queued[k][:2], k[1], k[0]))
+            self._bf_held[key] = self._bf_queued.pop(key)
+            out.append(key)
+        if out:
+            self.perf.inc("backfill_reservations_granted", len(out))
+            if len(self._bf_held) > self.perf.get("backfills_active_max"):
+                self.perf.set("backfills_active_max",
+                              len(self._bf_held))
+        return out
+
+    def _bf_send_grants(self, keys: list) -> None:
+        for primary, ps in keys:
+            try:
+                self.msgr.send(f"osd.{primary}", MBackfillReserve(
+                    MBackfillReserve.GRANT, ps, primary))
+            except (KeyError, OSError, ConnectionError) as e:
+                # the primary asks again on its next reconcile
+                self.c.log(f"{self.name}: backfill grant of pg 1.{ps} "
+                           f"to osd.{primary} not sent: {e}")
+
+    def _on_backfill_reserve(self, peer: str,
+                             msg: MBackfillReserve) -> None:
+        """Fast dispatch (the reactor): bookkeeping under `_bf_lock`
+        and at most a frame out."""
+        if msg.op == MBackfillReserve.GRANT:
+            # only a grant this primary is waiting for, from the OSD it
+            # asked, counts; any other is handed back
+            target = int(peer.split(".")[-1]) if peer.startswith("osd.") \
+                else -1
+            nxt = None
+            with self._bf_lock:
+                bf = self._recovering.get(msg.ps)
+                mine = (isinstance(bf, _Backfill)
+                        and target in bf.targets and bf.holds_slot())
+                if mine and bf.waiting[:1] == [target]:
+                    bf.waiting.pop(0)
+                    nxt = bf.waiting[0] if bf.waiting else None
+            if not mine:
+                if target >= 0:
+                    try:
+                        self.msgr.send(peer, MBackfillReserve(
+                            MBackfillReserve.RELEASE, msg.ps,
+                            self.osd_id))
+                    except (KeyError, OSError, ConnectionError):
+                        pass
+            elif nxt is not None:
+                self._backfill_send(nxt, MBackfillReserve.REQUEST, bf)
+            else:
+                self._backfill_pump()
+            return
+        if self.verifier is not None \
+                and self._auth_gate(peer, "w") is not None:
+            self.c.log(f"{self.name}: backfill reserve op {msg.op} "
+                       f"from {peer} refused (not authorized)")
+            return
+        key = (int(msg.primary), int(msg.ps))
+        grants = []
+        with self._bf_lock:
+            if msg.op == MBackfillReserve.RELEASE:
+                self._bf_held.pop(key, None)
+                self._bf_queued.pop(key, None)
+                grants = self._bf_grant_next()
+            elif key in self._bf_held:
+                grants = [key]          # asked again: say so again
+            else:
+                if key not in self._bf_queued:
+                    if len(self._bf_held) >= int(
+                            self.config["osd_max_backfills"]):
+                        self.perf.inc("backfill_reservation_waits")
+                self._bf_queued[key] = (*msg.prio, int(msg.epoch))
+                grants = self._bf_grant_next()
+        self._bf_send_grants(grants)
 
     def _plan_redundancy(self, ps: int, plan) -> int:
         """Surviving redundancy of one planned rebuild: failures the
@@ -2966,7 +3353,7 @@ class OSDDaemon:
                     plan = be.plan_recovery(
                         rnd.lost_of(ps), helper_exclude=exclude,
                         helper_costs=self._helper_costs(be))
-                    self._recovering[ps] = None   # round pending
+                    self._backfill_replanned(ps)
                     new_plans.append((ps, plan, set()))
                 except (ValueError, ConnectionError, KeyError) as e:
                     self.c.log(f"{self.name}: pg 1.{ps} recovery "
@@ -3071,19 +3458,20 @@ class OSDDaemon:
                     # lost slots so new client writes reach the
                     # rebuilding store directly); the mClock
                     # worker executes the batches. The recovering
-                    # marker goes up IN THE SAME locked breath as
-                    # the acting mutation — wait_for_clean polls
-                    # unlocked and must never see a repointed
-                    # acting without the in-flight marker.
+                    # marker goes up BEFORE the acting mutation —
+                    # wait_for_clean polls unlocked and must never
+                    # see a repointed acting without the in-flight
+                    # marker, and the plan calls the new member
+                    # after it re-points (seconds on a busy host).
                     # Replicated pools have no fused decode plan:
                     # their push-based recover_shards runs inline
                     # (the pre-r10 path; copies, not decodes).
                     if hasattr(be, "plan_recovery"):
+                        self._backfill_replanned(ps)
                         plan = be.plan_recovery(
                             lost, replacement_osds=repl,
                             helper_exclude=exclude,
                             helper_costs=self._helper_costs(be))
-                        self._recovering[ps] = None  # round pending
                         new_plans.append((ps, plan, dead))
                     else:
                         be.recover_shards(lost,
@@ -3093,6 +3481,10 @@ class OSDDaemon:
                         self.perf.inc("recovery_rounds")
                 self._persist_meta(ps)
             except (ValueError, ConnectionError, KeyError) as e:
+                if self._recovering.get(ps, self) is None:
+                    # the plan failed and put acting back: the marker
+                    # goes with it, and the next reconcile plans again
+                    del self._recovering[ps]
                 self.c.log(f"{self.name}: pg 1.{ps} recovery "
                            f"deferred: {e}")
 
@@ -3133,7 +3525,7 @@ class OSDDaemon:
                     slots,
                     names=sorted(names) if names is not None else None,
                     helper_costs=self._helper_costs(be))
-                self._recovering[ps] = None      # round pending
+                self._backfill_replanned(ps)
                 new_plans.append((ps, plan, set()))
             else:
                 be.recover_shards(
@@ -3164,26 +3556,58 @@ class OSDDaemon:
                     new_osd: int) -> None:
         """Backfill-by-copy for a re-slotted LIVE member: pull the
         shard's bytes from the old holder, push to the new one — all
-        as store-op frames (the backfill push role)."""
+        as store-op frames (the backfill push role), a bounded number
+        of bytes a frame (`RECOVERY_FETCH_BYTES`, as recovery's pulls):
+        one transaction for a whole PG's shard outgrows the
+        messenger's write budget and holds the destination's reactor
+        for as long as its store takes to apply it. Rows come a frame
+        of equal-length rows at a time (`_shard_rows`), the next frame
+        asked for before this one is pushed. A push is
+        write + truncate + setattr, so a move cut short is repeated
+        whole at the next reconcile."""
+        from .ecbackend import RECOVERY_FETCH_BYTES
         from .pgbackend import HINFO_KEY
         cid = shard_cid(be.pg, slot)
         src = be.cluster.osd(old_osd)
         dst = be.cluster.osd(new_osd)
+        by_len: dict[int, list[str]] = {}
+        for name in be.list_pg_objects():
+            by_len.setdefault(
+                be._expected_shard_len(be.object_sizes[name]),
+                []).append(name)
+        frames = []
+        for sl, names in sorted(by_len.items()):
+            per = max(1, RECOVERY_FETCH_BYTES // max(1, sl))
+            frames += [(sl, names[i:i + per])
+                       for i in range(0, len(names), per)]
+        submit = getattr(src, "readv_submit", None)
+
+        def ask(i: int):
+            if submit is None or i >= len(frames):
+                return None
+            sl, names = frames[i]
+            return submit(cid, names, sl, HINFO_KEY)
         t = Transaction().create_collection(cid)
         moved_objs = moved_bytes = 0
-        for name in be.list_pg_objects():
-            if not src.exists(cid, name):
-                continue
-            data = np.asarray(src.read(cid, name), np.uint8)
-            t.write(cid, name, 0, data).truncate(cid, name, len(data))
-            moved_objs += 1
-            moved_bytes += len(data)
-            try:
-                t.setattr(cid, name, HINFO_KEY,
-                          src.getattr(cid, name, HINFO_KEY))
-            except KeyError:
-                pass
-        dst.queue_transaction(t)
+        asked = ask(0)
+        try:
+            for i, (sl, names) in enumerate(frames):
+                rows = self._shard_rows(src, cid, names, sl, asked)
+                asked = ask(i + 1)
+                for name, data, hinfo in rows:
+                    t.write(cid, name, 0, data).truncate(cid, name,
+                                                         len(data))
+                    if hinfo is not None:
+                        t.setattr(cid, name, HINFO_KEY, hinfo)
+                    moved_objs += 1
+                    moved_bytes += len(data)
+                dst.queue_transaction(t)
+                t = Transaction()
+        finally:
+            if asked is not None:       # a push failed: nobody collects
+                asked.cancel()
+        if not frames:
+            dst.queue_transaction(t)
         # repair-traffic accounting (r17): backfill copies are repair
         # bytes too — the storm bench sums them with recovered_bytes
         self.perf.inc_many((("move_objects", moved_objs),
@@ -3191,6 +3615,38 @@ class OSDDaemon:
         be.acting[slot] = new_osd
         self.c.log(f"{self.name}: pg {be.pg} slot {slot} moved "
                    f"osd.{old_osd} -> osd.{new_osd}")
+
+    @staticmethod
+    def _shard_rows(src, cid: str, names: list[str], length: int,
+                    asked) -> list[tuple]:
+        """(name, row, hinfo attr or None) of each of `names` that the
+        store holds. `asked` is the `readv` frame already sent for them
+        (rows and attrs in one answer, where three calls an object took
+        a shard of a hundred objects tens of seconds on a busy pool);
+        where the store is local, or the frame fails because a row is
+        missing, of another length or without its attr, the rows are
+        read one by one and what is there is what moves."""
+        from .pgbackend import HINFO_KEY
+        if asked is not None:
+            try:
+                data, attrs = asked.result()
+                flat = np.frombuffer(data, np.uint8)
+                if flat.size == len(names) * length:
+                    return [(name, flat[i * length:(i + 1) * length],
+                             attrs[i]) for i, name in enumerate(names)]
+            except (KeyError, ConnectionError):
+                pass
+        rows = []
+        for name in names:
+            if not src.exists(cid, name):
+                continue
+            data = np.asarray(src.read(cid, name), np.uint8)
+            try:
+                hinfo = src.getattr(cid, name, HINFO_KEY)
+            except KeyError:
+                hinfo = None
+            rows.append((name, data, hinfo))
+        return rows
 
     # -- client ops ----------------------------------------------------------
 
@@ -3221,6 +3677,23 @@ class OSDDaemon:
          .add_u64_counter("subop_out_bytes", "store sub-op bytes out")
          .add_u64_counter("recovery_rounds",
                           "reconcile-driven recovery passes")
+         .add_u64_counter("recovery_grants",
+                          "grants of recovery rounds executed by the "
+                          "op-shard workers (one fused batch each)")
+         .add_u64_counter("backfill_reservations_granted",
+                          "backfill slots this OSD granted as a "
+                          "target (the remote reservation)")
+         .add_u64_counter("backfill_reservation_waits",
+                          "requests this OSD queued as a target "
+                          "because its osd_max_backfills slots were "
+                          "taken")
+         .add_u64("backfills_active_max",
+                  "most PGs that held this target's backfill slots "
+                  "at once (high-water mark)")
+         .add_time_avg("backfill_reserve_wait_time",
+                       "a PG's wait for its targets' backfill slots, "
+                       "request sent to last grant taken (this OSD as "
+                       "its primary)")
          .add_u64_counter("cephx_refresh_kicked",
                           "background ticket refreshes started")
          .add_u64_counter("cephx_refresh_coalesced",
@@ -7524,8 +7997,8 @@ class StandaloneCluster:
         import os as _os
         if verbose is None:
             verbose = bool(_os.environ.get("STANDALONE_VERBOSE"))
-        from ..crush.map import Tunables, build_hierarchy, ec_rule, \
-            replicated_rule
+        from ..crush.map import EC_RULE_CHOOSE_TRIES, Tunables, \
+            build_hierarchy, ec_rule, replicated_rule
         from ..ec.interface import profile_from_string
         from ..ec.registry import factory
         self.secret = secret
@@ -7586,6 +8059,11 @@ class StandaloneCluster:
             self.pool_size = coder.get_chunk_count()
             self.pool_min_size = coder.get_data_chunk_count()
             ec_rule(crush, 1, choose_type=1)
+            # as upstream's EC rule: on k+m+1 OSDs with one out, 51
+            # rounds leave a PG with a hole that 100 fill; the up sets
+            # of a whole cluster are the same at both
+            crush.tunables = Tunables(
+                choose_total_tries=EC_RULE_CHOOSE_TRIES)
         else:
             prof = profile_from_string(" ".join(toks[1:]))
             self.pool_size = int(prof.get("size", 3))

@@ -53,12 +53,24 @@ class Option:
 OPTIONS: list[Option] = [
     Option("osd_pool_default_size", int, 3, "replicas for new pools", min=1),
     Option("osd_pool_default_pg_num", int, 32, "PGs for new pools", min=1),
+    Option("osd_max_backfills", int, 1,
+           "the maximum number of backfills allowed to or from a "
+           "single OSD: a primary rebuilds at most this many of its "
+           "PGs at once (the local reservation), and an OSD that a "
+           "PG's rebuild pushes to (the new member) or pulls helper "
+           "rows from serves at most this many PGs at once (the "
+           "remote reservation, asked of each in turn before the "
+           "PG's first grant)",
+           min=1),
     Option("osd_recovery_max_active", int, 3,
-           "concurrent recovery pulls/pushes in flight per OSD (the "
-           "local+remote reservation: bounds outstanding fetch frames "
-           "and sizes the push window)", min=1),
+           "recovery pushes in flight per recovering primary: bounds "
+           "the push window's outstanding frames and, times "
+           "osd_recovery_max_chunk, the helper bytes one grant of a "
+           "recovery round stages (which PGs recover at once is "
+           "osd_max_backfills')", min=1),
     Option("osd_recovery_batch", int, 128,
-           "objects per batched recovery launch", min=1),
+           "ceiling on the objects of one batched recovery launch "
+           "(the byte budget of a grant usually binds first)", min=1),
     Option("osd_recovery_sleep", float, 0.0,
            "seconds a recovering OSD waits between recovery batch "
            "grants (throttles background_recovery under client load; "
@@ -66,7 +78,8 @@ OPTIONS: list[Option] = [
     Option("osd_recovery_max_chunk", int, 8 << 20,
            "byte budget of one recovery push op (with "
            "osd_recovery_max_active it bounds the windowed-push "
-           "in-flight bytes: active * chunk)", min=4096),
+           "in-flight bytes and the helper bytes one grant stages: "
+           "active * chunk)", min=4096),
     Option("osd_op_num_shards", int, 1,
            "op-queue shards per OSD daemon (the reference's sharded "
            "op work queue): ops hash by PG id to a shard, each shard "

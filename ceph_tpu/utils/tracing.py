@@ -24,7 +24,7 @@ jax.monitoring listener: they are rare, and one in an untraced stretch
 must still count.
 
 Usage:
-    with span("ecbackend.recover.batch"):
+    with span("recovery.launch"):
         ...
     with span("osd.op", counters=perf, key="op_latency"):
         ...

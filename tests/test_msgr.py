@@ -457,3 +457,115 @@ class TestCompression:
         finally:
             a.shutdown()
             b.shutdown()
+
+
+class TestWriteBudget:
+    """The write queue's byte budget is waited out before the per-peer
+    order lock, never inside it: the reactor takes that lock to answer
+    the same peer, and the reactor is who drains the queue (PR 33: a
+    30 MiB frame parked a heartbeat sender inside the lock, the reactor
+    behind it on a pong, and both for good)."""
+
+    def test_send_waits_out_the_budget_before_the_peer_lock(self):
+        a, b = pair()
+        try:
+            got = []
+            b.register_handler(Ping.type_id,
+                               lambda p, m: got.append(m.stamp))
+            a.send("osd.1", Ping(1))
+            assert wait_for(lambda: got == [1])
+            conn = a._conns["osd.1"]
+            held, real = [], conn.await_budget
+
+            def watched():
+                held.append(a._plock("osd.1")._is_owned())
+                real()
+            conn.await_budget = watched
+            a.send("osd.1", Ping(2))
+            assert wait_for(lambda: got == [1, 2])
+            assert held == [False]
+        finally:
+            a.shutdown()
+            b.shutdown()
+
+    def test_a_sender_parked_on_the_budget_does_not_park_the_reactor(self):
+        from ceph_tpu.msgr.messenger import _TLS, _WQ_HIGH
+        a, b = pair()
+        try:
+            got = []
+            b.register_handler(Ping.type_id,
+                               lambda p, m: got.append(m.stamp))
+            a.send("osd.1", Ping(1))
+            assert wait_for(lambda: got == [1])
+            conn = a._conns["osd.1"]
+            stalls = a.perf.get("writeq_stalls")
+            with conn.wlock:                 # a queue over its budget
+                conn._wq_bytes += _WQ_HIGH + 1
+            parked = threading.Thread(
+                target=lambda: a.send("osd.1", Ping(2)), daemon=True)
+            parked.start()
+            time.sleep(0.3)
+            assert parked.is_alive() and got == [1]
+
+            def as_reactor():
+                _TLS.in_reactor = True       # a pong from the reactor
+                a.send("osd.1", Ping(3))
+            reactor = threading.Thread(target=as_reactor, daemon=True)
+            reactor.start()
+            reactor.join(5)
+            assert not reactor.is_alive()
+            assert wait_for(lambda: got == [1, 3])
+            with conn.wlock:                 # the queue drains
+                conn._wq_bytes -= _WQ_HIGH + 1
+                conn._wcond.notify_all()
+            parked.join(5)
+            assert not parked.is_alive()
+            assert wait_for(lambda: got == [1, 3, 2])
+            assert a.perf.get("writeq_stalls") == stalls + 1
+        finally:
+            a.shutdown()
+            b.shutdown()
+
+    def test_a_replay_over_the_budget_waits_for_nothing(self):
+        """A reconnect with more than the budget unacked (recovery's
+        8 MiB pushes) replays inside the per-peer lock, and must not
+        park there: it resends what the unacked queue holds already."""
+        from ceph_tpu.msgr.messenger import _Conn, _WQ_HIGH
+        a, b = pair()
+        real = _Conn.await_budget
+        try:
+            got = []
+            b.register_handler(Ping.type_id,
+                               lambda p, m: got.append(m.stamp))
+            a.send("osd.1", Ping(1))
+            assert wait_for(lambda: got == [1])
+            for conn in list(a._conns.values()):
+                conn.close()
+            connect = a._connect
+            a._connect = lambda peer: (_ for _ in ()).throw(
+                ConnectionError("unreachable"))
+            time.sleep(0.05)
+            note = "x" * (8 << 20)
+            for i in (2, 3, 4, 5):          # strand 32 MiB unacked
+                a.send("osd.1", Ping(i, note))
+            assert sum(len(seg) for _, _, p in a._unacked["osd.1"]
+                       for seg in (p if isinstance(p, (list, tuple))
+                                   else [p])) > _WQ_HIGH
+            stalls = a.perf.get("writeq_stalls")
+            held = []
+
+            def watched(conn):
+                held.append(a._plock("osd.1")._is_owned())
+                real(conn)
+            _Conn.await_budget = watched
+            a._connect = connect
+            a.send("osd.1", Ping(6))        # redials, replays 2..6
+            assert a.flush("osd.1", timeout=30)
+            assert wait_for(lambda: got == [1, 2, 3, 4, 5, 6]), got
+            assert a.perf.get("replayed") >= 4
+            assert not any(held)
+            assert a.perf.get("writeq_stalls") == stalls
+        finally:
+            _Conn.await_budget = real
+            a.shutdown()
+            b.shutdown()

@@ -139,6 +139,34 @@ class TestCrossPgFused:
         # one NEW program key at most (both backends resolve to it)
         assert len(_RECOVER_PROGRAMS) <= before + 1
 
+    def test_a_plan_that_fails_after_repointing_puts_the_slots_back(self):
+        """`plan_recovery` re-points the lost slots, then calls the
+        replacement OSD (the shard's collection). Where that call fails,
+        a timeout on a busy host, acting is as it was: a caller that
+        looks again still finds the slot lost and plans again, instead
+        of a PG that reads clean with a shard never rebuilt."""
+        cluster = ShardSet()
+        be = ECBackend("k=4 m=2", "1.0", list(range(6)), cluster,
+                       chunk_size=512)
+        objs = _write_corpus(be, "rp", n=3, sizes=(4096,))
+        cluster.stores.pop(1)
+        was = list(be.acting)
+
+        class Unreachable:
+            def queue_transaction(self, t):
+                raise ConnectionError("rpc to osd.60 timed out")
+        cluster.stores[60] = Unreachable()
+        with pytest.raises(ConnectionError):
+            be.plan_recovery([1], replacement_osds={1: 60})
+        assert be.acting == was
+        cluster.stores.pop(60)
+        plan = be.plan_recovery([1], replacement_osds={1: 60})
+        RecoveryRunner([plan], batch=64).run()
+        assert be.acting[1] == 60 and not plan.remaining
+        got = be.read_objects(sorted(objs), dead_osds={2})
+        for name, data in objs.items():
+            np.testing.assert_array_equal(got[name], data, err_msg=name)
+
     def test_partial_round_marks_nothing(self):
         """A runner that dies mid-way must leave plan.remaining
         non-empty and the applied cursor un-advanced (the staleness

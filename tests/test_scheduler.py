@@ -182,6 +182,45 @@ class TestMClock:
             now += 0.001
         assert served <= 60, served
 
+    @pytest.mark.parametrize("lag_s", [0.5, 5.0, 50.0])
+    def test_a_class_back_from_idle_joins_the_busy_classes_virtual_time(
+            self, lag_s):
+        """An overloaded shard: a recovery round that re-enqueues itself
+        is served at 4 grants/s against a reservation of 12.5, so its R
+        tags run `lag_s` behind the clock. A client whose queue ran
+        empty comes back: its ops are served in the reservations' ratio
+        (50 : 25/2, four ops a grant) from the first one on, however
+        long the round has run: it does not wait out the round's
+        credit."""
+        s = MClockScheduler({
+            "client": ClientProfile(reservation=50.0, weight=10.0),
+            "background_recovery": ClientProfile(reservation=25.0,
+                                                 weight=5.0, limit=100.0)})
+        now = 100.0
+        s.enqueue("background_recovery", "grant", cost=2.0)
+        while now < 100.0 + lag_s / (1 - 0.08 / 0.25):
+            assert s.dequeue(now) == ("background_recovery", "grant")
+            now += 0.25                      # a grant's service time
+            s.enqueue("background_recovery", "grant", cost=2.0)
+        q = s._classes["background_recovery"]
+        assert now - q.r_prev >= lag_s * 0.9
+        for i in range(12):
+            s.enqueue("client", i)
+        order = []
+        while len([x for x in order if x != "grant"]) < 12:
+            cls, item = s.dequeue(now)
+            order.append(item)
+            now += 0.25 if item == "grant" else 0.1
+            if item == "grant":
+                s.enqueue("background_recovery", "grant", cost=2.0)
+        # the first client op waits for no grant, and no client op
+        # waits behind more than one
+        assert order[0] == 0
+        assert "grant" in order
+        assert all(not (a == "grant" and b == "grant")
+                   for a, b in zip(order, order[1:]))
+        assert order.count("grant") <= 4
+
     def test_fifo_within_class(self):
         s = MClockScheduler({"c": ClientProfile(weight=1.0)})
         for i in range(5):

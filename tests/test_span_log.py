@@ -21,6 +21,10 @@ A partial overwrite (PR 31) leaves `ecbackend.rmw`, its six children and
 its one apply round), one case each, and nothing with no session;
 `rmw_host_delta_launches` tells the host's delta launches from the
 device's; the log counts the records it drops.
+A backfill (PR 34: the degraded pool's victim marked out under a live
+session) leaves `recovery.reserve.wait` and `recovery.grant` with its
+children `.pull`, `.stage`, `.launch`, `.fetch`, `.push` and `.settle`,
+one case each, so that every `recovery.*` reader keeps a number.
 """
 
 import os
@@ -561,6 +565,87 @@ class TestDegradedReadSpansAndCounters:
 
 
 # -- a read's gather: one overlapped round (PR 28) ------------------------------
+
+# -- a backfill -----------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def backfill_log(tmp_path_factory):
+    """The span records of one backfill under a live session: a pool of
+    its own, degraded as `degraded` is, its victim then marked out and
+    the pool run to clean."""
+    from ceph_tpu.osd.standalone import StandaloneCluster
+    c = StandaloneCluster(n_osds=4, pg_num=2, hb_interval=0.5,
+                          hb_grace=30.0, down_out_interval=600.0)
+    try:
+        c.wait_for_clean(timeout=40)
+        cl = c.client(hedge_delay_ms=-1)
+        cl.trace_sample_rate = 0.0
+        acting = [cl.osdmap.pg_to_up_acting_osds(1, ps)[2] for ps in (0, 1)]
+        primaries = {a[0] for a in acting}
+        victim = next(a[1] for a in acting if a[1] not in primaries)
+        objs = {f"bf-{i}": bytes([i]) * 3000 for i in range(6)}
+        cl.write(objs)
+        c.kill_osd(victim)
+        cl.osd_down(victim)
+        c._wait(lambda: all(not d.osdmap.osd_up[victim]
+                            for d in c.osds.values()
+                            if not d._stop.is_set()), 15, "maps show down")
+        assert start_trace(str(tmp_path_factory.mktemp("backfill-trace")))
+        try:
+            t0 = time.perf_counter()
+            cl.osd_out(victim)
+            c._wait(lambda: all(d.osdmap.osd_weight[victim] == 0
+                                for d in c.osds.values()
+                                if not d._stop.is_set()), 15,
+                    "maps show out")
+            c.wait_for_clean(timeout=60)
+        finally:
+            table = stop_trace()
+        for name, want in objs.items():
+            assert cl.read(name) == want
+        yield _mine(t0), table
+    finally:
+        c.shutdown()
+
+
+GRANT_KIDS = ("pull", "stage", "launch", "fetch", "push", "settle")
+
+
+class TestBackfillSpans:
+    @pytest.mark.parametrize("kid", GRANT_KIDS)
+    def test_a_grant_holds_its_child(self, backfill_log, kid):
+        got, table = backfill_log
+        name = f"recovery.{kid}"
+        assert is_span_declared(name) and is_span_declared("recovery.grant")
+        assert name in table["stages"]
+        grants = got["recovery.grant"]
+        for rec in got[name]:
+            assert 0 <= rec["self"] <= rec["dur"] + 1e-9
+            assert any(g["start"] <= rec["start"] and rec["start"]
+                       + rec["dur"] <= g["start"] + g["dur"] + 1e-6
+                       for g in grants), name
+
+    def test_a_grant_s_self_time_is_what_its_children_leave(self,
+                                                            backfill_log):
+        got, _ = backfill_log
+        grants = got["recovery.grant"]
+        kids = sum(r["dur"] for kid in GRANT_KIDS
+                   for r in got[f"recovery.{kid}"])
+        assert sum(g["self"] for g in grants) == pytest.approx(
+            sum(g["dur"] for g in grants) - kids, abs=1e-3)
+        # a launching grant says what it is to stage; the launch what it
+        # staged: one 1536-byte row of k=2 an object
+        staged = sum(r["nbytes"] for r in got["recovery.launch"])
+        assert staged > 0 and staged % (2 * 1536) == 0
+        assert sum(g["nbytes"] for g in grants) >= staged
+
+    def test_the_reservation_s_wait_is_logged_once_a_pg(self, backfill_log):
+        got, _ = backfill_log
+        waits = got["recovery.reserve.wait"]
+        assert is_span_declared("recovery.reserve.wait")
+        assert 1 <= len(waits) <= 2            # the pool has two PGs
+        assert all(w["dur"] >= 0 and w["self"] == w["dur"] for w in waits)
+
 
 @pytest.fixture
 def served_kinds(monkeypatch):

@@ -124,15 +124,17 @@ def test_fused_write_compiles_at_bucket_16(one_chip, coder):
     assert out.memory_analysis().temp_size_in_bytes < 1174 * MiB
 
 
-def test_recover_program_compiles_at_the_staged_batch(one_chip, coder):
+@pytest.mark.parametrize("lost", [(0, 9), (4,)])
+def test_recover_program_compiles_at_the_staged_batch(one_chip, coder, lost):
     """The batch RecoveryRunner really stages at 4 MiB objects: its byte
     bound, not osd_recovery_batch (128 objects asked for 6 GiB of
-    scratch per launch, two launches in flight per daemon)."""
+    scratch per launch, two launches in flight per daemon). Two lost
+    rows, and the one data row a PG of configuration
+    rados_k8m3_12osd_1out loses with its OSD."""
     from ceph_tpu.osd.ecbackend import (RECOVERY_STAGE_BYTES,
                                         _build_recover_program)
     batch = RECOVERY_STAGE_BYTES // (K * SHARD)
     assert batch == 32
-    lost = (0, 9)
     helper = tuple(i for i in range(K + M) if i not in lost)[:K]
     fn = _build_recover_program(coder.batch_decoder(lost, helper),
                                 verify=True, host_crc=False)
@@ -141,6 +143,30 @@ def test_recover_program_compiles_at_the_staged_batch(one_chip, coder):
     assert _no_gather(out)
     # 1,687 MiB with the gathers (PR 26 read 1,010)
     assert out.memory_analysis().temp_size_in_bytes < 1687 * MiB
+
+
+@pytest.mark.parametrize("lost", [(1,), (3,), (4,), (6,), (7,)])
+def test_recover_program_compiles_at_the_wire_tiers_grant(one_chip, coder,
+                                                          lost):
+    """What one grant of a recovery round launches under the default
+    settings: osd_recovery_max_active x osd_recovery_max_chunk = 24 MiB
+    of helper rows is 4 objects of 4 MiB (the power of two under 6); the
+    five lost slots of configuration rados_k8m3_12osd_1out's PGs."""
+    from ceph_tpu.osd.ecbackend import _build_recover_program
+    from ceph_tpu.utils.config import OPTIONS
+    defaults = {o.name: o.default for o in OPTIONS}
+    fit = (defaults["osd_recovery_max_active"]
+           * defaults["osd_recovery_max_chunk"]) // (K * SHARD)
+    batch = 1 << (fit.bit_length() - 1)
+    assert batch == 4
+    helper = tuple(i for i in range(K + M) if i not in lost)[:K]
+    fn = _build_recover_program(coder.batch_decoder(lost, helper),
+                                verify=True, host_crc=False)
+    out = _compile(fn, one_chip, ((batch, K, SHARD), np.uint8),
+                   ((batch,), np.uint32))
+    assert _no_gather(out)
+    # an eighth of the 32-object launch's scratch
+    assert out.memory_analysis().temp_size_in_bytes < 256 * MiB
 
 
 @pytest.mark.parametrize("column", [0, 7])

@@ -41,8 +41,8 @@ def main(argv=None) -> None:
     ap.add_argument("--trace", metavar="DIR", default=None,
                     help="capture a jax.profiler trace of the recovery "
                          "phase into DIR (view with tensorboard/xprof; "
-                         "the ecbackend.recover.{stage,launch,fetch,"
-                         "writeback} spans mark the pipeline stages)")
+                         "the recovery.{pull,stage,launch,fetch,"
+                         "push} spans mark the pipeline stages)")
     ap.add_argument("--history-interval", type=float, default=0.25,
                     help="seconds per telemetry interval for the "
                          "run's local MetricsHistory ring (the JSON "
@@ -176,7 +176,7 @@ def main(argv=None) -> None:
         return plan, runner, sched
 
     # r15: the timed recovery runs under a SAMPLED flight-recorder
-    # context, so the ecbackend.recover.* spans assemble into one
+    # context, so the recovery.* spans assemble into one
     # causal timeline with critical-path attribution — the same
     # instrumentation points feed the jax.profiler trace, the perf
     # counters, and this block (schema pinned by test_bench_schema)
