@@ -17,9 +17,14 @@ rows and return host rows: they run the HOST FACE
 uint32 words both ways and the result's copy to the host is a plain
 copy (as uint8 `(B, m, L)` the parity left the device tiled with the
 batch in m's place, and de-tiling it on the host was 84 of a 108 ms
-call at 32 x 4 MiB: PERF.md, PR 30). `batch_decoder` hands out the
-DEVICE-RESIDENT program (`make_encoder`) that the served path composes
-with its crc programs; its shape, dtype and HLO are what they were.
+call at 32 x 4 MiB: PERF.md, PR 30). A large call (two or more
+sub-batches of 8 MiB of rows: `rs_kernels._sub_batch_rows`) crosses the
+link as a pipeline of sub-batches of the batch axis, so the device
+works and the next rows are laid out while the rows before them are
+still being copied in; one object is one launch (PERF.md, PR 35).
+`batch_decoder` hands out the DEVICE-RESIDENT program (`make_encoder`)
+that the served path composes with its crc programs; its shape, dtype
+and HLO are what they were.
 """
 
 from __future__ import annotations

@@ -49,6 +49,10 @@ Two faces of one matrix, and both lowerings serve both:
       cast to words on the device cost more than they save (the cast
       wants a minor dimension of 4: 73.5 GB of padding, or a uint8
       relayout at 1.4 GB/s). PERF.md, PR 30, has every form tried.
+      A call large enough (`_sub_batch_rows`) goes in as sub-batches
+      of the batch axis, each put and launched without a wait between,
+      so the copy in, the program and the next copy's layout overlap;
+      the parities come home as one array (PERF.md, PR 35).
 """
 
 from __future__ import annotations
@@ -60,6 +64,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..gf.tables import bit_powers, matrix_to_bitmatrix
+from ..utils.perf_counters import PerfCountersBuilder, g_perf_counters
 
 Array = jax.Array
 
@@ -244,15 +249,21 @@ def pow2_bucket(n: int) -> int:
     return 1 << max(0, int(n - 1).bit_length())
 
 
+def _pad_rows(arr, rows: int):
+    """`arr` on the device with its leading dim zero-padded to `rows`."""
+    arr = jnp.asarray(arr)
+    if arr.shape[0] != rows:
+        arr = jnp.pad(arr, [(0, rows - arr.shape[0])]
+                      + [(0, 0)] * (arr.ndim - 1))
+    return arr
+
+
 def pad_to_bucket(arr):
     """`arr` on the device with its leading dim padded to the pow2
     bucket, and the leading dim it had."""
     arr = jnp.asarray(arr)
     B = arr.shape[0]
-    bucket = pow2_bucket(B)
-    if bucket != B:
-        arr = jnp.pad(arr, [(0, bucket - B)] + [(0, 0)] * (arr.ndim - 1))
-    return arr, B
+    return _pad_rows(arr, pow2_bucket(B)), B
 
 
 def run_bucketed(fn, arr):
@@ -284,6 +295,54 @@ def make_encoder(matrix: np.ndarray, bucket_batch: bool = True):
                                      jnp.asarray(data, jnp.uint8))
 
 
+# A call of the host face whose bucket holds at least two sub-batches of
+# this many bytes of operand crosses the link as a pipeline of them
+# (`make_host_encoder`); a smaller one is the single launch it always
+# was. One v5e, k=8 m=3, rows of 512 KiB, ms a warm call by the operand
+# a sub-batch holds (PR 35's chip run; PERF.md has the table):
+#
+#   call (operand)      one launch   4 MiB    8 MiB   16 MiB   32 MiB
+#   B=1    4 MiB           2.47        -        -        -        -
+#   B=2    8 MiB           3.27      3.06       -        -        -
+#   B=4   16 MiB           4.65      4.25     4.25       -        -
+#   B=8   32 MiB           7.62      6.98     6.24     6.68       -
+#   B=16  64 MiB          13.79     12.28     9.89    10.72    12.36
+#   B=32 128 MiB          39.01     37.03    30.12    31.58    33.82
+#
+# Under 8 MiB a put costs more than its copy hides (32 puts a call at
+# 4 MiB); over it the first sub-batch's way in, which nothing hides,
+# grows. The whole gain is the device and the linearize hidden under
+# the H2D copy: the way home stays one copy, because a result fetched
+# in pieces has to be assembled into a fresh host array and first-touch
+# page faults make that pass dearer (53 ms for 48 MiB on one thread,
+# 24 on four) than everything it would hide.
+_SUB_BATCH_BYTES = 8 << 20
+
+
+def _sub_batch_rows(rows: int, row_bytes: int) -> int:
+    """Rows of a bucket of `rows` that one launch of the host face
+    takes: all of them, unless the bucket holds at least two sub-batches
+    of `_SUB_BATCH_BYTES` of operand. From the call's shape alone; both
+    counts are powers of two, so the sub-batches tile the bucket."""
+    sub = pow2_bucket(-(-_SUB_BATCH_BYTES // row_bytes))
+    return sub if 2 * sub <= rows else rows
+
+
+#: how often the host face is called and how often it pipelines
+#: (`perf dump` shows the process-wide collection's loggers)
+_codec_perf = g_perf_counters.add(
+    PerfCountersBuilder("codec")
+    .add_u64_counter("host_face_calls",
+                     "calls of a matrix's host face (encode_chunks, "
+                     "decode_chunks)")
+    .add_u64_counter("host_face_pipelined_calls",
+                     "of those, calls large enough to go as sub-batches")
+    .add_u64_counter("host_face_sub_batches",
+                     "sub-batches launched by pipelined calls")
+    .add_u64_counter("host_face_bytes_in", "operand bytes handed to the face")
+    .create_perf_counters())
+
+
 def make_host_encoder(matrix: np.ndarray):
     """The host-to-host face of the matrix's program: host uint8
     (B, k, L) in, host uint8 (B, m, L) out, C-contiguous, every byte on
@@ -303,12 +362,22 @@ def make_host_encoder(matrix: np.ndarray):
     1.4 GB/s), which is why the multiply runs on words and not before a
     cast.
 
-    One path for every shape: a row length that is no multiple of 512
-    bytes (128 words, a row of the flat form) is zero-padded here and
+    One algorithm for every shape: a row length that is no multiple of
+    512 bytes (128 words, a row of the flat form) is zero-padded here and
     the pad sliced off the view (the one case that copies on the host:
     zero columns give zero parity); the batch is bucketed to a power of
     two as `make_encoder` does, and the bucket's spare rows are sliced
-    off the view, not on the device. Device-resident callers keep
+    off the view, not on the device.
+
+    A large call is a pipeline (`_sub_batch_rows`): the bucket goes in
+    as equal sub-batches of the batch axis (host views, no host copy),
+    each put and launched without waiting for the one before (the same
+    program at the sub-batch's shape: one compile, S launches), so the
+    H2D copy of sub-batch i+1 runs under the program of sub-batch i;
+    the S parities are concatenated on the device into the one flat
+    array and come home in one copy. Every operand and parity piece
+    stays on the device until the result is on the host. A small call
+    is one put and one launch, as before. Device-resident callers keep
     `make_encoder`: its program, shape and dtype are unchanged."""
     matrix = np.ascontiguousarray(matrix, dtype=np.uint8)
     m, k = matrix.shape
@@ -317,12 +386,29 @@ def make_host_encoder(matrix: np.ndarray):
     def encode(data) -> np.ndarray:
         data = np.asarray(data, np.uint8)
         _check(data, k)
-        L = data.shape[2]
+        B, _, L = data.shape
         tail = -L % _FLAT_ROW
         if tail:
             data = np.pad(data, ((0, 0), (0, 0), (0, tail)))
-        words, B = pad_to_bucket(np.ascontiguousarray(data).view(np.uint32))
-        flat = np.asarray(jitted(words))
-        parity = flat.view(np.uint8).reshape(words.shape[0], m, L + tail)
+        host = np.ascontiguousarray(data).view(np.uint32)
+        sub = _sub_batch_rows(pow2_bucket(B), k * (L + tail))
+        # a bucket's empty sub-batches are not sent; under the line the
+        # one sub-batch is the bucket
+        launches = max(1, -(-B // sub))
+        # both lists live until the parity is home: the device holds the
+        # whole call, however many launches it is
+        words, parts = [], []
+        for i in range(launches):
+            words.append(_pad_rows(host[i * sub:(i + 1) * sub], sub))
+            parts.append(jitted(words[-1]))
+        flat = parts[0] if launches == 1 else jnp.concatenate(parts)
+        pipelined = int(launches > 1)
+        _codec_perf.inc_many((
+            ("host_face_calls", 1),
+            ("host_face_pipelined_calls", pipelined),
+            ("host_face_sub_batches", launches * pipelined),
+            ("host_face_bytes_in", B * k * L)))
+        parity = np.asarray(flat).view(np.uint8).reshape(launches * sub, m,
+                                                         L + tail)
         return np.ascontiguousarray(parity[:B, :, :L])
     return encode

@@ -3961,10 +3961,14 @@ class OSDDaemon:
         admin_socket.cc registering OpTracker/PerfCounters/log
         commands) — so the two can't drift."""
         from ..utils.log import g_log
+        from ..utils.perf_counters import g_perf_counters
+        # the process-wide loggers (`codec`: the EC plugin's host face)
+        # beside the daemon's own; not in perf_dump_all, whose dumps the
+        # mgr sums over daemons that may share one process
         if cmd == "perf dump":
-            return self.perf_dump_all()
+            return {**g_perf_counters.dump(), **self.perf_dump_all()}
         if cmd == "perf schema":
-            return self.perf_schema_all()
+            return {**g_perf_counters.schema(), **self.perf_schema_all()}
         if cmd == "perf reset":
             self.perf_reset_all()
             return {"success": True}

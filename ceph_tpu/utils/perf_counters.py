@@ -329,6 +329,10 @@ class PerfCountersCollection:
         with self._lock:
             return {name: c.dump() for name, c in self._loggers.items()}
 
+    def schema(self) -> dict:
+        with self._lock:
+            return {name: c.schema() for name, c in self._loggers.items()}
+
     def reset(self) -> None:
         with self._lock:
             loggers = list(self._loggers.values())

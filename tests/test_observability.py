@@ -100,6 +100,48 @@ class TestAdminSocket:
                 assert is_declared(logger, key), \
                     f"mon {logger}.{key} emitted but never declared"
 
+    def test_codec_counters_in_perf_dump(self, cluster, client,
+                                         monkeypatch):
+        """The process-wide `codec` logger: a host-face call under the
+        line raises `host_face_calls` only, one over it the pipelined
+        counters too, and a daemon's `perf dump` and `perf schema` list
+        the logger beside the daemon's own."""
+        import numpy as np
+
+        from ceph_tpu.ec.registry import factory
+        from ceph_tpu.ops import rs_kernels as K
+
+        def codec():
+            return admin_command(cluster.asok_path("osd.0"),
+                                 "perf dump")["codec"]
+
+        coder = factory("plugin=jerasure technique=reed_sol_van k=4 m=2")
+        data = np.arange(6 * 4 * 512, dtype=np.uint8).reshape(6, 4, 512)
+        before = codec()
+        coder.encode_chunks(data)               # 12 KiB: one launch
+        small = codec()
+        assert small["host_face_calls"] == before["host_face_calls"] + 1
+        assert small["host_face_bytes_in"] \
+            == before["host_face_bytes_in"] + data.size
+        assert small["host_face_pipelined_calls"] \
+            == before["host_face_pipelined_calls"]
+        assert small["host_face_sub_batches"] \
+            == before["host_face_sub_batches"]
+        # the line lowered to two rows a sub-batch: 6 rows go as 3
+        monkeypatch.setattr(K, "_SUB_BATCH_BYTES", 2 * 4 * 512)
+        coder.encode_chunks(data)
+        large = codec()
+        assert large["host_face_calls"] == small["host_face_calls"] + 1
+        assert large["host_face_pipelined_calls"] \
+            == small["host_face_pipelined_calls"] + 1
+        assert large["host_face_sub_batches"] \
+            == small["host_face_sub_batches"] + 3
+        schema = admin_command(cluster.asok_path("osd.0"), "perf schema")
+        assert schema["codec"]["host_face_calls"]["kind"] == "counter"
+        assert set(large) == set(schema["codec"])
+        # the daemon's own loggers are there as before
+        assert "msgr" in schema and "ec" in schema
+
     def test_historic_ops_and_log_dump(self, cluster, client):
         p = cluster.asok_path("osd.0")
         # some osd served client ops; find one with history

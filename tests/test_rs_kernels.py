@@ -209,7 +209,7 @@ def test_host_face_takes_strided_rows_and_refuses_bad_shapes():
         face(_rand(1, 4, 8)[0])
 
 
-def test_host_face_compiles_once_a_shape():
+def test_host_face_compiles_once_a_shape(monkeypatch):
     mat = reed_sol_van_matrix(5, 2)
     before = K._make_jitted_words.cache_info()
     face = K.make_host_encoder(mat)
@@ -225,6 +225,17 @@ def test_host_face_compiles_once_a_shape():
     assert jitted._cache_size() == 2
     # the device-resident program is another function with its own cache
     assert K._make_jitted(mat.tobytes(), 2, 5) is not jitted
+    # over the line: sub-batches of 2 rows, and the 4 (or 3 and a padded
+    # one, or 3 of a bucket's 4) share ONE program of that shape
+    monkeypatch.setattr(K, "_SUB_BATCH_BYTES", 2 * 5 * 512)
+    for B in (8, 7, 5):
+        data = _rand(B, 5, 512, seed=B)
+        np.testing.assert_array_equal(face(data), R.encode_ref(mat, data))
+    assert jitted._cache_size() == 3
+    # under it again: the whole bucket's program, compiled before
+    monkeypatch.undo()
+    face(_rand(8, 5, 512))
+    assert jitted._cache_size() == 3
 
 
 @pytest.mark.parametrize("erased", [(0, 9), (3,)])
@@ -257,3 +268,69 @@ def test_host_face_zero_and_identity_rows():
     data = _rand(2, 3, 130, seed=13)
     np.testing.assert_array_equal(K.make_host_encoder(mat)(data),
                                   R.encode_ref(mat, data))
+
+
+# sub-batch bytes, which matrix, B, L, rows strided: the line lowered so
+# that a CPU run pipelines at a few KiB
+_PIPELINED = [
+    (8192, "pool", 8, 512, False),      # 4 sub-batches of 2, none partial
+    (8192, "pool", 5, 512, False),      # bucket 8: 2 + 2 + a padded 1, the 4th not sent
+    (8192, "pool", 3, 512, False),      # bucket 4: 2 + a padded 1
+    (16384, "pool", 6, 700, False),     # L no multiple of 512: rows of 1,024
+    (4096, "row", 12, 4, False),        # one object a sub-batch, 12 of a bucket's 16
+    (8192, "decode", 7, 516, False),    # a decode matrix
+    (8192, "pool", 6, 512, True),       # strided rows
+    (32768, "pool", 16, 1024, False),   # B a power of two, S = 4
+]
+
+
+@pytest.mark.parametrize("sub_bytes,which,B,L,strided", _PIPELINED)
+def test_host_face_pipelined_equals_single_launch(monkeypatch, sub_bytes,
+                                                  which, B, L, strided):
+    """A call over the line goes as sub-batches and gives, byte for
+    byte, the oracle's parity and the single launch's."""
+    mat = _face_matrix(which)
+    k = mat.shape[1]
+    data = _rand(B, k, 2 * L if strided else L, seed=B + L)
+    if strided:
+        data = data[:, :, ::2]
+    face = K.make_host_encoder(mat)
+    single = face(data)
+    row_bytes = k * (L + -L % 512)
+    monkeypatch.setattr(K, "_SUB_BATCH_BYTES", sub_bytes)
+    sub = K._sub_batch_rows(K.pow2_bucket(B), row_bytes)
+    assert 2 * sub <= K.pow2_bucket(B)
+    before = K._codec_perf.dump()
+    got = face(data)
+    after = K._codec_perf.dump()
+    assert after["host_face_pipelined_calls"] \
+        == before["host_face_pipelined_calls"] + 1
+    assert after["host_face_sub_batches"] \
+        == before["host_face_sub_batches"] + -(-B // sub)
+    assert isinstance(got, np.ndarray) and got.dtype == np.uint8
+    assert got.shape == (B, mat.shape[0], L) and got.flags.c_contiguous
+    np.testing.assert_array_equal(got, R.encode_ref(mat, data))
+    np.testing.assert_array_equal(got, single)
+    # the caller's own: no later call writes into it, nor is it the
+    # operand's memory
+    keep = got.copy()
+    other = face(_rand(B, k, L, seed=99))
+    assert not np.shares_memory(got, other)
+    assert not np.shares_memory(got, data)
+    np.testing.assert_array_equal(got, keep)
+
+
+@pytest.mark.parametrize("rows,row_bytes,want", [
+    (1, 4 << 20, 1),        # one served object: the single launch
+    (2, 4 << 20, 2),        # 8 MiB: one sub-batch's worth
+    (4, 4 << 20, 2),        # 16 MiB: two sub-batches of two objects
+    (8, 4 << 20, 2),
+    (32, 4 << 20, 2),       # the codec cell's call: 16 sub-batches
+    (32, 4096, 32),         # 128 KiB in all: far under the line
+    (8, 3 << 20, 4),        # rows that do not divide 8 MiB: 12 MiB a sub-batch
+    (4, 16 << 20, 1),       # a row over the line: a row a sub-batch
+    (1, 64 << 20, 1),       # nothing to split
+])
+def test_sub_batch_rule_from_the_shape_alone(rows, row_bytes, want):
+    assert K._SUB_BATCH_BYTES == 8 << 20
+    assert K._sub_batch_rows(rows, row_bytes) == want

@@ -64,24 +64,47 @@ def test_rs_encode_compiles_at_32_objects(one_chip, coder, lowering):
     assert out.memory_analysis().temp_size_in_bytes < 2048 * MiB
 
 
-def test_host_face_program_is_words_in_a_flat_row_major_result_out(one_chip,
-                                                                   coder):
-    """What `encode_chunks` launches on 32 x 4 MiB: words in, the
-    parity out as (N, 128) uint32 in layout {1,0:T(8,128)}, the one
-    form the copy to the host neither de-tiles nor transposes. (The
-    uint8 (32, 3, L) parity leaves as {2,0,1:T(8,128)(4,1)}; a cast of
-    it to words on the device asks for 73.5 GB: PERF.md, PR 30.)"""
+@pytest.mark.parametrize("sub_batch", [False, True])
+def test_host_face_program_is_words_in_a_flat_row_major_result_out(
+        one_chip, coder, sub_batch):
+    """What `encode_chunks` launches: on a bucket under the line the
+    whole of it (here 32 x 4 MiB, the shape every call had before PR
+    35), on 32 x 4 MiB itself 16 sub-batches of the shape
+    `_sub_batch_rows` gives. Words in, the parity out as (N, 128) uint32
+    in layout {1,0:T(8,128)}, the one form the copy to the host neither
+    de-tiles nor transposes. (The uint8 (32, 3, L) parity leaves as
+    {2,0,1:T(8,128)(4,1)}; a cast of it to words on the device asks for
+    73.5 GB: PERF.md, PR 30.)"""
     import re
 
     from ceph_tpu.ops import rs_kernels
+    rows = rs_kernels._sub_batch_rows(32, K * SHARD) if sub_batch else 32
+    assert rows == (2 if sub_batch else 32)
     fn = rs_kernels._make_jitted_words(coder.matrix.tobytes(), M, K)
-    out = fn.lower(_struct(one_chip, (32, K, SHARD // 4),
+    out = fn.lower(_struct(one_chip, (rows, K, SHARD // 4),
                            np.uint32)).compile()
     layout = re.search(r"entry_computation_layout=\{(.*?)\}\}",
                        out.as_text()).group(1)
-    assert layout.endswith("->u32[98304,128]{1,0:T(8,128)"), layout
+    flat_rows = rows * M * SHARD // 512
+    assert layout.endswith(f"->u32[{flat_rows},128]{{1,0:T(8,128)"), layout
     assert _no_gather(out)
     assert out.memory_analysis().temp_size_in_bytes < 3072 * MiB
+
+
+def test_host_face_sub_batch_parities_concatenate_row_major(one_chip):
+    """The 16 flat parities of a 32 x 4 MiB call become the one flat
+    array of the whole call on the device, still row-major: a copy, no
+    relayout."""
+    import re
+
+    import jax.numpy as jnp
+    part = ((2 * M * SHARD // 512, 128), np.uint32)
+    out = _compile(lambda *parts: jnp.concatenate(parts), one_chip,
+                   *[part] * 16)
+    layout = re.search(r"entry_computation_layout=\{(.*?)\}\}",
+                       out.as_text()).group(1)
+    assert layout.endswith("->u32[98304,128]{1,0:T(8,128)"), layout
+    assert out.memory_analysis().temp_size_in_bytes < 64 * MiB
 
 
 def _no_gather(compiled):
