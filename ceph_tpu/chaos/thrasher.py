@@ -779,7 +779,8 @@ class Thrasher:
                     return
                 acked[n_] = data
 
-        t = threading.Thread(target=_writer, daemon=True)
+        t = threading.Thread(target=_writer, daemon=True,
+                             name="thrash-writer")
         try:
             for o in sorted(self.c.osd_ids()):
                 st = self.c.osds[o].store.statfs()

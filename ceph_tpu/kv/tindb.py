@@ -55,6 +55,7 @@ import os
 import struct
 import threading
 
+from ..utils.perf_counters import PerfCountersBuilder, g_perf_counters
 from .interface import (KeyValueDB, KVTransaction, combine_key,
                         prefix_range)
 
@@ -75,6 +76,16 @@ class TinDBCorruption(IOError):
 
 _crc_impl = None
 
+#: which crc the stores and the WAL seal with (`perf dump` shows the
+#: process-wide collection's loggers): the fallback is pure Python and
+#: was picked silently
+_kv_perf = g_perf_counters.add(
+    PerfCountersBuilder("kv")
+    .add_u64("host_crc32c_native",
+             "host_crc32c runs the native library (1) or the pure-Python "
+             "fallback (0; also until the first crc picks)")
+    .create_perf_counters())
+
 
 def host_crc32c(data, seed: int = 0xFFFFFFFF) -> int:
     """Raw-register crc32c (seed 0xFFFFFFFF, no final inversion) —
@@ -89,10 +100,12 @@ def host_crc32c(data, seed: int = 0xFFFFFFFF) -> int:
             def _crc_impl(b, s, _L=L):
                 return int(_L.ec_crc32c(s, b, len(b)))
         except Exception:          # no toolchain: correctness over speed
+            L = None
             from ..csum.reference import ceph_crc32c
 
             def _crc_impl(b, s):
                 return int(ceph_crc32c(s, b))
+        _kv_perf.set("host_crc32c_native", int(L is not None))
     return _crc_impl(bytes(data), seed)
 
 
@@ -480,7 +493,6 @@ class TinDB(KeyValueDB):
         # what a daemon nests under "tindb" in its perf dump and what
         # MgrReports aggregate (the RocksDB statistics -> perf
         # counters bridge the reference's BlueStore maintains)
-        from ..utils.perf_counters import PerfCountersBuilder
         self.perf = (PerfCountersBuilder("tindb")
                      .add_u64_counter("wal_records",
                                       "transaction batches appended")

@@ -88,6 +88,10 @@ CATEGORY_OF = {
     "recovery.settle": "store",
     "store.apply": "store",
     "store.commit": "store",
+    "store.commit.stage": "store",
+    "store.commit.pwrite": "store",
+    "store.commit.csum": "store",
+    "store.commit.wal": "store",
     "store.read": "store",
     "osd.subop": "store",
     "retro.reached_pg": "queue",

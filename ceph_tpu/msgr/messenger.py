@@ -871,7 +871,8 @@ class Messenger:
         # messenger sleeps
         self._ack_event = threading.Event()
         self._ack_thread = threading.Thread(target=self._ack_loop,
-                                            daemon=True)
+                                            daemon=True,
+                                            name=f"msgr-{name}-ack")
         self._ack_thread.start()
 
     # -- dispatch ------------------------------------------------------------
@@ -891,8 +892,8 @@ class Messenger:
         if not fast and self._dispatch_q is None:
             import queue
             self._dispatch_q = queue.SimpleQueue()
-            threading.Thread(target=self._dispatch_loop,
-                             daemon=True).start()
+            threading.Thread(target=self._dispatch_loop, daemon=True,
+                             name=f"msgr-{self.name}-dispatch").start()
 
     def _dispatch_loop(self) -> None:
         import queue
@@ -929,7 +930,8 @@ class Messenger:
                 # than silently going deaf
                 return
             threading.Thread(target=self._handshake_in, args=(sock,),
-                             daemon=True).start()
+                             daemon=True,
+                             name=f"msgr-{self.name}-handshake").start()
 
     def _check_incarnation(self, peer: str, nonce: bytes) -> None:
         """A changed instance cookie = the peer rebooted: its sequence

@@ -66,7 +66,8 @@ class ECRuntimeServer:
         self._sock.bind(path)
         self._sock.listen(8)
         self._stop = threading.Event()
-        self._thread = threading.Thread(target=self._serve, daemon=True)
+        self._thread = threading.Thread(target=self._serve, daemon=True,
+                                        name="native-server")
 
     # -- lifecycle ----------------------------------------------------------
 
@@ -107,7 +108,7 @@ class ECRuntimeServer:
                 conn.close()
                 break
             t = threading.Thread(target=self._handle_conn, args=(conn,),
-                                 daemon=True)
+                                 daemon=True, name="native-server-conn")
             t.start()
 
     def _handle_conn(self, conn: socket.socket) -> None:

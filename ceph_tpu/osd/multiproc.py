@@ -178,7 +178,8 @@ class OSDProcHandle:
         def _read():
             nonlocal line
             line = self._proc.stdout.readline()
-        t = threading.Thread(target=_read, daemon=True)
+        t = threading.Thread(target=_read, daemon=True,
+                             name=f"{self.name}-child-stdout")
         t.start()
         t.join(max(0.0, t_end - time.monotonic()))
         if not line:
@@ -228,7 +229,8 @@ class OSDProcHandle:
 
                 def _read():
                     line[0] = self._proc.stdout.readline()
-                t = threading.Thread(target=_read, daemon=True)
+                t = threading.Thread(target=_read, daemon=True,
+                                     name=f"{self.name}-child-stdout")
                 t.start()
                 t.join(max(0.0, t_end - time.monotonic()))
                 if not line[0]:

@@ -98,6 +98,8 @@ declare_span_names(
     "osd.queue", "osd.op", "osd.pg_lock.wait",
     "osd.subop", "osd.store_lock.wait",
     "store.apply", "store.commit", "store.read",
+    "store.commit.stage", "store.commit.pwrite", "store.commit.csum",
+    "store.commit.wal", "profiler.sample",
     "osd.recovery_round",
     "osd.repair_policy", "osd.repair_throttle",
     "msgr.seal", "msgr.open",
@@ -1300,15 +1302,15 @@ class _RecoveryRound:
         # each grant executes one fused batch under the round's trace
         # context (if sampled): the pull/stage/launch/fetch/push spans
         # and the helper pulls' osd.subop spans all land in one trace.
-        # `osd.recovery_round` is the flight recorder's record of the
-        # grant (with its PGs); `recovery.grant` the profiler's and
-        # the span log's, `nbytes` the helper bytes it is to stage
-        from ..utils.flight_recorder import activate, trace_span
+        # `osd.recovery_round` carries the grant's PGs to the flight
+        # recorder; `recovery.grant` is the stage, `nbytes` the helper
+        # bytes it is to stage
+        from ..utils.flight_recorder import activate
         with activate(self.trace_ctx,
                       self.d.flight if self.trace_ctx is not None
                       else None):
-            with trace_span("osd.recovery_round",
-                            pgs=sorted(self.plans)):
+            with span("osd.recovery_round",
+                      tags={"pgs": sorted(self.plans)}):
                 with span("recovery.grant",
                           nbytes=self.runner.next_stage_bytes()):
                     self.d.perf.inc("recovery_grants")
@@ -1342,10 +1344,9 @@ class _RecoveryRound:
             time.monotonic())
         if wait > 0.0:
             d.repair_policy._count("repair_domain_throttles")
-            from ..utils.flight_recorder import trace_span
-            with trace_span("osd.repair_throttle",
-                            wait_ms=int(wait * 1000),
-                            domains=len(domain_bytes)):
+            with span("osd.repair_throttle",
+                      tags={"wait_ms": int(wait * 1000),
+                            "domains": len(domain_bytes)}):
                 pass
         return wait
 
@@ -1357,7 +1358,7 @@ class _RecoveryRound:
             # back when the bucket has refilled (bounded nap so a
             # live budget raise is picked up promptly)
             t = threading.Timer(min(wait, 0.5), self._requeue)
-            t.daemon = True
+            t.daemon, t.name = True, f"{d.name}-requeue"
             t.start()
             return
         # the daemon lock plus EVERY member PG's lock (ascending —
@@ -1397,7 +1398,7 @@ class _RecoveryRound:
         sleep = float(d.config["osd_recovery_sleep"])
         if sleep > 0 and not d._stop.is_set():
             t = threading.Timer(sleep, self._requeue)
-            t.daemon = True
+            t.daemon, t.name = True, f"{d.name}-requeue"
             t.start()
         else:
             self._requeue()
@@ -1738,9 +1739,10 @@ class OSDDaemon:
                         return
                     except Exception:   # noqa: BLE001 — mons booting
                         self._stop.wait(0.5)
-            threading.Thread(target=_prewarm, daemon=True).start()
+            threading.Thread(target=_prewarm, daemon=True,
+                             name=f"{self.name}-tickets").start()
         self._hb = threading.Thread(target=self._heartbeat_loop,
-                                    daemon=True)
+                                    daemon=True, name=f"{self.name}-hb")
         self._hb.start()
 
     def _spawn_ticket_refresh(self) -> None:
@@ -1763,7 +1765,8 @@ class OSDDaemon:
                 pass             # the next deferral re-kicks us
             finally:
                 self._ticket_gate.release()
-        threading.Thread(target=_go, daemon=True).start()
+        threading.Thread(target=_go, daemon=True,
+                         name=f"{self.name}-tickets").start()
 
     def _authorize_peer(self, peer: str) -> None:
         """osd->osd cephx (ref: OSD heartbeat/cluster messengers carry
@@ -4183,7 +4186,8 @@ class OSDDaemon:
                     self.msgr.send(peer, rep)
                 except (KeyError, OSError, ConnectionError):
                     pass
-            threading.Thread(target=_serve_admin, daemon=True).start()
+            threading.Thread(target=_serve_admin, daemon=True,
+                             name=f"{self.name}-admin").start()
             return
         # mClock SHARDED admission: PG ops hash by their leading PG id
         # to an op shard and queue under their QoS class; each shard
@@ -5419,7 +5423,7 @@ class MonDaemon:
         m.register_handler(MOSDPing.type_id, self._on_ping)
         m.register_handler(MOSDPingReply.type_id, self._on_pong)
         self._hb = threading.Thread(target=self._mon_hb_loop,
-                                    daemon=True)
+                                    daemon=True, name=f"{self.name}-hb")
         self._hb.start()
 
     # -- election (rank + liveness, gated on monmap membership) --------------
@@ -7334,7 +7338,8 @@ class Client:
                 self._link_costs_at = time.monotonic()
                 self._link_gate.release()
 
-        threading.Thread(target=_pull, daemon=True).start()
+        threading.Thread(target=_pull, daemon=True,
+                         name=f"{self.msgr.name}-linkcosts").start()
 
     def _read_fallback(self, ps: int, avoid: set[str]) -> str | None:
         """Next-best acting shard for a degraded/hedged read: an

@@ -974,7 +974,10 @@ class TinStore:
 
     def queue_transaction(self, txn: Transaction) -> None:
         # the span inside the lock: the device write + WAL append, not
-        # the wait for another transaction
+        # the wait for another transaction. Its parts are detail spans
+        # beside it (`.stage` the read-modify copy, `tobytes` and
+        # compression, `.pwrite`, `.csum`, `.wal`): the commit's own
+        # self time stays whole for whoever sums it
         with self._lock, _span("store.commit"):
             self._alive()
             self._validate(txn)
@@ -1005,31 +1008,33 @@ class TinStore:
                             del staged[key]
                     if kind in ("write", "xor"):
                         _, cid, oid, woff, data = op
-                        cur = self._staged_bytes(staged, gone,
-                                                 gone_colls, cid, oid)
-                        end = woff + len(data)
-                        if end > len(cur):
-                            grown = np.zeros(end, dtype=np.uint8)
-                            grown[:len(cur)] = cur
-                            cur = grown
-                        else:
-                            cur = cur.copy()
-                        if kind == "xor":
-                            cur[woff:end] ^= data
-                        else:
-                            cur[woff:end] = data
+                        with _span("store.commit.stage", detail=True):
+                            cur = self._staged_bytes(staged, gone,
+                                                     gone_colls, cid, oid)
+                            end = woff + len(data)
+                            if end > len(cur):
+                                grown = np.zeros(end, dtype=np.uint8)
+                                grown[:len(cur)] = cur
+                                cur = grown
+                            else:
+                                cur = cur.copy()
+                            if kind == "xor":
+                                cur[woff:end] ^= data
+                            else:
+                                cur[woff:end] = data
                         meta_ops.append(self._stage(
                             staged, new_extents, cid, oid, cur))
                     elif kind == "truncate":
                         _, cid, oid, size = op
-                        cur = self._staged_bytes(staged, gone,
-                                                 gone_colls, cid, oid)
-                        if size <= len(cur):
-                            cur = cur[:size].copy()
-                        else:
-                            grown = np.zeros(size, dtype=np.uint8)
-                            grown[:len(cur)] = cur
-                            cur = grown
+                        with _span("store.commit.stage", detail=True):
+                            cur = self._staged_bytes(staged, gone,
+                                                     gone_colls, cid, oid)
+                            if size <= len(cur):
+                                cur = cur[:size].copy()
+                            else:
+                                grown = np.zeros(size, dtype=np.uint8)
+                                grown[:len(cur)] = cur
+                                cur = grown
                         meta_ops.append(self._stage(
                             staged, new_extents, cid, oid, cur))
                     else:
@@ -1039,9 +1044,12 @@ class TinStore:
                     self._alloc.free(doff, dlen)
                 raise
             if self.o_dsync and new_extents:
-                os.fsync(self._dev_fd)     # data durable BEFORE the WAL
+                with _span("store.commit.pwrite", detail=True):
+                    os.fsync(self._dev_fd)  # data durable BEFORE the WAL
             try:
-                self._db.submit_transaction(self._kv_txn_for(meta_ops))
+                with _span("store.commit.wal", detail=True):
+                    self._db.submit_transaction(
+                        self._kv_txn_for(meta_ops))
             except OSError:
                 # ENOSPC on the WAL append (r21): the KV plane rolled
                 # its seq/tail back and nothing references the staged
@@ -1198,42 +1206,46 @@ class TinStore:
         batch. Compression happens HERE (the _do_write decision):
         the device and the crc-on-stored-bytes see compressed data,
         the cache and the logical crc see raw data."""
-        stored = arr.tobytes()
-        calg = ""
-        if self.compression is not None \
-                and len(arr) >= self.compression_min_blob:
-            comp = self._compress(self.compression, stored)
-            if len(comp) <= self.compression_required_ratio * len(arr):
-                stored, calg = comp, self.compression
+        with _span("store.commit.stage", detail=True):
+            stored = arr.tobytes()
+            calg = ""
+            if self.compression is not None \
+                    and len(arr) >= self.compression_min_blob:
+                comp = self._compress(self.compression, stored)
+                if len(comp) <= self.compression_required_ratio * len(arr):
+                    stored, calg = comp, self.compression
         # capacity gate BEFORE the allocator grows the device: the
         # raise unwinds through queue_transaction's except path, which
         # frees every extent this txn already staged — the ENOSPC
         # abort is atomic (nothing hit the KV plane yet)
-        if self.capacity_bytes:
-            need = ExtentAllocator.round_up(max(1, len(stored)))
-            if self.used_bytes() + need > self.capacity_bytes:
-                import errno
-                raise OSError(
-                    errno.ENOSPC,
-                    f"tinstore over capacity "
-                    f"({self.capacity_bytes} bytes)")
-        doff, dlen = self._alloc.alloc(len(stored))
-        if self._alloc.device_size > os.fstat(self._dev_fd).st_size:
-            os.ftruncate(self._dev_fd, self._alloc.device_size)
-        if stored:
-            os.pwrite(self._dev_fd, stored, doff)
+        with _span("store.commit.pwrite", detail=True):
+            if self.capacity_bytes:
+                need = ExtentAllocator.round_up(max(1, len(stored)))
+                if self.used_bytes() + need > self.capacity_bytes:
+                    import errno
+                    raise OSError(
+                        errno.ENOSPC,
+                        f"tinstore over capacity "
+                        f"({self.capacity_bytes} bytes)")
+            doff, dlen = self._alloc.alloc(len(stored))
+            if self._alloc.device_size > os.fstat(self._dev_fd).st_size:
+                os.ftruncate(self._dev_fd, self._alloc.device_size)
+            if stored:
+                os.pwrite(self._dev_fd, stored, doff)
         new_extents.append((doff, dlen))
         staged[(cid, oid)] = arr
         st = self.compress_stats
         st["logical_bytes"] += len(arr)
         st["stored_bytes"] += len(stored)
-        if calg:
-            st["compressed_blobs"] += 1
-            return ("setextc", cid, oid, doff, dlen, len(arr),
-                    _crc32c(arr), calg, len(stored),
-                    _crc32c(np.frombuffer(stored, np.uint8)))
-        st["raw_blobs"] += 1
-        return ("setext", cid, oid, doff, dlen, len(arr), _crc32c(arr))
+        with _span("store.commit.csum", detail=True):
+            if calg:
+                st["compressed_blobs"] += 1
+                return ("setextc", cid, oid, doff, dlen, len(arr),
+                        _crc32c(arr), calg, len(stored),
+                        _crc32c(np.frombuffer(stored, np.uint8)))
+            st["raw_blobs"] += 1
+            return ("setext", cid, oid, doff, dlen, len(arr),
+                    _crc32c(arr))
 
     def _validate(self, txn: Transaction) -> None:
         # the ObjectStore contract: ops referencing missing

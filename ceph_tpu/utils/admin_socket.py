@@ -36,6 +36,8 @@ class AdminSocket:
 
     def __init__(self, path: str):
         self.path = path
+        # the daemon's name as its socket's file gives it: the threads'
+        self._owner = os.path.basename(path).removesuffix(".asok")
         self._commands: dict[str, tuple] = {}   # cmd -> (fn, help)
         self._listener: socket.socket | None = None
         self._stopping = False
@@ -85,7 +87,8 @@ class AdminSocket:
         srv.bind(self.path)
         srv.listen(8)
         self._listener = srv
-        threading.Thread(target=self._accept_loop, daemon=True).start()
+        threading.Thread(target=self._accept_loop, daemon=True,
+                         name=f"{self._owner}-asok").start()
         return self
 
     def stop(self) -> None:
@@ -107,7 +110,8 @@ class AdminSocket:
             except OSError:
                 return               # closed by stop()
             threading.Thread(target=self._serve, args=(conn,),
-                             daemon=True).start()
+                             daemon=True,
+                             name=f"{self._owner}-asok-conn").start()
 
     def _serve(self, conn: socket.socket) -> None:
         try:

@@ -21,7 +21,7 @@ Design points, in the r9 observability plane's idiom:
   (declare_span_names) and the observability smoke test asserts no
   ring ever carries an undeclared name.
 * OFF-SAMPLE near-zero cost — with no active sampled context,
-  trace_span() is one contextvar read; an UNSAMPLED context (the
+  a span() pays one contextvar read; an UNSAMPLED context (the
   common case: the id travels so slow ops can be retroactively
   assembled, but nothing records eagerly) costs ~17 bytes on the wire
   and nothing else.
@@ -55,10 +55,9 @@ import struct
 import threading
 import time
 
-from . import profiler as _prof
 
 __all__ = [
-    "TraceContext", "FlightRecorder", "trace_span", "activate",
+    "TraceContext", "FlightRecorder", "activate",
     "current", "current_sampled", "declare_span_names",
     "is_span_declared", "declared_span_names", "new_trace_id",
     "retro_root_id",
@@ -392,42 +391,20 @@ def activate(ctx: TraceContext | None, recorder: FlightRecorder | None):
 
 
 @contextlib.contextmanager
-def trace_span(name: str, **tags):
-    """Record `name` as a span under the active SAMPLED context (else
-    a no-op costing one contextvar read). The body runs under a child
-    context so nested spans parent correctly. Sampled or not, the
-    name's attribution category tags the executing thread for the r19
-    CPU sampler (utils/profiler) — unsampled sub-ops still burn CPU,
-    and the flame profile must see store/crypto time the trace plane
-    skipped."""
-    ctx = _CUR.get()
-    if ctx is None or not ctx.sampled:
-        tagged = _prof.push_span(name)
-        try:
-            yield None
-        finally:
-            if tagged:
-                _prof.pop_span()
-        return
-    rec = _REC.get()
-    if rec is None:
-        tagged = _prof.push_span(name)
-        try:
-            yield None
-        finally:
-            if tagged:
-                _prof.pop_span()
-        return
+def _trace_span(name: str, **tags):
+    """`utils/tracing.span`'s flight-ring sink, private to it (every
+    span site is a `span()`, which calls this only where
+    `current_sampled()` holds): record `name` under the active SAMPLED
+    context into the bound recorder. The body runs under a child
+    context so nested spans parent correctly."""
+    ctx, rec = _CUR.get(), _REC.get()
     sid = new_trace_id()
     tok = _CUR.set(ctx.child(sid))
-    tagged = _prof.push_span(name)
     t0w = time.time()
     t0 = time.perf_counter()
     try:
         yield ctx
     finally:
-        if tagged:
-            _prof.pop_span()
         _CUR.reset(tok)
         rec.record(ctx.trace_id, sid, ctx.parent_span_id, name,
                    t0w, time.perf_counter() - t0, tags or None)

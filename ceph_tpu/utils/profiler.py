@@ -31,8 +31,8 @@ Design:
   messenger loop threads outside any span + "other"), so a flame
   profile and a `trace slow` attribution answer in the SAME units.
   The category comes from a per-thread stack maintained by the span
-  instrumentation itself (utils/tracing.span + flight_recorder
-  .trace_span push/pop here): a contextvar cannot be read from the
+  instrumentation itself (utils/tracing.span pushes and pops
+  here): a contextvar cannot be read from the
   sampler thread, a plain dict keyed by thread ident can — and
   because the SAME span sites feed it, the profiler's buckets cannot
   drift from the trace plane's.
@@ -87,6 +87,10 @@ _ACTIVE = 0
 
 _CAT_CACHE: dict[str, str] = {}
 
+#: the span round a sampler's own pass: in a capture's stage table and
+#: timeline by this name, never a category of the profile it takes
+SAMPLE_SPAN = "profiler.sample"
+
 #: innermost-frame function names that mean BLOCKED, not on-CPU —
 #: Condition/Event waits, selector polls, socket accepts/reads, lock
 #: acquires, thread joins (the py-spy idle heuristic). A thread
@@ -120,7 +124,7 @@ def push_span(name: str) -> bool:
     whether a pop is owed (False when no profiler samples — the
     caller must only pop what it pushed, since _ACTIVE can flip
     mid-span)."""
-    if not _ACTIVE:
+    if not _ACTIVE or name == SAMPLE_SPAN:
         return False
     tid = threading.get_ident()
     st = _SPAN_CATS.get(tid)
@@ -233,6 +237,7 @@ class SamplingProfiler:
             self._was_on = False
 
     def _run(self) -> None:
+        from .tracing import span    # it imports this module
         my_tid = threading.get_ident()
         while not self._stop.is_set():
             hz = self.hz
@@ -243,7 +248,8 @@ class SamplingProfiler:
             self._set_active(True)
             t0 = time.perf_counter()
             try:
-                self.sample_once(skip_tids=(my_tid,))
+                with span(SAMPLE_SPAN):
+                    self.sample_once(skip_tids=(my_tid,))
             except Exception:   # noqa: BLE001 — sampling must never
                 pass            # kill its own thread
             busy = time.perf_counter() - t0

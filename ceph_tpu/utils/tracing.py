@@ -14,7 +14,16 @@ reference's op path.
 is, a span that ends has two sinks — the profiler's timeline (device
 clock: idle gaps get stage names) and the process-wide span log below
 (`time.perf_counter`: per-op stage times, with each span's SELF time,
-its duration minus what its child spans on the same thread covered).
+its duration minus what its child spans on the same thread covered, and
+beside it `cpu`, the thread's own CPU seconds (`time.thread_time`,
+read at most once in `_CPU_TRUST_S` a thread) by
+the same rule: a stage whose wall is several times its CPU was waiting,
+for the interpreter or the OS, not working; `tid` and `parent`, the
+enclosing span's name, are the keys a later join needs). A
+`detail=True` span is logged and annotated like any other but takes
+nothing from its parent's self or cpu: the parts of a stage beside the
+whole. While a session is live one `trace-probe` thread also logs the
+host's side of the ledger (`host.tick`, `host.usage`: below).
 While none is, a span is a few attribute stores and touches neither.
 Spans also time into an optional PerfCounters time_avg key, so
 production counters and profiler traces come from the SAME
@@ -37,6 +46,10 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import os
+import re
+import resource
+import sys
 import threading
 import time
 
@@ -48,6 +61,13 @@ from . import profiler as _prof
 
 _enabled = TraceAnnotation.is_enabled
 _now = time.perf_counter
+_cpu_now = time.thread_time
+#: a thread's CPU clock is read at most once in this many seconds (one
+#: reading is a syscall: 0.25 us on a plain kernel, 6 us on the chip
+#: host's sandbox, where the messenger's spans last 12): a span boundary
+#: this close after a reading takes the reading plus the wall time
+#: since, as if the thread had run; so `cpu` is within this of the clock
+_CPU_TRUST_S = 250e-6
 
 #: finished spans of the live capture(s) plus every `xla.compile`.
 #: Appends are GIL-atomic; readers copy (`span_log`).
@@ -61,51 +81,78 @@ _tls = threading.local()             # .stack: this thread's open spans
 
 def _log(name: str, start: float, dur: float, self_s: float,
          trace_id: int | None = None, nbytes: int | None = None,
+         cpu: float | None = 0.0, parent: str | None = None,
          **more) -> None:
-    """One record: start and dur in perf_counter seconds."""
+    """One record, by the thread it is about: start and dur in
+    perf_counter seconds, cpu in that thread's CPU seconds."""
     if len(_LOG) == getattr(_LOG, "maxlen", None):
         with _drop_lock:
             _dropped[0] += 1
     _LOG.append({"name": name, "start": start, "dur": dur, "self": self_s,
-                 "trace_id": trace_id, "nbytes": nbytes, **more})
+                 "trace_id": trace_id, "nbytes": nbytes, "cpu": cpu,
+                 "tid": threading.get_ident(), "parent": parent, **more})
+
+
+def _cpu_at(t: float) -> float:
+    """This thread's CPU seconds at `t` (perf_counter, now): the
+    clock's, or within `_CPU_TRUST_S` of a reading that reading plus
+    the time since; never less than what it last handed out."""
+    last = getattr(_tls, "cpu", None)    # [t of the reading, reading, max]
+    if last is None:
+        last = _tls.cpu = [-1.0, 0.0, 0.0]
+    if t - last[0] >= _CPU_TRUST_S:
+        last[1] = _cpu_now()
+        last[0] = t = _now()             # the reading's own time is no span's
+    last[2] = cpu = max(last[1] + (t - last[0]), last[2])
+    return cpu
 
 
 class span:
     """Named span: visible in jax.profiler traces and, while a capture
-    is live, logged with its self time; optionally tincs
-    `counters[key]` (a time_avg) with the wall duration; when a
-    SAMPLED trace context is active (utils/flight_recorder) — recorded
-    into the executing daemon's flight ring under that trace; and —
+    is live, logged with its self time and its thread's CPU time;
+    optionally tincs `counters[key]` (a time_avg) with the wall
+    duration; when a SAMPLED trace context is active
+    (utils/flight_recorder) — recorded into the executing daemon's
+    flight ring under that trace, with `tags` and `nbytes`; and —
     when the r19 CPU sampler is on — tags this thread with the span's
     attribution category so wall-clock samples land in the same
     queue/crypto/encode/store buckets the trace critical-path uses.
     One instrumentation point, so none of its consumers can drift from
-    the others."""
+    the others. `detail=True`: a part shown beside its parent's whole
+    (the record says so and the parent's self and cpu keep it)."""
 
-    __slots__ = ("name", "counters", "key", "nbytes", "_t0", "_ann",
-                 "_flight", "_tagged", "_covered")
+    __slots__ = ("name", "counters", "key", "nbytes", "tags", "detail",
+                 "_t0", "_c0", "_ann", "_flight", "_tagged", "_covered",
+                 "_cpu_covered")
 
     def __init__(self, name: str, counters=None, key: str | None = None,
-                 nbytes: int | None = None):
+                 nbytes: int | None = None, tags: dict | None = None,
+                 detail: bool = False):
         self.name, self.counters, self.key = name, counters, key
-        self.nbytes = nbytes
+        self.nbytes, self.tags, self.detail = nbytes, tags, detail
 
     def __enter__(self):
         self._flight = None
-        if _fr.current_sampled() is not None:
-            tags = {} if self.nbytes is None else {"nbytes": self.nbytes}
-            self._flight = _fr.trace_span(self.name, **tags)
+        if _fr.current_sampled() is not None and not self.detail:
+            tags = dict(self.tags) if self.tags else {}
+            if self.nbytes is not None:
+                tags["nbytes"] = self.nbytes
+            self._flight = _fr._trace_span(self.name, **tags)
             self._flight.__enter__()
         self._tagged = _prof.push_span(self.name)
         self._ann = None
         if _enabled():
+            if _probe[0] is None:
+                _start_probe()
             self._ann = TraceAnnotation(self.name)
             self._ann.__enter__()
-            self._covered = 0.0      # seconds under child spans
+            # seconds under child spans: wall, and this thread's CPU
+            self._covered = self._cpu_covered = 0.0
             stack = getattr(_tls, "stack", None)
             if stack is None:
                 stack = _tls.stack = []
             stack.append(self)
+            self._c0 = _cpu_at(_now())
         self._t0 = _now()
         return self
 
@@ -114,14 +161,21 @@ class span:
         # exactly the ones worth timing (PerfCounters.time() semantics)
         dur = _now() - self._t0
         if self._ann is not None:
+            cpu = _cpu_at(self._t0 + dur) - self._c0
             self._ann.__exit__(*exc)
             stack = _tls.stack
             stack.pop()
-            if stack:
-                stack[-1]._covered += dur
+            parent, more = (stack[-1] if stack else None), {}
+            if self.detail:
+                more["detail"] = True
+            elif parent is not None:
+                parent._covered += dur
+                parent._cpu_covered += cpu
             ctx = _fr.current()
             _log(self.name, self._t0, dur, dur - self._covered,
-                 ctx.trace_id if ctx else None, self.nbytes)
+                 ctx.trace_id if ctx else None, self.nbytes,
+                 cpu - self._cpu_covered,
+                 parent.name if parent is not None else None, **more)
         if self._tagged:
             _prof.pop_span()
         if self._flight is not None:
@@ -155,7 +209,7 @@ def record_wait(name: str, start: float, dur: float,
     reply awaited): `start` on time.perf_counter. Log only, no profiler
     annotation — nothing ran."""
     if _enabled():
-        _log(name, start, dur, dur, trace_id)
+        _log(name, start, dur, dur, trace_id)    # cpu 0.0: nothing ran
 
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -171,7 +225,7 @@ def _on_compile(event: str, duration_secs: float, **kw) -> None:
     if stack:
         stack[-1]._covered += duration_secs
     _log("xla.compile", _now() - duration_secs, duration_secs,
-         duration_secs, program=kw.get("fun_name"))
+         duration_secs, cpu=None, program=kw.get("fun_name"))
 
 
 jax.monitoring.register_event_duration_secs_listener(_on_compile)
@@ -194,18 +248,138 @@ def span_log_dropped() -> int:
 
 
 def stage_table(records, ops: int) -> dict[str, dict]:
-    """Self time summed by span name: name -> {count, self_s,
-    self_ms_per_op}. `ops` is the number of operations the records
-    served; each row is that stage's busy or waiting time an op, over
-    every daemon and thread that worked for it."""
+    """Self time and CPU time summed by span name: name -> {count,
+    self_s, self_ms_per_op, cpu_s, cpu_ms_per_op}. `ops` is the number
+    of operations the records served; `self` is that stage's busy or
+    waiting time an op, over every daemon and thread that worked for
+    it, `cpu` what its threads ran of it (a `detail` span's row is a
+    part of its parent's, not beside it)."""
     table: dict[str, dict] = {}
     for r in records:
-        row = table.setdefault(r["name"], {"count": 0, "self_s": 0.0})
+        row = table.setdefault(r["name"],
+                               {"count": 0, "self_s": 0.0, "cpu_s": 0.0})
         row["count"] += 1
         row["self_s"] += r["self"]
+        row["cpu_s"] += r.get("cpu") or 0.0
     for row in table.values():
         row["self_ms_per_op"] = row["self_s"] / ops * 1e3 if ops else None
+        row["cpu_ms_per_op"] = row["cpu_s"] / ops * 1e3 if ops else None
     return table
+
+
+# -- the host probe: one thread, exactly as long as a capture ----------------
+
+#: seconds the probe sleeps between two ticks
+_PROBE_TICK_S = 0.010
+_probe: list = [None]                # the live `trace-probe` thread
+_probe_lock = threading.Lock()
+_DAEMON_TOKEN = re.compile(r"^[a-z]+\.\w+$")
+
+
+def thread_role(name: str) -> str:
+    """A thread's role from its name. The one convention: dash-joined
+    tokens, of which the daemon's is the one with a dot (`osd.3`,
+    `mon.0`, `client.0`); the role is the others, each less its
+    trailing digits (`osd.3-shard0` -> `shard`, `msgr-osd.3-r0` ->
+    `msgr-r`, `profiler-mon.1` -> `profiler`, `bench-loop-7` ->
+    `bench-loop`)."""
+    tokens = (t.rstrip("0123456789_") for t in name.split("-")
+              if not _DAEMON_TOKEN.match(t))
+    return "-".join(t for t in tokens if t) or name
+
+
+def _usage() -> dict:
+    """What the process has used so far, and each live Python thread's
+    CPU seconds as [ident, name, seconds]."""
+    ru = resource.getrusage(resource.RUSAGE_SELF)
+    threads = []
+    for t in threading.enumerate():
+        try:
+            threads.append([t.ident, t.name, time.clock_gettime(
+                time.pthread_getcpuclockid(t.ident))])
+        except (OSError, TypeError, ValueError):
+            pass                     # gone since it was listed
+    return {"process_s": time.process_time(), "user_s": ru.ru_utime,
+            "system_s": ru.ru_stime, "minflt": ru.ru_minflt,
+            "nvcsw": ru.ru_nvcsw, "nivcsw": ru.ru_nivcsw,
+            "cpus": len(os.sched_getaffinity(0)),
+            "switch_interval_s": sys.getswitchinterval(),
+            "threads": threads}
+
+
+def _probe_run() -> None:
+    """`host.usage` now and at the end; between them a `host.tick`
+    every `_PROBE_TICK_S` whose `late` is how long past its sleep the
+    thread got to run again: what a thread that becomes runnable pays
+    to take the GIL back (plus the OS's wake-up). Ends itself at the
+    first tick that finds no session live."""
+    t = _now()
+    _log("host.usage", t, 0.0, 0.0, **_usage())
+    while True:
+        time.sleep(_PROBE_TICK_S)
+        woke = _now()
+        if not _enabled():
+            break
+        _log("host.tick", woke, 0.0, 0.0,
+             late=max(0.0, woke - t - _PROBE_TICK_S))
+        t = _now()
+    _log("host.usage", woke, 0.0, 0.0, **_usage())
+    with _probe_lock:
+        _probe[0] = None
+
+
+def _start_probe() -> None:
+    with _probe_lock:
+        if _probe[0] is None:
+            _probe[0] = threading.Thread(target=_probe_run, daemon=True,
+                                         name="trace-probe")
+            _probe[0].start()
+
+
+def _join_probe() -> None:
+    """Wait for a probe whose capture has ended to log its last record."""
+    probe = _probe[0]
+    if probe is not None and not _enabled():
+        probe.join(1.0)
+
+
+def host_usage(records) -> dict | None:
+    """The host's ledger of the stretch between the first and the last
+    `host.usage` record of `records`: cores busy, user against system
+    seconds, minor faults, context switches, CPU by thread role (a
+    thread gone at the end is left out) with the runtime's native
+    threads as the remainder, and the ticks' `late`. None without such
+    a pair."""
+    marks = [r for r in records if r["name"] == "host.usage"]
+    if len(marks) < 2 or marks[-1]["start"] <= marks[0]["start"]:
+        return None
+    first, last = marks[0], marks[-1]
+    seconds = last["start"] - first["start"]
+    cpu_s = last["process_s"] - first["process_s"]
+    before = {(ident, name): s for ident, name, s in first["threads"]}
+    by_role: dict[str, float] = {}
+    for ident, name, s in last["threads"]:
+        s0 = before.get((ident, name), 0.0)
+        role = thread_role(name)     # a smaller reading: the ident reused
+        by_role[role] = by_role.get(role, 0.0) + (s - s0 if s >= s0 else s)
+    late = sorted(r["late"] for r in records if r["name"] == "host.tick"
+                  and first["start"] <= r["start"] <= last["start"])
+    return {"seconds": seconds, "cpu_s": cpu_s,
+            "cores_busy": cpu_s / seconds,
+            "user_s": last["user_s"] - first["user_s"],
+            "system_s": last["system_s"] - first["system_s"],
+            "minor_faults": last["minflt"] - first["minflt"],
+            "voluntary_switches": last["nvcsw"] - first["nvcsw"],
+            "involuntary_switches": last["nivcsw"] - first["nivcsw"],
+            "cpus": last["cpus"],
+            "switch_interval_s": last["switch_interval_s"],
+            "ticks": len(late),
+            "late_mean_ms": sum(late) / len(late) * 1e3 if late else None,
+            "late_p95_ms": (late[-(-95 * len(late) // 100) - 1] * 1e3
+                            if late else None),
+            "cpu_s_by_role": dict(sorted(by_role.items(),
+                                         key=lambda kv: -kv[1])),
+            "native_cpu_s": cpu_s - sum(by_role.values())}
 
 
 # [ProfilerSession, log_dir, t_start, records dropped before it]
@@ -223,6 +397,7 @@ def start_trace(log_dir: str) -> bool:
     is for. Falls back to the plain jax.profiler API otherwise."""
     try:
         jax.devices()                # backend init before the session
+        _join_probe()                # one that a capture just left behind
         t_start, sess = _now(), None
         try:
             from jax._src.lib import _profiler
@@ -233,6 +408,7 @@ def start_trace(log_dir: str) -> bool:
             opts.python_tracer_level = 0
             sess = _profiler.ProfilerSession(opts)
         _session[:] = [sess, log_dir, t_start, span_log_dropped()]
+        _start_probe()
         return True
     except Exception:
         return False
@@ -242,7 +418,8 @@ def stop_trace() -> dict | None:
     """End the capture and write it under its directory. Returns the
     capture's stage table (`stage_table` of what the log gained, an
     op being one `osd.op`) with `dropped`, the records the log lost
-    since the capture began (not 0: the table is short of them), or
+    since the capture began (not 0: the table is short of them), and
+    `host`, the host's ledger of the same stretch (`host_usage`), or
     None when no capture could be stopped."""
     sess, log_dir, t_start, dropped_before = _session
     _session[0] = None
@@ -253,11 +430,15 @@ def stop_trace() -> dict | None:
             jax.profiler.stop_trace()
     except Exception:
         return None
+    _join_probe()                    # its last `host.usage` is in
     records = span_log(since=t_start)
     ops = sum(1 for r in records if r["name"] == "osd.op")
     return {"dir": log_dir, "ops": ops,
             "dropped": span_log_dropped() - dropped_before,
-            "stages": stage_table(records, ops)}
+            "stages": stage_table((r for r in records
+                                   if not r["name"].startswith("host.")),
+                                  ops),
+            "host": host_usage(records)}
 
 
 @contextlib.contextmanager

@@ -180,8 +180,9 @@ class WorkloadEngine:
         client histograms into telemetry at interval cadence."""
         start = threading.Event()
         threads = [threading.Thread(target=self._run_tenant,
-                                    args=(st, start), daemon=True)
-                   for st in self.tenants.values()]
+                                    args=(st, start), daemon=True,
+                                    name=f"workload-tenant-{i}")
+                   for i, st in enumerate(self.tenants.values())]
         for th in threads:
             th.start()
         stop = threading.Event()
@@ -193,7 +194,8 @@ class WorkloadEngine:
                         tick()
                     except Exception:   # noqa: BLE001 — a tick racing
                         pass            # a dying daemon never kills IO
-            ticker = threading.Thread(target=_tick_loop, daemon=True)
+            ticker = threading.Thread(target=_tick_loop, daemon=True,
+                                      name="workload-ticker")
             ticker.start()
         self._t0 = time.perf_counter()
         start.set()

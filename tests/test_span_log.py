@@ -25,9 +25,18 @@ A backfill (PR 34: the degraded pool's victim marked out under a live
 session) leaves `recovery.reserve.wait` and `recovery.grant` with its
 children `.pull`, `.stage`, `.launch`, `.fetch`, `.push` and `.settle`,
 one case each, so that every `recovery.*` reader keeps a number.
+The host's ledger (PR 36): under a live session every record carries its
+thread's CPU time by the rule of `self`, its `tid` and its `parent`; a
+`detail=True` child is logged beside its parent's whole; one
+`trace-probe` thread lives exactly as long as a capture and logs
+`host.tick` (`late`) and two `host.usage` records; `stop_trace` answers
+with `cpu_s` by stage and a `host` block; a store commit shows its four
+parts; the cluster's threads carry their role in their name.
 """
 
 import os
+import re
+import threading
 import time
 
 import pytest
@@ -64,10 +73,12 @@ def session(tmp_path):
 
 
 def _mine(since: float) -> dict:
-    """name -> records logged since `since`, compiles left out."""
+    """name -> records logged since `since`; compiles, the probe's
+    `host.*` records and the samplers' own spans left out."""
     out: dict = {}
     for r in span_log(since=since):
-        if r["name"] != "xla.compile":
+        if r["name"] not in ("xla.compile", "profiler.sample") \
+                and not r["name"].startswith("host."):
             out.setdefault(r["name"], []).append(r)
     return out
 
@@ -254,6 +265,8 @@ class TestSpanLogUnits:
                                                     tmp_path):
         import collections
         monkeypatch.setattr(tracing, "_LOG", collections.deque(maxlen=4))
+        # the probe's records would count too (PR 36): held off here
+        monkeypatch.setattr(tracing, "_start_probe", lambda: None)
         before = tracing.span_log_dropped()
         assert start_trace(str(tmp_path / "cap"))
         for i in range(7):
@@ -271,6 +284,303 @@ class TestSpanLogUnits:
         with span("osd.op"):
             pass
         assert stop_trace()["dropped"] == 0
+
+
+
+# -- the host's ledger (PR 36) -------------------------------------------------
+
+def _burn(cpu_s: float) -> None:
+    """Spin until this thread has used `cpu_s` of CPU."""
+    end = time.thread_time() + cpu_s
+    while time.thread_time() < end:
+        pass
+
+
+def _retried(attempt, tries: int = 4):
+    """A case that reads the scheduler's timing: steady under `-n 6`
+    when any of a few attempts holds."""
+    for left in range(tries - 1, -1, -1):
+        try:
+            return attempt()
+        except AssertionError:
+            if not left:
+                raise
+            time.sleep(0.2)
+
+
+def _probe_threads() -> list:
+    return [t for t in threading.enumerate() if t.name == "trace-probe"]
+
+
+def _bare_session():
+    """A profiler session opened the way bench/run.py opens its own:
+    no `start_trace`."""
+    import jax
+    from jax._src.lib import _profiler
+    jax.devices()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    return _profiler.ProfilerSession(opts)
+
+
+def _wait_probe_gone(timeout: float) -> float:
+    """Seconds until no `trace-probe` thread is left (the timeout's
+    length if one still is)."""
+    t0 = time.perf_counter()
+    while _probe_threads() and time.perf_counter() - t0 < timeout:
+        time.sleep(0.001)
+    return time.perf_counter() - t0
+
+
+class TestCpuTidParentDetail:
+    def test_a_span_that_spins_reads_cpu_near_its_self_time(self, session):
+        t0 = time.perf_counter()
+        with span("unit.spin"):
+            _burn(0.02)
+        (r,) = _mine(t0)["unit.spin"]
+        assert 0.02 <= r["cpu"] <= r["self"] + 1e-3
+        assert r["cpu"] < 0.03
+
+    def test_a_span_that_sleeps_reads_next_to_no_cpu(self, session):
+        t0 = time.perf_counter()
+        with span("unit.sleep"):
+            time.sleep(0.02)
+        (r,) = _mine(t0)["unit.sleep"]
+        assert r["self"] >= 0.02 and 0.0 <= r["cpu"] < 0.002
+
+    def test_a_parent_s_cpu_is_its_own_less_its_children_s(self, session):
+        t0 = time.perf_counter()
+        c0 = time.thread_time()
+        with span("unit.parent"):
+            _burn(0.01)
+            with span("unit.child"):
+                _burn(0.02)
+        used = time.thread_time() - c0
+        got = _mine(t0)
+        (parent,), (child,) = got["unit.parent"], got["unit.child"]
+        assert 0.02 <= child["cpu"] < 0.03
+        assert 0.01 <= parent["cpu"] < 0.02
+        assert parent["cpu"] + child["cpu"] == pytest.approx(used, abs=2e-3)
+
+    def test_a_detail_child_is_logged_beside_its_parent_s_whole(self,
+                                                                session):
+        t0 = time.perf_counter()
+        with span("unit.whole"):
+            with span("unit.part", detail=True):
+                _burn(0.01)
+                with span("unit.inside"):
+                    _burn(0.005)
+            with span("unit.child"):
+                time.sleep(0.005)
+        got = _mine(t0)
+        (whole,), (part,) = got["unit.whole"], got["unit.part"]
+        (inside,), (child,) = got["unit.inside"], got["unit.child"]
+        assert part["detail"] is True and part["parent"] == "unit.whole"
+        assert "detail" not in whole and "detail" not in child
+        # the part took nothing from the whole: only the plain child did
+        assert whole["self"] == pytest.approx(whole["dur"] - child["dur"])
+        assert whole["cpu"] >= 0.015
+        # a detail span is a parent like any other
+        assert inside["parent"] == "unit.part"
+        assert part["self"] == pytest.approx(part["dur"] - inside["dur"])
+        assert 0.01 <= part["cpu"] < 0.015
+
+    def test_the_cpu_clock_is_read_once_for_boundaries_close_together(
+            self, session, monkeypatch):
+        reads = []
+
+        def clock():
+            reads.append(time.perf_counter())
+            return time.thread_time()
+        monkeypatch.setattr(tracing, "_cpu_now", clock)
+        monkeypatch.setattr(tracing, "_CPU_TRUST_S", 0.005)
+        time.sleep(0.006)                # past the last reading's trust
+        t0 = time.perf_counter()
+        with span("unit.a"):             # one reading serves all four
+            pass
+        with span("unit.b"):
+            pass
+        assert len(reads) == 1
+        time.sleep(0.006)
+        with span("unit.c"):
+            _burn(0.01)                  # entered past it, left past it
+        assert len(reads) == 3
+        got = _mine(t0)
+        (a,), (b,), (c,) = got["unit.a"], got["unit.b"], got["unit.c"]
+        # within the trust a boundary reads the wall time since
+        assert a["cpu"] == pytest.approx(a["dur"], abs=1e-5)
+        assert b["cpu"] == pytest.approx(b["dur"], abs=1e-5)
+        assert 0.01 <= c["cpu"] < 0.02
+        assert min(a["cpu"], b["cpu"]) >= 0.0
+
+    def test_tid_and_parent_across_two_threads(self, session):
+        t0 = time.perf_counter()
+        tids = {}
+
+        def work(key):
+            tids[key] = threading.get_ident()
+            with span(f"unit.outer.{key}"):
+                with span(f"unit.inner.{key}"):
+                    time.sleep(0.005)
+        workers = [threading.Thread(target=work, args=(k,), name=f"unit-{k}")
+                   for k in ("a", "b")]
+        for w in workers:
+            w.start()
+        for w in workers:
+            w.join()
+        got = _mine(t0)
+        for key in ("a", "b"):
+            (outer,), (inner,) = (got[f"unit.outer.{key}"],
+                                  got[f"unit.inner.{key}"])
+            assert outer["tid"] == inner["tid"] == tids[key]
+            assert outer["parent"] is None
+            assert inner["parent"] == f"unit.outer.{key}"
+        assert tids["a"] != threading.get_ident()
+
+    def test_record_wait_and_compiles_carry_the_keys_every_record_has(
+            self, session):
+        import jax
+        import jax.numpy as jnp
+        t0 = time.perf_counter()
+        record_wait("unit.wait", t0, 0.25, trace_id=9)
+        salt = float(time.time()) + 2.0
+        jax.jit(lambda x: x * salt)(jnp.arange(27)).block_until_ready()
+        (wait,) = _mine(t0)["unit.wait"]
+        assert wait["cpu"] == 0.0 and wait["parent"] is None
+        assert wait["tid"] == threading.get_ident()
+        compiles = [r for r in span_log(since=t0)
+                    if r["name"] == "xla.compile"]
+        assert compiles and all(r["cpu"] is None and "tid" in r
+                                for r in compiles)
+
+    def test_stage_table_sums_cpu_beside_self(self):
+        recs = [{"name": "a", "self": 0.3, "cpu": 0.1},
+                {"name": "a", "self": 0.1, "cpu": 0.05},
+                {"name": "xla.compile", "self": 1.0, "cpu": None},
+                {"name": "old", "self": 0.2}]
+        table = stage_table(recs, ops=2)
+        assert table["a"]["cpu_s"] == pytest.approx(0.15)
+        assert table["a"]["cpu_ms_per_op"] == pytest.approx(75.0)
+        assert table["a"]["self_ms_per_op"] == pytest.approx(200.0)
+        assert table["xla.compile"]["cpu_s"] == table["old"]["cpu_s"] == 0.0
+        assert stage_table(recs, ops=0)["a"]["cpu_ms_per_op"] is None
+
+
+class TestHostProbe:
+    def test_no_session_no_probe_and_no_host_record(self):
+        assert _wait_probe_gone(2.0) < 2.0
+        t0 = time.perf_counter()
+        with span("unit.off"):
+            time.sleep(0.03)
+        assert _probe_threads() == []
+        assert [r for r in span_log(since=t0)
+                if r["name"].startswith("host.")] == []
+
+    def test_a_bare_session_and_one_span_start_it_and_its_end_ends_it(self):
+        def attempt():
+            assert _wait_probe_gone(2.0) < 2.0
+            t0 = time.perf_counter()
+            sess = _bare_session()
+            try:
+                assert _probe_threads() == []    # nothing entered a span yet
+                with span("unit.first"):
+                    pass
+                assert len(_probe_threads()) == 1
+                time.sleep(0.1)
+                with span("unit.second"):        # and no second probe
+                    pass
+                assert len(_probe_threads()) == 1
+            finally:
+                sess.stop()
+            gone_after = _wait_probe_gone(1.0)
+            recs = [r for r in span_log(since=t0)
+                    if r["name"].startswith("host.")]
+            names = [r["name"] for r in recs]
+            assert names[0] == names[-1] == "host.usage"
+            assert names.count("host.usage") == 2
+            ticks = [r for r in recs if r["name"] == "host.tick"]
+            assert 3 <= len(ticks) <= 11
+            assert all(r["late"] >= 0.0 and r["dur"] == r["self"] == 0.0
+                       and r["cpu"] == 0.0 for r in ticks)
+            first, last = recs[0], recs[-1]
+            assert first["start"] <= ticks[0]["start"]
+            assert ticks[-1]["start"] <= last["start"]
+            for key in ("process_s", "user_s", "system_s", "minflt",
+                        "nvcsw", "nivcsw"):
+                assert last[key] >= first[key], key
+            assert first["cpus"] >= 1 and first["switch_interval_s"] > 0
+            by_name = {name: s for _, name, s in last["threads"]}
+            assert by_name["MainThread"] > 0 and "trace-probe" in by_name
+            assert gone_after < 0.05
+        _retried(attempt)
+
+    def test_spinning_threads_make_the_probe_late(self):
+        """What a thread that becomes runnable pays to take the GIL
+        back: well above the idle floor (~0.1-0.4 ms here) once eight
+        threads spin in the interpreter."""
+        def attempt():
+            assert _wait_probe_gone(2.0) < 2.0
+            stop = threading.Event()
+
+            def spin():
+                while not stop.is_set():
+                    pass
+            spinners = [threading.Thread(target=spin, name=f"unit-spin-{i}",
+                                         daemon=True) for i in range(8)]
+            t0 = time.perf_counter()
+            sess = _bare_session()
+            try:
+                with span("unit.kick"):
+                    pass
+                for s in spinners:
+                    s.start()
+                time.sleep(0.5)
+            finally:
+                sess.stop()
+                _wait_probe_gone(2.0)    # its last record sees them live
+                stop.set()
+                for s in spinners:
+                    s.join()
+            host = tracing.host_usage(span_log(since=t0))
+            assert host["ticks"] >= 3
+            assert host["late_mean_ms"] >= 1.0
+            assert host["late_p95_ms"] >= host["late_mean_ms"] * 0.5
+            assert host["cpu_s_by_role"]["unit-spin"] > 0.1
+        _retried(attempt)
+
+    def test_stop_trace_answers_with_the_host_s_ledger(self, tmp_path):
+        assert start_trace(str(tmp_path / "cap"))
+        assert len(_probe_threads()) == 1        # start_trace starts it
+        with span("osd.op"):
+            _burn(0.05)
+        time.sleep(0.05)
+        got = stop_trace()
+        assert _probe_threads() == []            # and stop_trace waits for it
+        assert not any(n.startswith("host.") for n in got["stages"])
+        assert got["stages"]["osd.op"]["cpu_s"] >= 0.05
+        host = got["host"]
+        assert host["seconds"] >= 0.1 and host["ticks"] >= 3
+        assert host["cpu_s"] >= 0.05
+        assert host["cores_busy"] == pytest.approx(
+            host["cpu_s"] / host["seconds"])
+        assert host["user_s"] + host["system_s"] == pytest.approx(
+            host["cpu_s"], abs=0.02)
+        assert host["minor_faults"] >= 0
+        assert host["late_p95_ms"] >= 0 and host["late_mean_ms"] >= 0
+        assert host["cpu_s_by_role"]["MainThread"] >= 0.05
+        assert sum(host["cpu_s_by_role"].values()) + host["native_cpu_s"] \
+            == pytest.approx(host["cpu_s"])
+
+    @pytest.mark.parametrize("name,role", [
+        ("osd.3-shard0", "shard"), ("msgr-osd.3-r0", "msgr-r"),
+        ("msgr-client.0-ack", "msgr-ack"), ("profiler-mon.1", "profiler"),
+        ("osd.11-hb", "hb"), ("mon.0-hb", "hb"),
+        ("osd.3-recover-build-5", "recover-build"),
+        ("bench-loop-7", "bench-loop"), ("MainThread", "MainThread"),
+        ("trace-probe", "trace-probe"), ("rados-aio_0", "rados-aio")])
+    def test_a_thread_s_role_is_its_name_less_daemon_and_digits(self, name,
+                                                                role):
+        assert tracing.thread_role(name) == role
 
 
 # -- live cluster ------------------------------------------------------------
@@ -399,6 +709,129 @@ class TestLiveSpanLog:
         assert got["stages"]["ecbackend.write.fanout"]["self_ms_per_op"] > 0
         again = admin_command(cluster.asok_path(d.name), "trace stop")
         assert again == {"stopped": False}
+
+    # -- the host's ledger on the served path (PR 36) --------------------------
+
+    def test_a_traced_write_shows_each_commit_s_parts(self, cluster, client,
+                                                      session):
+        t0 = time.perf_counter()
+        client.write({"parts": b"p" * 3000})
+        recs = span_log(since=t0)
+        commits = [r for r in recs if r["name"] == "store.commit"]
+        parts = [r for r in recs if r["name"].startswith("store.commit.")]
+        assert all(p["detail"] is True and p["parent"] == "store.commit"
+                   for p in parts)
+        four = {"store.commit.stage", "store.commit.pwrite",
+                "store.commit.csum", "store.commit.wal"}
+        with_bytes = inside_all = 0
+        for c in commits:
+            end = c["start"] + c["dur"]
+            inside = [p for p in parts if p["tid"] == c["tid"]
+                      and c["start"] <= p["start"]
+                      and p["start"] + p["dur"] <= end + 1e-9]
+            names = {p["name"] for p in inside}
+            inside_all += len(inside)
+            assert "store.commit.wal" in names
+            if names != {"store.commit.wal"}:    # a commit that holds bytes
+                assert names == four
+                with_bytes += 1
+            assert sum(p["dur"] for p in inside) <= c["dur"] + 1e-6
+            # the parts take nothing from the whole: what
+            # `store.apply_ms_per_op` sums is the commit's time
+            assert c["self"] == pytest.approx(c["dur"])
+            assert 0.0 <= c["cpu"] <= c["dur"] + 1e-3
+        # a row on each of the k+m shards (a loaded host may hold one
+        # peer for slow and ack without it)
+        assert with_bytes >= 2
+        assert inside_all == len(parts)          # and none outside a commit
+
+    def test_trace_stop_over_the_admin_socket_shows_cpu_and_the_host(
+            self, cluster, client, tmp_path):
+        from ceph_tpu.utils.admin_socket import admin_command
+        d = next(iter(cluster.osds.values()))
+        out = str(tmp_path / "asok-host")
+        assert admin_command(cluster.asok_path(d.name),
+                             f"trace start {out}")["started"]
+        for i in range(4):
+            client.write({f"by-operator-{i}": b"o" * 3000})
+        time.sleep(0.1)
+        got = admin_command(cluster.asok_path(d.name), "trace stop")
+        assert got["stopped"] is True and got["ops"] >= 4
+        for row in got["stages"].values():
+            assert {"count", "self_s", "self_ms_per_op", "cpu_s",
+                    "cpu_ms_per_op"} <= set(row)
+        assert got["stages"]["osd.op"]["cpu_s"] > 0
+        assert got["stages"]["store.commit.wal"]["self_s"] > 0
+        host = got["host"]
+        assert {"seconds", "cores_busy", "user_s", "system_s",
+                "minor_faults", "late_mean_ms", "late_p95_ms",
+                "cpu_s_by_role", "native_cpu_s"} <= set(host)
+        assert host["cores_busy"] > 0 and host["ticks"] >= 3
+        assert {"shard", "msgr-r", "profiler", "hb"} <= set(
+            host["cpu_s_by_role"])
+
+    def test_host_crc32c_native_is_in_perf_dump(self, cluster, client):
+        from ceph_tpu.utils.admin_socket import admin_command
+        d = next(iter(cluster.osds.values()))
+        try:
+            from ceph_tpu.native import lib
+            lib()
+            want = 1
+        except Exception:
+            want = 0
+        dump = admin_command(cluster.asok_path(d.name), "perf dump")
+        assert dump["kv"]["host_crc32c_native"] == want
+        schema = admin_command(cluster.asok_path(d.name), "perf schema")
+        assert schema["kv"]["host_crc32c_native"]["kind"] == "gauge"
+
+    def test_a_sampler_s_pass_is_a_span_and_tags_nothing(self, cluster,
+                                                         client, session):
+        from ceph_tpu.utils import profiler
+        samplers = {t.ident: t.name for t in threading.enumerate()
+                    if t.name.startswith("profiler-")}
+        assert samplers
+        t0, recs = time.perf_counter(), []
+        while not recs and time.perf_counter() - t0 < 5.0:
+            time.sleep(0.05)
+            recs = [r for r in span_log(since=t0)
+                    if r["name"] == "profiler.sample"]
+        assert recs and all(r["tid"] in samplers for r in recs)
+        assert all(r["parent"] is None and r["cpu"] >= 0 for r in recs)
+        assert is_span_declared("profiler.sample")
+        # it samples every thread but its own, and is no category of
+        # the profile it takes
+        assert not any(tid in profiler._SPAN_CATS for tid in samplers)
+        assert profiler.push_span(profiler.SAMPLE_SPAN) is False
+
+    def test_every_thread_of_the_cluster_carries_its_role(self, cluster,
+                                                          client):
+        """One convention (utils/tracing.thread_role): the daemon is
+        the dash-separated token with a dot, the role the others less
+        their trailing digits. No `Thread-N`; the benchmark's own rule
+        and its list of always-on planes hold to the program's names."""
+        from bench import host_usage
+
+        def of_the_program(t):
+            fn = getattr(t, "_target", None) or getattr(t, "function", None)
+            mod = getattr(fn, "__module__", None) or type(t).__module__
+            return (mod or "").startswith("ceph_tpu")
+        client.write({"threads": b"t" * 3000})
+        mine = [t for t in threading.enumerate() if of_the_program(t)]
+        assert len(mine) > 20
+        assert [t.name for t in mine
+                if re.match(r"Thread-\d+", t.name)] == []
+        roles = {tracing.thread_role(t.name) for t in mine}
+        assert {"shard", "msgr-r", "msgr-ack", "msgr-dispatch", "profiler",
+                "hb", "asok"} <= roles
+        assert not any("." in role for role in roles), roles
+        for t in threading.enumerate():
+            assert host_usage.role_of(t.name) == tracing.thread_role(t.name)
+        assert set(host_usage.PLANE_ROLES) <= roles
+        # every daemon has one of each plane
+        daemons = len(cluster.osds) + len(cluster.mons)
+        for role in host_usage.PLANE_ROLES:
+            assert sum(tracing.thread_role(t.name) == role
+                       for t in mine) == daemons, role
 
 
 # -- a partial overwrite (PR 31) ------------------------------------------------

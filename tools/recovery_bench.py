@@ -124,7 +124,7 @@ def main(argv=None) -> None:
         repl = {s: 2000 + s for s in lost}
 
     from ceph_tpu.utils.perf_counters import MetricsHistory, dump_delta
-    from ceph_tpu.utils.tracing import trace
+    from ceph_tpu.utils.tracing import span, trace
     if args.telemetry_off:
         import ceph_tpu.utils.perf_counters as _pcmod
         _pcmod.LHIST_ENABLED = False
@@ -182,14 +182,13 @@ def main(argv=None) -> None:
     # counters, and this block (schema pinned by test_bench_schema)
     from ceph_tpu.utils.flight_recorder import (FlightRecorder,
                                                 TraceContext, activate,
-                                                new_trace_id,
-                                                trace_span)
+                                                new_trace_id)
     flight = FlightRecorder("recovery_bench")
     trace_ctx = TraceContext(new_trace_id(), 0, sampled=True)
 
     def traced_recover():
         with activate(trace_ctx, flight):
-            with trace_span("osd.recovery_round"):
+            with span("osd.recovery_round"):
                 return timed_recover()
 
     t0 = time.perf_counter()
