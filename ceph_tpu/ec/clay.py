@@ -481,6 +481,16 @@ class Clay(ErasureCode):
         D, planes = self.repair_plan_matrix(erasures[0], survivors)
         return ("clayrng", D.tobytes(), D.shape, tuple(planes))
 
+    def vector_encode_matrix(self):
+        """Encode is "decode the parities" from the k data chunks: the
+        (m*q^t, k*q^t) matrix `encode_chunks` applies, for the served
+        write's one launch."""
+        if self.ref_oracle:
+            return None
+        D, _ = self._affine_decode(tuple(range(self.k, self.k + self.m)),
+                                   tuple(range(self.k)))
+        return D, self.sub_chunk_count
+
     # -- data paths ---------------------------------------------------------
 
     def _apply(self, D: np.ndarray, stacked: np.ndarray) -> np.ndarray:

@@ -200,6 +200,15 @@ class ErasureCode(abc.ABC):
             full[:, tr, :] = deltas[:, ti, :]
         return np.asarray(self.encode_chunks(full))
 
+    def vector_encode_matrix(self):
+        """Optional static encode of a vector code: (D, P), where P is
+        the sub-chunk count and D the (m*P, k*P) GF(2^8) matrix whose
+        product with the k data rows viewed as (k*P, L/P) sub-chunks is
+        the m parity rows viewed the same way. The served write fuses
+        it with the rows' crcs into one launch. None for a code without
+        one (RS takes its own (m, k) matrix there)."""
+        return None
+
     def range_batch_decoder(self, erasures: Sequence[int],
                             survivors: Sequence[int]):
         """Optional sub-chunk fast path: a jitted fn mapping the

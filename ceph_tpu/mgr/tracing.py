@@ -86,6 +86,7 @@ CATEGORY_OF = {
     "recovery.fetch": "encode",
     "recovery.push": "store",
     "recovery.settle": "store",
+    "recovery.serve_ranges": "store",
     "store.apply": "store",
     "store.commit": "store",
     "store.commit.stage": "store",

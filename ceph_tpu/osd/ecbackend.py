@@ -137,6 +137,14 @@ def ec_perf_counters():
             .add_u64_counter("recover_wire_bytes",
                              "helper bytes pulled for recovery (the "
                              "repair-bytes-on-wire numerator)")
+            .add_u64_counter("recover_range_frames_served",
+                             "sub-chunk pull frames this daemon served "
+                             "as a source (`readv_ranges_host`)")
+            .add_u64_counter("recover_range_bytes_served",
+                             "range bytes those frames shipped")
+            .add_u64_counter("recover_range_verify_bytes",
+                             "full-row bytes checksummed at the source "
+                             "against their hinfo for those frames")
             .add_time_avg("encode_time", "write-path encode wall time",
                           hist=True)
             .add_time_avg("decode_time", "read-path decode wall time",
@@ -323,33 +331,76 @@ class ECBackend(PGBackend):
     @staticmethod
     @_functools.lru_cache(maxsize=256)
     def _fused_write_fn(matrix_bytes: bytes, m: int, k: int, sl: int,
-                        bucket: int):
+                        bucket: int, planes: int = 1):
         """Process-wide cache (like rs_kernels._make_jitted): every
         PG backend with the same coder geometry shares ONE compiled
         program per (shard len, batch bucket) — a per-backend cache
-        would recompile the identical HLO once per PG per daemon."""
+        would recompile the identical HLO once per PG per daemon.
+        `planes` > 1 is a vector code's encode (`vector_encode_matrix`):
+        the (m*planes, k*planes) matrix over the data rows viewed as
+        (k*planes, sl/planes) sub-chunks, the parity viewed back as m
+        rows."""
         import jax
         import jax.numpy as jnp
 
         from ..csum.kernels import crc32c_blocks
         from ..ops.rs_kernels import make_encoder
-        matrix = np.frombuffer(matrix_bytes,
-                               dtype=np.uint8).reshape(m, k)
+        matrix = np.frombuffer(matrix_bytes, dtype=np.uint8).reshape(
+            m * planes, k * planes)
         enc = make_encoder(matrix, bucket_batch=False)
 
         def fused(d):                # (bucket, k, sl) u8
-            parity = enc(d)          # (bucket, m, sl)
+            if planes == 1:
+                parity = enc(d)      # (bucket, m, sl)
+            else:
+                parity = enc(d.reshape(bucket, k * planes, sl // planes)
+                             ).reshape(bucket, m, sl)
             rows = jnp.concatenate([d, parity], axis=1)
             crcs = crc32c_blocks(rows, init=0xFFFFFFFF, xorout=0)
             return parity, crcs      # (bucket, n) u32
         return jax.jit(fused)
 
+    @_functools.cached_property
+    def _write_matrix(self) -> tuple[bytes, int] | None:
+        """(matrix bytes, sub-chunks) of the one-launch write: RS's
+        (m, k) matrix over whole rows, or a vector code's encode over
+        its sub-chunks; None for a coder with neither."""
+        from ..ec.rs import ReedSolomon
+        if isinstance(self.coder, ReedSolomon):
+            mat = np.ascontiguousarray(self.coder.matrix, dtype=np.uint8)
+            return mat.tobytes(), 1
+        got = self.coder.vector_encode_matrix()
+        if got is None:
+            return None
+        return np.ascontiguousarray(got[0], np.uint8).tobytes(), int(got[1])
+
+    def _fused_write_program(self, sl: int, bucket: int):
+        """The one-launch write program for (shard len, bucket), or None
+        where the coder has no static encode at this length. Counts the
+        launch and the program cache's hit or miss."""
+        if self._write_matrix is None or sl % self._write_matrix[1]:
+            return None
+        mat, planes = self._write_matrix
+        # RS leaves `planes` out: its cache key is the one it always had
+        args = (mat, self.m, self.k, sl, bucket) + (
+            (planes,) if planes > 1 else ())
+        cache = self._fused_write_fn
+        ci0 = cache.cache_info()
+        fn = cache(*args)
+        ci1 = cache.cache_info()
+        self.perf.inc_many(
+            (("fused_write_launches", 1),
+             ("program_cache_hits", ci1.hits - ci0.hits),
+             ("program_cache_misses", ci1.misses - ci0.misses)))
+        return fn
+
     def _encode_shards_with_crcs(self, data_shards: np.ndarray,
                                  sl: int) -> tuple[np.ndarray,
                                                    np.ndarray]:
         """(B, k, sl) data rows -> (slot-ordered (B, n, sl) shards,
-        slot-ordered (B, n) hinfo CRCs). For static-matrix coders the
-        encode AND both CRC sets run as ONE fused, B-bucketed device
+        slot-ordered (B, n) hinfo CRCs). For static-matrix coders (RS,
+        and a vector code's sub-chunk encode: `_fused_write_program`)
+        the encode AND both CRC sets run as ONE fused, B-bucketed device
         launch with a single host fetch — the write path's r01 shape
         dispatched encode + CRC as separate launches with host
         round-trips between (the wire tier pays that per client op).
@@ -398,21 +449,12 @@ class ECBackend(PGBackend):
                         crcs[:, self._perm] = dense_crcs
                         return shards, crcs
                 # rc != 0: fall through to the fused device launch
-        if isinstance(self.coder, ReedSolomon):
+        from ..ops.rs_kernels import pow2_bucket
+        bucket = pow2_bucket(B)
+        fn = self._fused_write_program(sl, bucket)
+        if fn is not None:
             import jax
-            from ..ops.rs_kernels import pow2_bucket
-            bucket = pow2_bucket(B)
-            mat = np.ascontiguousarray(self.coder.matrix,
-                                       dtype=np.uint8)
-            ci0 = self._fused_write_fn.cache_info()
-            fn = self._fused_write_fn(mat.tobytes(), self.m, self.k,
-                                      sl, bucket)
-            ci1 = self._fused_write_fn.cache_info()
-            self.perf.inc_many(
-                (("fused_write_launches", 1),
-                 ("encode_bytes", int(data_shards.size)),
-                 ("program_cache_hits", ci1.hits - ci0.hits),
-                 ("program_cache_misses", ci1.misses - ci0.misses)))
+            self.perf.inc("encode_bytes", int(data_shards.size))
             # stage + launch is the host's own work; fetch is the
             # wait for the device (and for the ops queued before it)
             with span("ecbackend.write.encode", counters=self.perf,
@@ -2177,6 +2219,14 @@ RECOVERY_FETCH_BYTES = 8 << 20
 RECOVERY_STAGE_BYTES = 128 << 20
 
 
+def _fetch_frames(nb: int, rl: int) -> list[tuple[int, int]]:
+    """(first row, rows) of each readv frame that pulls `nb` objects'
+    rows of `rl` bytes from one helper: chunks of RECOVERY_FETCH_BYTES,
+    so that one source never serializes a giant frame."""
+    per = max(1, RECOVERY_FETCH_BYTES // max(1, rl))
+    return [(c0, min(per, nb - c0)) for c0 in range(0, nb, per)]
+
+
 @_functools.lru_cache(maxsize=64)
 def _host_encoder_handle(matrix_bytes: bytes, k: int, m: int):
     """Process-wide native RS encoder per coding matrix (the same
@@ -2261,7 +2311,7 @@ def _rows_crc32c(rows: np.ndarray) -> np.ndarray:
 
 
 def readv_ranges_host(store, cid: str, names: list[str], length: int,
-                      ranges, attr_key: str | None
+                      ranges, attr_key: str | None, perf=None
                       ) -> tuple[np.ndarray, np.ndarray | None,
                                  list[int]]:
     """Serve a ranged shard pull from a LOCAL store — the source half
@@ -2278,46 +2328,71 @@ def readv_ranges_host(store, cid: str, names: list[str], length: int,
 
     Returns (rows (B, rl) uint8, range CRCs (B,) uint32 | None,
     indices of rows whose FULL shard failed its hinfo — their range
-    bytes ship anyway and the receiver plans around them)."""
+    bytes ship anyway and the receiver plans around them).
+
+    Spans: `recovery.serve_ranges` (nbytes: the range bytes shipped)
+    round the whole, with the parts beside it: `.read` (the store
+    reads), `.verify` (the full rows' crcs against hinfo) and `.slice`
+    (the ranges copied out and their crcs). `perf`, the serving
+    daemon's `ec` logger, counts the frame, the bytes it ships and the
+    bytes it checksums whole."""
     ranges = [(int(o), int(ln)) for o, ln in ranges]
     rl = sum(ln for _o, ln in ranges)
+    B = len(names)
+    with span("recovery.serve_ranges", nbytes=B * rl):
+        served = _serve_ranges(store, cid, names, length, ranges, rl,
+                               attr_key)
+    if perf is not None:
+        perf.inc_many((("recover_range_frames_served", 1),
+                       ("recover_range_bytes_served", B * rl),
+                       ("recover_range_verify_bytes",
+                        B * length if attr_key is not None else 0)))
+    return served
+
+
+def _serve_ranges(store, cid: str, names: list[str], length: int,
+                  ranges: list, rl: int, attr_key: str | None):
     B = len(names)
     rows = np.empty((B, rl), dtype=np.uint8)
     bad: list[int] = []
     if attr_key is not None:
         full = np.empty((B, length), dtype=np.uint8)
-        for i, name in enumerate(names):
-            arr = store.read(cid, name)
-            if len(arr) != length:
-                # a stale/partial shard must fail LOUDLY — zero-
-                # filling would hand the decoder garbage (the readv
-                # contract)
-                raise ValueError(
-                    f"readv_ranges: {name!r} is {len(arr)} bytes, "
-                    f"expected {length}")
-            full[i] = arr
-        crcs = _rows_crc32c(full)
-        for i, name in enumerate(names):
-            hinfo = HashInfo.from_bytes(
-                store.getattr(cid, name, attr_key))
-            if int(crcs[i]) != hinfo.get_chunk_hash(0):
-                bad.append(i)
-        at = 0
-        for off, ln in ranges:
-            rows[:, at:at + ln] = full[:, off:off + ln]
-            at += ln
-        range_crcs = _rows_crc32c(rows)
+        with span("recovery.serve_ranges.read", detail=True):
+            for i, name in enumerate(names):
+                arr = store.read(cid, name)
+                if len(arr) != length:
+                    # a stale/partial shard must fail LOUDLY — zero-
+                    # filling would hand the decoder garbage (the readv
+                    # contract)
+                    raise ValueError(
+                        f"readv_ranges: {name!r} is {len(arr)} bytes, "
+                        f"expected {length}")
+                full[i] = arr
+        with span("recovery.serve_ranges.verify", detail=True):
+            crcs = _rows_crc32c(full)
+            for i, name in enumerate(names):
+                hinfo = HashInfo.from_bytes(
+                    store.getattr(cid, name, attr_key))
+                if int(crcs[i]) != hinfo.get_chunk_hash(0):
+                    bad.append(i)
+        with span("recovery.serve_ranges.slice", detail=True):
+            at = 0
+            for off, ln in ranges:
+                rows[:, at:at + ln] = full[:, off:off + ln]
+                at += ln
+            range_crcs = _rows_crc32c(rows)
         return rows, range_crcs, bad
-    for i, name in enumerate(names):
-        at = 0
-        for off, ln in ranges:
-            got = store.read(cid, name, off, ln)
-            if len(got) != ln:
-                raise ValueError(
-                    f"readv_ranges: {name!r} range ({off},{ln}) "
-                    f"returned {len(got)} bytes")
-            rows[i, at:at + ln] = got
-            at += ln
+    with span("recovery.serve_ranges.read", detail=True):
+        for i, name in enumerate(names):
+            at = 0
+            for off, ln in ranges:
+                got = store.read(cid, name, off, ln)
+                if len(got) != ln:
+                    raise ValueError(
+                        f"readv_ranges: {name!r} range ({off},{ln}) "
+                        f"returned {len(got)} bytes")
+                rows[i, at:at + ln] = got
+                at += ln
     return rows, None, bad
 
 
@@ -2617,10 +2692,12 @@ class RecoveryRunner:
         member PG's lock, and a program takes seconds to compile)."""
         import jax
         seen = set()
-        for bi, (kind, proto, sl, _pairs) in enumerate(self._batches):
+        for bi, (kind, proto, sl, pairs) in enumerate(self._batches):
             if kind != "fused":
                 continue
             rl, _ranges = proto.row_ranges(sl)
+            if proto.range_planes is not None:
+                self._prepare_range_sources(pairs, sl, rl, seen)
             shape = (self._buckets[bi], len(proto.helper), rl)
             program = self._program(proto)
             if (id(program), shape) in seen:
@@ -2630,6 +2707,26 @@ class RecoveryRunner:
             jax.block_until_ready(
                 program(stack) if self._host_crc
                 else program(stack, np.zeros(shape[0], np.uint32)))
+
+    def _prepare_range_sources(self, pairs, sl: int, rl: int,
+                               seen: set) -> None:
+        """The sources of a range plan checksum each frame's full rows
+        and its shipped ranges on the device (`readv_ranges_host`), at
+        the frame's row count: the frames `_stage` sends for each
+        plan's run of the batch (`_segments`, `_fetch_frames`). Those
+        crc programs are built here too, where every daemon of an
+        in-process cluster finds them, so that no frame of a short tail
+        compiles."""
+        if _host_crc_available():
+            return
+        from ..ops.rs_kernels import pow2_bucket
+        for _plan, _r0, names in self._segments(pairs):
+            for _c0, rows in _fetch_frames(len(names), rl):
+                for width in (sl, rl):
+                    key = ("range-source", pow2_bucket(rows), width)
+                    if key not in seen:
+                        seen.add(key)
+                        _rows_crc32c(np.zeros((rows, width), np.uint8))
 
     def run(self) -> None:
         while self.step():
@@ -2801,9 +2898,10 @@ class RecoveryRunner:
 
     @staticmethod
     def _segments(live) -> list[tuple]:
-        """Contiguous per-plan runs of a batch: (plan, row0, names)."""
+        """Contiguous per-plan runs of a batch's (plan, name, ...)
+        entries: (plan, row0, names)."""
         segs: list[tuple] = []
-        for ri, (plan, name, _v) in enumerate(live):
+        for ri, (plan, name, *_rest) in enumerate(live):
             if not segs or segs[-1][0] is not plan:
                 segs.append((plan, ri, []))
             segs[-1][2].append(name)
@@ -2833,13 +2931,11 @@ class RecoveryRunner:
             for hi, s in enumerate(plan.helper):
                 st = plan.be._store(s)
                 cid = shard_cid(plan.be.pg, s)
-                # chunk by the fetch byte budget so one source OSD
-                # never serializes a giant frame
-                per = max(1, RECOVERY_FETCH_BYTES // max(1, rl))
+                frames = _fetch_frames(nb, rl)
                 if ranges is not None:
                     subr = getattr(st, "readv_ranges_submit", None)
-                    for c0 in range(0, nb, per):
-                        cnames = names[c0:c0 + per]
+                    for c0, rows_n in frames:
+                        cnames = names[c0:c0 + rows_n]
                         if subr is not None:
                             waits.append(
                                 (subr(cid, cnames, sl, ranges,
@@ -2848,7 +2944,8 @@ class RecoveryRunner:
                             continue
                         rows, crcs, bad = readv_ranges_host(
                             st, cid, cnames, sl, ranges,
-                            HINFO_KEY if verify else None)
+                            HINFO_KEY if verify else None,
+                            perf=self.perf)
                         stack[r0 + c0:r0 + c0 + len(cnames), hi, :] \
                             = rows
                         if crcs is not None:
@@ -2860,8 +2957,8 @@ class RecoveryRunner:
                     continue
                 subv = getattr(st, "readv_submit", None)
                 if subv is not None:
-                    for c0 in range(0, nb, per):
-                        cnames = names[c0:c0 + per]
+                    for c0, rows_n in frames:
+                        cnames = names[c0:c0 + rows_n]
                         waits.append(
                             (subv(cid, cnames, sl,
                                   HINFO_KEY if verify else None),

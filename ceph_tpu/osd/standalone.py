@@ -122,6 +122,8 @@ declare_span_names(
     "recovery.reserve.wait", "recovery.grant", "recovery.pull",
     "recovery.stage", "recovery.launch", "recovery.fetch",
     "recovery.push", "recovery.settle",
+    "recovery.serve_ranges", "recovery.serve_ranges.read",
+    "recovery.serve_ranges.verify", "recovery.serve_ranges.slice",
 )
 
 
@@ -2089,7 +2091,8 @@ class OSDDaemon:
             ranges = d.list(lambda dd: (dd.i64(), dd.i64()))
             names = d.list(Decoder.string)
             rows, crcs, bad = readv_ranges_host(
-                st, cid, names, length, ranges, attr_key or None)
+                st, cid, names, length, ranges, attr_key or None,
+                perf=self.ec_perf)
             e = Encoder()
             e.blob(rows.tobytes())
             e.list([int(c) for c in crcs] if crcs is not None else [],

@@ -1080,6 +1080,90 @@ class TestBackfillSpans:
         assert all(w["dur"] >= 0 and w["self"] == w["dur"] for w in waits)
 
 
+@pytest.fixture(scope="module")
+def clay_serve_log(tmp_path_factory):
+    """The span records and `ec` counters of one Clay backfill under a
+    live session: a k=4 m=2 d=5 pool on 7 OSDs, a non-primary marked
+    down and out, the lost rows rebuilt from ranges of d helpers."""
+    from ceph_tpu.osd.standalone import StandaloneCluster
+    c = StandaloneCluster(n_osds=7, pg_num=2, hb_interval=0.5,
+                          hb_grace=30.0, down_out_interval=600.0,
+                          profile="plugin=clay k=4 m=2 d=5")
+    try:
+        c.wait_for_clean(timeout=40)
+        cl = c.client(hedge_delay_ms=-1)
+        cl.trace_sample_rate = 0.0
+        acting = [cl.osdmap.pg_to_up_acting_osds(1, ps)[2] for ps in (0, 1)]
+        primaries = {a[0] for a in acting}
+        victim = next(o for a in acting for o in a[1:4]
+                      if o not in primaries)
+        objs = {f"clay-{i}": bytes([i + 1]) * 4096 for i in range(6)}
+        cl.write(objs)
+        c.kill_osd(victim)
+        cl.osd_down(victim)
+        c._wait(lambda: all(not d.osdmap.osd_up[victim]
+                            for d in c.osds.values()
+                            if not d._stop.is_set()), 15, "maps show down")
+        keys = ("recover_range_frames_served", "recover_range_bytes_served",
+                "recover_range_verify_bytes", "recovered_objects")
+
+        def ec():
+            return {k: sum(int(d.ec_perf.get(k)) for d in c.osds.values()
+                           if not d._stop.is_set()) for k in keys}
+        before = ec()
+        assert start_trace(str(tmp_path_factory.mktemp("clay-trace")))
+        try:
+            t0 = time.perf_counter()
+            cl.osd_out(victim)
+            c._wait(lambda: all(d.osdmap.osd_weight[victim] == 0
+                                for d in c.osds.values()
+                                if not d._stop.is_set()), 15,
+                    "maps show out")
+            c.wait_for_clean(timeout=60)
+        finally:
+            table = stop_trace()
+        rise = {k: v - before[k] for k, v in ec().items()}
+        for name, want in objs.items():
+            assert cl.read(name) == want
+        yield _mine(t0), table, rise
+    finally:
+        c.shutdown()
+
+
+class TestClaySourceSpans:
+    """The source half of a sub-chunk pull: `recovery.serve_ranges` round
+    each frame a helper serves, its parts beside it, and the three `ec`
+    counters of what the frames shipped and checked."""
+
+    @pytest.mark.parametrize("part", ["read", "verify", "slice"])
+    def test_a_serve_holds_its_part(self, clay_serve_log, part):
+        got, table, _ = clay_serve_log
+        name = f"recovery.serve_ranges.{part}"
+        assert is_span_declared(name)
+        assert name in table["stages"]
+        serves = got["recovery.serve_ranges"]
+        for rec in got[name]:
+            assert rec.get("detail") is True
+            assert any(s["start"] <= rec["start"] and rec["start"]
+                       + rec["dur"] <= s["start"] + s["dur"] + 1e-6
+                       for s in serves)
+
+    def test_the_serves_carry_the_bytes_they_shipped(self, clay_serve_log):
+        got, table, rise = clay_serve_log
+        serves = got["recovery.serve_ranges"]
+        assert is_span_declared("recovery.serve_ranges")
+        assert "recovery.serve_ranges" in table["stages"]
+        assert len(serves) == rise["recover_range_frames_served"] >= 5
+        assert sum(s["nbytes"] for s in serves) \
+            == rise["recover_range_bytes_served"]
+        # d = 5 helpers ship half a 1024-byte row (q = 2) an object, and
+        # check the whole row first
+        assert rise["recover_range_bytes_served"] \
+            == rise["recovered_objects"] * 5 * 512 > 0
+        assert rise["recover_range_verify_bytes"] \
+            == 2 * rise["recover_range_bytes_served"]
+
+
 @pytest.fixture
 def served_kinds(monkeypatch):
     """kind -> store sub-ops the OSDs served since the fixture."""

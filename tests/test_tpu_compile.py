@@ -190,3 +190,41 @@ def test_fused_delta_compiles_at_one_4k_block(one_chip, coder, column):
     out = fn.lower(_struct(one_chip, (1, 1, 4096), np.uint8)).compile()
     assert _no_gather(out)
     assert out.memory_analysis().temp_size_in_bytes < 1 * MiB
+
+
+# -- a vector code: Clay k=8 m=4 d=11 (rados_clay_k8m4d11_13osd_1out) ------
+
+@pytest.fixture(scope="module")
+def clay():
+    from ceph_tpu.ec.registry import factory
+    return factory("plugin=clay k=8 m=4 d=11")
+
+
+def test_the_fused_clay_write_compiles_at_bucket_1(one_chip, clay):
+    """What a served write of the Clay pool launches: the 256 x 512
+    encode over the (64 x 8, 8 KiB) sub-chunk view of the data rows on
+    the dense lowering, then the crcs of all 12 rows."""
+    from ceph_tpu.osd.ecbackend import ECBackend
+    D, planes = clay.vector_encode_matrix()
+    assert D.shape == (256, 512) and planes == 64
+    fn = ECBackend._fused_write_fn(
+        np.ascontiguousarray(D, np.uint8).tobytes(), 4, 8, SHARD, 1, planes)
+    out = _compile(fn, one_chip, ((1, 8, SHARD), np.uint8))
+    assert _no_gather(out)
+    assert out.memory_analysis().temp_size_in_bytes < 512 * MiB
+
+
+def test_the_clay_range_repair_compiles_at_the_wire_tiers_grant(
+        one_chip, clay):
+    """One grant of the Clay pool's backfill: 24 MiB of helper bytes is
+    16 objects of 11 quarter rows; the 64 x 176 repair matrix of lost
+    slot 1, whose 16 repair planes are 16 ranges of 8 KiB."""
+    from ceph_tpu.osd.ecbackend import _build_recover_program
+    lost = 1
+    helper = tuple(i for i in range(12) if i != lost)
+    fn = _build_recover_program(clay.range_batch_decoder((lost,), helper),
+                                verify=True, host_crc=False)
+    out = _compile(fn, one_chip, ((16, 11, SHARD // 4), np.uint8),
+                   ((16,), np.uint32))
+    assert _no_gather(out)
+    assert out.memory_analysis().temp_size_in_bytes < 1024 * MiB
