@@ -50,10 +50,13 @@ prove remount+fsck come back clean on either side of the swap.
 
 from __future__ import annotations
 
+import ctypes
 import heapq
 import os
 import struct
 import threading
+
+import numpy as np
 
 from ..utils.perf_counters import PerfCountersBuilder, g_perf_counters
 from .interface import (KeyValueDB, KVTransaction, combine_key,
@@ -76,37 +79,49 @@ class TinDBCorruption(IOError):
 
 _crc_impl = None
 
-#: which crc the stores and the WAL seal with (`perf dump` shows the
-#: process-wide collection's loggers): the fallback is pure Python and
-#: was picked silently
-_kv_perf = g_perf_counters.add(
+#: which crc the stores and the WAL seal with, and what TinStore's
+#: commits stage (`perf dump` shows the process-wide collection's
+#: loggers): the fallback is pure Python and was picked silently
+kv_perf = g_perf_counters.add(
     PerfCountersBuilder("kv")
     .add_u64("host_crc32c_native",
              "host_crc32c runs the native library (1) or the pure-Python "
              "fallback (0; also until the first crc picks)")
+    .add_u64_counter("store_objects_staged",
+                     "TinStore objects whose final bytes a commit wrote to "
+                     "a fresh extent (one a touched object a transaction)")
+    .add_u64_counter("store_byte_ops_folded",
+                     "TinStore write / xor / truncate ops that wrote no "
+                     "extent of their own (folded into their object's one "
+                     "staging, or a no-op)")
     .create_perf_counters())
 
 
 def host_crc32c(data, seed: int = 0xFFFFFFFF) -> int:
     """Raw-register crc32c (seed 0xFFFFFFFF, no final inversion) —
     native C fast path, ceph_tpu.csum pure-python fallback. Chainable
-    through `seed` for incremental seals."""
+    through `seed` for incremental seals. A C-contiguous numpy array is
+    read in place by the native path (no copy of its bytes)."""
     global _crc_impl
     if _crc_impl is None:
         try:
             from ..native import lib
             L = lib()
 
-            def _crc_impl(b, s, _L=L):
+            def _crc_impl(b, s, _L=L, _p=ctypes.c_char_p):
+                if isinstance(b, np.ndarray) and b.flags.c_contiguous:
+                    return int(_L.ec_crc32c(s, b.ctypes.data_as(_p),
+                                            b.nbytes))
+                b = bytes(b)
                 return int(_L.ec_crc32c(s, b, len(b)))
         except Exception:          # no toolchain: correctness over speed
             L = None
             from ..csum.reference import ceph_crc32c
 
             def _crc_impl(b, s):
-                return int(ceph_crc32c(s, b))
-        _kv_perf.set("host_crc32c_native", int(L is not None))
-    return _crc_impl(bytes(data), seed)
+                return int(ceph_crc32c(s, bytes(b)))
+        kv_perf.set("host_crc32c_native", int(L is not None))
+    return _crc_impl(data, seed)
 
 
 # -- WAL record framing (shared scan used by TinDB and legacy replay) ---------

@@ -15,7 +15,9 @@ ref: src/os/ObjectStore.h Transaction/queue_transaction):
   first-fit free list with coalescing — the BitmapAllocator role).
   Data writes are COPY-ON-WRITE: a write stages the object's new
   bytes into a FRESH extent (never over live data), so torn data
-  writes can't damage committed state. The freelist is not persisted;
+  writes can't damage committed state. A transaction folds the byte
+  ops of each object it touches and stages the object once, on its
+  final bytes. The freelist is not persisted;
   it is derived at mount from the live extent map (and fsck audits
   the same derivation for overlaps/bounds).
 * KV METADATA PLANE. All metadata — collections, object records
@@ -107,7 +109,7 @@ from collections.abc import Mapping
 import numpy as np
 
 from ..kv import TinDB, TinDBCorruption, host_crc32c
-from ..kv.tindb import Segment, scan_wal, write_segment
+from ..kv.tindb import Segment, kv_perf, scan_wal, write_segment
 from ..utils.encoding import Decoder, Encoder, EncodingError
 from ..utils.tracing import span as _span
 from .memstore import MemStore, Transaction, _Object  # noqa: F401 — _Object
@@ -124,9 +126,9 @@ class TinStoreCorruption(IOError):
 
 def _crc32c(data) -> int:
     """Whole-buffer crc32c, raw-register convention (seed 0xFFFFFFFF,
-    no final inversion) — shared with the KV plane's seals."""
-    b = data.tobytes() if isinstance(data, np.ndarray) else bytes(data)
-    return host_crc32c(b)
+    no final inversion) — shared with the KV plane's seals. An array is
+    read where it lies (no `tobytes` copy)."""
+    return host_crc32c(data)
 
 
 # -- wire transaction (de)serialization --------------------------------------
@@ -975,9 +977,9 @@ class TinStore:
     def queue_transaction(self, txn: Transaction) -> None:
         # the span inside the lock: the device write + WAL append, not
         # the wait for another transaction. Its parts are detail spans
-        # beside it (`.stage` the read-modify copy, `tobytes` and
-        # compression, `.pwrite`, `.csum`, `.wal`): the commit's own
-        # self time stays whole for whoever sums it
+        # beside it (`.stage` the read-modify fold and compression,
+        # `.pwrite`, `.csum`, `.wal`): the commit's own self time stays
+        # whole for whoever sums it
         with self._lock, _span("store.commit"):
             self._alive()
             self._validate(txn)
@@ -985,64 +987,56 @@ class TinStore:
                 # injection point BEFORE any staging: an injected
                 # ENOSPC aborts with nothing allocated or written
                 self._fault("txn.apply")
+            # the byte ops (write, xor, truncate) of one object fold in
+            # order into one buffer this txn owns; once every op is
+            # seen each touched object is staged ONCE, on its final
+            # bytes, and its setext stands where its last byte op stood
+            # (an earlier setext would only be overwritten)
             staged: dict[tuple[str, str], np.ndarray] = {}
+            slots: dict[tuple[str, str], int] = {}
             # objects removed EARLIER IN THIS TXN: a later write must
             # start from empty, not resurrect the pre-txn bytes
             # (MemStore applies ops in order; staging must match)
             gone: set[tuple[str, str]] = set()
             gone_colls: set[str] = set()
             new_extents: list[tuple[int, int]] = []
-            meta_ops: list[tuple] = []
+            meta_ops: list[tuple | None] = []
+            byte_ops = 0
             try:
                 for op in txn.ops:
                     kind = op[0]
+                    if kind in ("write", "xor", "truncate"):
+                        byte_ops += 1
+                        key = (op[1], op[2])
+                        if self._fold(op, staged, gone, gone_colls):
+                            if key in slots:
+                                meta_ops[slots[key]] = None
+                            slots[key] = len(meta_ops)
+                            meta_ops.append(None)
+                        continue
+                    dropped = ()
                     if kind == "remove":
                         gone.add((op[1], op[2]))
-                        staged.pop((op[1], op[2]), None)
+                        dropped = [(op[1], op[2])]
                     elif kind == "rmcoll":
                         # stays in gone_colls even if re-created later
                         # in the txn: the fresh collection is EMPTY,
                         # pre-txn objects must not show through it
                         gone_colls.add(op[1])
-                        for key in [k for k in staged if k[0] == op[1]]:
-                            del staged[key]
-                    if kind in ("write", "xor"):
-                        _, cid, oid, woff, data = op
-                        with _span("store.commit.stage", detail=True):
-                            cur = self._staged_bytes(staged, gone,
-                                                     gone_colls, cid, oid)
-                            end = woff + len(data)
-                            if end > len(cur):
-                                grown = np.zeros(end, dtype=np.uint8)
-                                grown[:len(cur)] = cur
-                                cur = grown
-                            else:
-                                cur = cur.copy()
-                            if kind == "xor":
-                                cur[woff:end] ^= data
-                            else:
-                                cur[woff:end] = data
-                        meta_ops.append(self._stage(
-                            staged, new_extents, cid, oid, cur))
-                    elif kind == "truncate":
-                        _, cid, oid, size = op
-                        with _span("store.commit.stage", detail=True):
-                            cur = self._staged_bytes(staged, gone,
-                                                     gone_colls, cid, oid)
-                            if size <= len(cur):
-                                cur = cur[:size].copy()
-                            else:
-                                grown = np.zeros(size, dtype=np.uint8)
-                                grown[:len(cur)] = cur
-                                cur = grown
-                        meta_ops.append(self._stage(
-                            staged, new_extents, cid, oid, cur))
-                    else:
-                        meta_ops.append(op)
+                        dropped = [k for k in staged if k[0] == op[1]]
+                    for key in dropped:
+                        staged.pop(key, None)
+                        if key in slots:
+                            meta_ops[slots.pop(key)] = None
+                    meta_ops.append(op)
+                for (cid, oid), i in slots.items():
+                    meta_ops[i] = self._stage(new_extents, cid, oid,
+                                              staged[(cid, oid)])
             except Exception:
                 for doff, dlen in new_extents:
                     self._alloc.free(doff, dlen)
                 raise
+            meta_ops = [op for op in meta_ops if op is not None]
             if self.o_dsync and new_extents:
                 with _span("store.commit.pwrite", detail=True):
                     os.fsync(self._dev_fd)  # data durable BEFORE the WAL
@@ -1065,6 +1059,10 @@ class TinStore:
                 if cid in self._meta and oid in self._meta[cid]:
                     self._cache.put(key, arr)
             self.committed_txns += 1
+            if byte_ops:
+                kv_perf.inc_many((
+                    ("store_objects_staged", len(slots)),
+                    ("store_byte_ops_folded", byte_ops - len(slots))))
             if self._db.wal_size() >= self.wal_max_bytes:
                 try:
                     self._db.flush()
@@ -1085,13 +1083,17 @@ class TinStore:
         kvt.set("S", b"committed_txns",
                 struct.pack("<Q", self.committed_txns + 1))
         work: dict[tuple[str, str], _TinObject | None] = {}
+        # collections removed earlier in the batch: a pre-txn record
+        # must not show through one re-created after the rmcoll
+        gone_colls: set[str] = set()
 
         def getobj(cid, oid, create):
             key = (cid, oid)
             if key in work:
                 o = work[key]
             else:
-                cur = self._meta.get(cid, {}).get(oid)
+                cur = (None if cid in gone_colls
+                       else self._meta.get(cid, {}).get(oid))
                 o = cur.copy() if cur is not None else None
             if o is None and create:
                 o = _TinObject()
@@ -1110,6 +1112,7 @@ class TinStore:
                 kvt.rmkey("C", cid.encode())
                 kvt.rmkeys_by_prefix("O", cid.encode() + b"\x00")
                 kvt.rmkeys_by_prefix("M", cid.encode() + b"\x00")
+                gone_colls.add(cid)
                 for key in [k for k in work if k[0] == cid]:
                     work[key] = None
             elif kind == "touch":
@@ -1166,6 +1169,18 @@ class TinStore:
                 raise ValueError(f"unknown meta op {kind!r}")
         return kvt
 
+    def _staged_len(self, staged, gone, gone_colls,
+                    cid, oid) -> int | None:
+        """The object's length as the txn has left it so far, without
+        reading its bytes (None: it does not exist)."""
+        key = (cid, oid)
+        if key in staged:
+            return len(staged[key])
+        if key in gone or cid in gone_colls:
+            return None
+        o = self._meta.get(cid, {}).get(oid)
+        return o.size if o is not None else None
+
     def _staged_bytes(self, staged, gone, gone_colls,
                       cid, oid) -> np.ndarray:
         key = (cid, oid)
@@ -1178,8 +1193,58 @@ class TinStore:
             return self._object_bytes(cid, oid)
         return np.zeros(0, dtype=np.uint8)
 
+    def _fold(self, op: tuple, staged, gone, gone_colls) -> bool:
+        """Apply one byte op to its object's buffer in `staged`, which
+        this txn owns: changed in place where the op fits, else copied
+        once into a buffer of the new length. The current bytes are read
+        only where the op keeps some of them. False when the op changes
+        nothing: a truncate to the current length (BlueStore's
+        _do_truncate returns at once)."""
+        kind, cid, oid = op[0], op[1], op[2]
+        key = (cid, oid)
+        n = self._staged_len(staged, gone, gone_colls, cid, oid)
+        if kind == "truncate":
+            size = op[3]
+            if size == n:
+                return False
+            with _span("store.commit.stage", detail=True):
+                cur = self._staged_bytes(staged, gone, gone_colls, cid, oid)
+                if size <= len(cur):
+                    buf = cur[:size].copy()
+                else:
+                    buf = np.empty(size, dtype=np.uint8)
+                    buf[:len(cur)] = cur
+                    buf[len(cur):] = 0
+                staged[key] = buf
+            return True
+        woff, data = op[3], op[4]
+        end = woff + len(data)
+        n = n or 0
+        with _span("store.commit.stage", detail=True):
+            if key in staged and end <= n:
+                buf = staged[key]
+            else:
+                buf = np.empty(max(n, end), dtype=np.uint8)
+                # [lo, hi): what the op sets whole (a write's range;
+                # nothing of an xor, which reads every current byte)
+                lo, hi = (woff, end) if kind == "write" else (n, n)
+                head = min(lo, n)
+                if head or hi < n:
+                    cur = self._staged_bytes(staged, gone, gone_colls,
+                                             cid, oid)
+                    buf[:head] = cur[:head]
+                    buf[hi:n] = cur[hi:]
+                buf[head:lo] = 0
+                buf[max(hi, n):] = 0
+            if kind == "xor":
+                buf[woff:end] ^= data
+            else:
+                buf[woff:end] = data
+            staged[key] = buf
+        return True
+
     @staticmethod
-    def _compress(alg: str, raw: bytes) -> bytes:
+    def _compress(alg: str, raw: bytes | np.ndarray) -> bytes:
         if alg == "zlib":
             import zlib
             return zlib.compress(raw, 3)
@@ -1199,19 +1264,18 @@ class TinStore:
         out = dec.decompress(stored, logical_size + 1)
         return out
 
-    def _stage(self, staged, new_extents, cid, oid,
-               arr: np.ndarray) -> tuple:
-        """COW the object's new bytes into a fresh extent; return the
+    def _stage(self, new_extents, cid, oid, arr: np.ndarray) -> tuple:
+        """COW the object's final bytes into a fresh extent; return the
         setext/setextc metadata op. Nothing commits until the KV
-        batch. Compression happens HERE (the _do_write decision):
+        batch. `pwrite` and the crc read `arr` where it lies; only
+        compression (the _do_write decision, made HERE) builds bytes:
         the device and the crc-on-stored-bytes see compressed data,
         the cache and the logical crc see raw data."""
-        with _span("store.commit.stage", detail=True):
-            stored = arr.tobytes()
-            calg = ""
-            if self.compression is not None \
-                    and len(arr) >= self.compression_min_blob:
-                comp = self._compress(self.compression, stored)
+        stored, calg = arr, ""
+        if self.compression is not None \
+                and len(arr) >= self.compression_min_blob:
+            with _span("store.commit.stage", detail=True):
+                comp = self._compress(self.compression, arr)
                 if len(comp) <= self.compression_required_ratio * len(arr):
                     stored, calg = comp, self.compression
         # capacity gate BEFORE the allocator grows the device: the
@@ -1230,10 +1294,9 @@ class TinStore:
             doff, dlen = self._alloc.alloc(len(stored))
             if self._alloc.device_size > os.fstat(self._dev_fd).st_size:
                 os.ftruncate(self._dev_fd, self._alloc.device_size)
-            if stored:
+            if len(stored):
                 os.pwrite(self._dev_fd, stored, doff)
         new_extents.append((doff, dlen))
-        staged[(cid, oid)] = arr
         st = self.compress_stats
         st["logical_bytes"] += len(arr)
         st["stored_bytes"] += len(stored)
@@ -1241,8 +1304,7 @@ class TinStore:
             if calg:
                 st["compressed_blobs"] += 1
                 return ("setextc", cid, oid, doff, dlen, len(arr),
-                        _crc32c(arr), calg, len(stored),
-                        _crc32c(np.frombuffer(stored, np.uint8)))
+                        _crc32c(arr), calg, len(stored), _crc32c(stored))
             st["raw_blobs"] += 1
             return ("setext", cid, oid, doff, dlen, len(arr),
                     _crc32c(arr))

@@ -784,6 +784,27 @@ class TestLiveSpanLog:
         schema = admin_command(cluster.asok_path(d.name), "perf schema")
         assert schema["kv"]["host_crc32c_native"]["kind"] == "gauge"
 
+    def test_a_write_stages_each_shard_object_once(self, cluster, client):
+        from ceph_tpu.utils.admin_socket import admin_command
+        d = next(iter(cluster.osds.values()))
+
+        def kv():
+            return admin_command(cluster.asok_path(d.name), "perf dump")["kv"]
+
+        before = kv()
+        client.write({"staged-once": b"s" * 3000})
+        after = kv()
+        shards = sum(1 for x in cluster.osds.values()
+                     for cid in x.store.list_collections()
+                     if x.store.exists(cid, "staged-once"))
+        assert shards >= 2
+        # write + same-length truncate + hinfo: one extent a shard, the
+        # truncate folded into it
+        assert after["store_objects_staged"] \
+            - before["store_objects_staged"] == shards
+        assert after["store_byte_ops_folded"] \
+            - before["store_byte_ops_folded"] == shards
+
     def test_a_sampler_s_pass_is_a_span_and_tags_nothing(self, cluster,
                                                          client, session):
         from ceph_tpu.utils import profiler

@@ -122,6 +122,19 @@ class TestStoreContract:
             .write("c", "o", 3, b"xy"))
         assert bytes(store.read("c", "o")) == b"\x00\x00\x00xy"
 
+    def test_rmcoll_recreate_touch_keeps_no_old_record(self, store):
+        # the re-created collection is empty in the KV plane too: the
+        # touched object is new, not the pre-txn record resurrected
+        store.queue_transaction(
+            Transaction().create_collection("c")
+            .write("c", "o", 0, b"old bytes").setattr("c", "o", "a", b"1"))
+        store.queue_transaction(
+            Transaction().remove_collection("c").create_collection("c")
+            .touch("c", "o"))
+        reopen(store)
+        assert store.stat("c", "o") == 0
+        assert dict(store.collections["c"]["o"].xattrs) == {}
+
     def test_omap_rmkeys_missing_object_is_noop(self, store):
         store.queue_transaction(Transaction().create_collection("c"))
         store.queue_transaction(
@@ -742,6 +755,187 @@ class TestLegacyForwardReplay:
             TinStore(path)
         rep = TinStore.fsck(path)
         assert rep["format"] == "legacy" and rep["errors"]
+
+
+def _random_txn(rng, shadow: MemStore) -> Transaction:
+    """One transaction of byte, attr and teardown ops over two
+    collections and three objects, several ops an object. `shadow`
+    applies each op as it is drawn, so a truncate can aim at the length
+    the transaction has left so far (down, up, or the same)."""
+    t = Transaction()
+
+    def emit(op):
+        t.ops.append(op)
+        one = Transaction()
+        one.ops.append(op)
+        shadow.queue_transaction(one)
+
+    def length(c, o):
+        return shadow.stat(c, o) if shadow.exists(c, o) else 0
+
+    def payload():
+        return rng.integers(0, 256, int(rng.integers(0, 3000)), np.uint8)
+
+    for _ in range(int(rng.integers(6, 16))):
+        c = str(rng.choice(["a", "b"]))
+        o = str(rng.choice(["o0", "o1", "o2"]))
+        kind = int(rng.integers(0, 9))
+        if kind == 0:
+            emit(("write", c, o, int(rng.integers(0, 2000)), payload()))
+        elif kind == 1:
+            emit(("xor", c, o, int(rng.integers(0, 2000)), payload()))
+        elif kind == 2:
+            n = length(c, o)
+            size = int(rng.choice([max(0, n - int(rng.integers(1, 500))),
+                                   n + int(rng.integers(1, 500)), n]))
+            emit(("truncate", c, o, size))
+        elif kind == 3:
+            emit(("remove", c, o))
+            emit(("write", c, o, int(rng.integers(0, 100)), payload()))
+        elif kind == 4:
+            emit(("rmcoll", c))
+            emit(("mkcoll", c))
+        elif kind == 5:
+            emit(("setattr", c, o, str(rng.choice(["h", "i"])),
+                  rng.bytes(int(rng.integers(1, 9)))))
+        elif kind == 6:
+            emit(("rmattr", c, o, "h"))
+        elif kind == 7:
+            emit(("touch", c, o))
+        else:                    # a shard write: row, same-length truncate
+            row = payload()
+            emit(("write", c, o, 0, row))
+            emit(("truncate", c, o, len(row)))
+            emit(("setattr", c, o, "hinfo", rng.bytes(8)))
+    return t
+
+
+def _assert_same_state(tin: TinStore, mem: MemStore) -> None:
+    from ceph_tpu.osd.tinstore import _crc32c
+    assert tin.list_collections() == sorted(mem.collections)
+    for cid, coll in mem.collections.items():
+        assert tin.list_objects(cid) == sorted(coll)
+        for oid, o in coll.items():
+            assert tin.stat(cid, oid) == len(o.data)
+            assert bytes(tin.read(cid, oid)) == o.data.tobytes()
+            assert dict(tin.collections[cid][oid].xattrs) == o.xattrs
+            if len(o.data):       # an empty object's crc is never read
+                assert tin._meta[cid][oid].crc == _crc32c(o.data)
+    # every live extent is an object's; nothing staged and let go leaks
+    assert tin._alloc.used_bytes() == sum(
+        o.dlen for coll in tin._meta.values() for o in coll.values())
+
+
+class TestTinStoreStagesOnce:
+    """A transaction folds each object's byte ops in order and stages
+    the object once, on its final bytes (ref: BlueStore writes an
+    object's data once per TransContext; _do_truncate returns at once
+    when the size is the onode's)."""
+
+    @pytest.mark.parametrize("compression", [None, "zlib"])
+    @pytest.mark.parametrize("seed", range(12))
+    def test_random_txn_matches_memstore_before_and_after_replay(
+            self, tmp_path, seed, compression):
+        rng = np.random.default_rng(3900 + seed)
+        base = Transaction().create_collection("a").create_collection("b")
+        for c in ("a", "b"):
+            for o in ("o0", "o1"):
+                base.write(c, o, 0, rng.integers(0, 256, 1500, np.uint8))
+                base.setattr(c, o, "h", b"base")
+        mem, shadow = MemStore(), MemStore()
+        tin = TinStore(str(tmp_path / "s"), compression=compression,
+                       compression_min_blob=1)
+        for st in (mem, shadow, tin):
+            st.queue_transaction(base)
+        txn = _random_txn(rng, shadow)
+        mem.queue_transaction(txn)
+        tin.queue_transaction(txn)
+        _assert_same_state(tin, mem)
+        tin.crash()
+        tin.remount()                     # the KV plane's WAL replayed
+        _assert_same_state(tin, mem)
+        rep = TinStore.fsck(str(tmp_path / "s"))
+        assert not rep["bad_objects"] and not rep["errors"]
+        assert not rep["extent_errors"]
+
+    def test_a_shard_write_stages_one_extent(self, tmp_path, monkeypatch):
+        from ceph_tpu.kv.tindb import kv_perf
+        from ceph_tpu.osd import tinstore
+        st = TinStore(str(tmp_path / "s"))
+        st.queue_transaction(Transaction().create_collection("c"))
+        row = np.random.default_rng(1).integers(0, 256, 512 << 10,
+                                                np.uint8)
+        writes = []
+        real = tinstore.os.pwrite
+        monkeypatch.setattr(tinstore.os, "pwrite",
+                            lambda fd, b, off: writes.append(len(b))
+                            or real(fd, b, off))
+        before = kv_perf.dump()
+        used = st._alloc.used_bytes()
+        st.queue_transaction(
+            Transaction().write("c", "o", 0, row).truncate("c", "o", len(row))
+            .setattr("c", "o", "hinfo", b"\x01"))
+        after = kv_perf.dump()
+        assert writes == [512 << 10]                # one pwrite, one extent
+        assert st._alloc.used_bytes() - used == 512 << 10
+        assert after["store_objects_staged"] \
+            - before["store_objects_staged"] == 1
+        assert after["store_byte_ops_folded"] \
+            - before["store_byte_ops_folded"] == 1
+        assert bytes(st.read("c", "o")) == row.tobytes()
+        # a same-length truncate alone writes nothing at all
+        st.queue_transaction(Transaction().truncate("c", "o", len(row)))
+        assert writes == [512 << 10]
+        assert kv_perf.dump()["store_objects_staged"] \
+            == after["store_objects_staged"]
+        st.crash()
+        st.remount()
+        assert bytes(st.read("c", "o")) == row.tobytes()
+        assert st.getattr("c", "o", "hinfo") == b"\x01"
+
+    def test_enospc_on_the_second_object_leaves_store_as_before(
+            self, tmp_path):
+        import errno
+        st = TinStore(str(tmp_path / "s"))
+        rng = np.random.default_rng(2)
+        st.queue_transaction(
+            Transaction().create_collection("c")
+            .write("c", "old", 0, rng.integers(0, 256, 9000, np.uint8)))
+        def kv_rows(store):
+            return [(p, k, v) for p in "COMS"
+                    for k, v in store._db.iterate(p)]
+
+        kv0 = kv_rows(st)
+        used0, txns0 = st._alloc.used_bytes(), st.committed_txns
+        # room for one more 512 KiB extent, not for two
+        st.set_capacity(st.used_bytes() + (512 << 10) + 2048)
+        t = Transaction()
+        for name in ("o1", "o2"):
+            row = rng.integers(0, 256, 512 << 10, np.uint8)
+            t.write("c", name, 0, row).truncate("c", name, len(row)) \
+             .setattr("c", name, "hinfo", b"h")
+        with pytest.raises(OSError) as e:
+            st.queue_transaction(t)
+        assert e.value.errno == errno.ENOSPC
+        # the first object's extent went back (the device may have grown)
+        assert st._alloc.used_bytes() == used0
+        assert st.committed_txns == txns0
+        assert kv_rows(st) == kv0
+        assert st.list_objects("c") == ["old"]
+        st.crash()
+        st.remount()
+        assert kv_rows(st) == kv0 and st.committed_txns == txns0
+        assert st._alloc.used_bytes() == used0
+
+    @pytest.mark.parametrize("view", ["contiguous", "strided", "readonly",
+                                      "words"])
+    def test_host_crc_reads_an_array_in_place(self, view):
+        from ceph_tpu.kv import host_crc32c
+        a = np.random.default_rng(4).integers(0, 256, 4099, np.uint8)
+        arr = {"contiguous": a, "strided": a[::3],
+               "readonly": np.frombuffer(a.tobytes(), np.uint8),
+               "words": a[:4096].view(np.uint32)}[view]
+        assert host_crc32c(arr) == host_crc32c(arr.tobytes())
 
 
 def test_store_bench_tool_smoke():
