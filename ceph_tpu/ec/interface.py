@@ -118,10 +118,11 @@ class ErasureCode(abc.ABC):
         if fn is None:
             from ..ops.rs_kernels import make_encoder
             from .linearize import derive_repair_matrix
-            R = None
-            for seed in range(3):  # a random probe matrix is singular
-                try:               # ~0.4% of the time even when the
-                    R = derive_repair_matrix(   # helpers suffice
+            R = self.repair_matrix(erasures, survivors)
+            for seed in range(3 if R is None else 0):
+                try:    # a random probe matrix is singular ~0.4% of
+                    #     the time even when the helpers suffice
+                    R = derive_repair_matrix(
                         self, erasures, survivors, seed=seed)
                     break
                 except ValueError:
@@ -133,6 +134,15 @@ class ErasureCode(abc.ABC):
                     (erasures, survivors)] = (
                         "lin", R.tobytes(), R.shape)
         return fn or None
+
+    def repair_matrix(self, erasures: tuple[int, ...],
+                      survivors: tuple[int, ...]):
+        """Optional static repair: the (len(erasures), len(survivors))
+        GF(2^8) matrix that rebuilds `erasures` from the rows of
+        `survivors` in that order, known on the host, as
+        `encode_matrix` serves the write. None by default:
+        `batch_decoder` then derives one by probing `encode_chunks`."""
+        return None
 
     # -- parity-delta fast path (partial-stripe RMW) -----------------------
 
@@ -200,13 +210,22 @@ class ErasureCode(abc.ABC):
             full[:, tr, :] = deltas[:, ti, :]
         return np.asarray(self.encode_chunks(full))
 
+    def encode_matrix(self):
+        """Optional static encode over whole rows: the (m, k) GF(2^8)
+        matrix whose product with the k data rows is the m parity rows,
+        both in DENSE order (`encode_chunks`' order), byte-wise. The
+        served write fuses it with the rows' crcs into one launch. None
+        for a code without one: RS has its coding matrix, LRC its
+        layers composed; a vector code has `vector_encode_matrix`."""
+        return None
+
     def vector_encode_matrix(self):
         """Optional static encode of a vector code: (D, P), where P is
         the sub-chunk count and D the (m*P, k*P) GF(2^8) matrix whose
         product with the k data rows viewed as (k*P, L/P) sub-chunks is
         the m parity rows viewed the same way. The served write fuses
         it with the rows' crcs into one launch. None for a code without
-        one (RS takes its own (m, k) matrix there)."""
+        one (a matrix code has `encode_matrix`)."""
         return None
 
     def range_batch_decoder(self, erasures: Sequence[int],

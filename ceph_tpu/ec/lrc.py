@@ -218,6 +218,32 @@ class Lrc(ErasureCode):
                             set(self.data_positions)]
         return full[:, coding_positions, :]
 
+    def encode_matrix(self):
+        """The layers composed on the host, in layer order: each
+        position's generator row over the k data rows, a layer's parity
+        rows its matrix times the rows of its inputs — what
+        `encode_chunks` computes, as one (m, k) matrix (parity rows by
+        ascending position). None where a layer's coder has no matrix
+        of its own."""
+        if "_encode_matrix" not in self.__dict__:
+            from ..gf.numpy_ref import gf_matmul
+            gen = {p: np.eye(self.k, dtype=np.uint8)[i]
+                   for i, p in enumerate(self.data_positions)}
+            composed = None
+            for layer in self.layers:
+                mat = layer.coder.encode_matrix()
+                if mat is None:
+                    break
+                rows = gf_matmul(np.asarray(mat, np.uint8),
+                                 np.stack([gen[p] for p in layer.d_pos]))
+                gen.update(zip(layer.c_pos, rows))
+            else:
+                composed = np.stack(
+                    [gen[p] for p in range(self.get_chunk_count())
+                     if p not in set(self.data_positions)])
+            self._encode_matrix = composed
+        return self._encode_matrix
+
     # -- repair planning ---------------------------------------------------
 
     def _repair_plan(self, want: set[int], avail: set[int],
@@ -305,6 +331,36 @@ class Lrc(ErasureCode):
             for p in missing:
                 known[p] = rec[layer.local_id(p)]
         return {p: known[p] for p in want}
+
+    # -- device fast path --------------------------------------------------
+
+    def repair_matrix(self, erasures: tuple[int, ...],
+                      survivors: tuple[int, ...]):
+        """A plan that stays inside one layer: that layer's decode rows
+        over the k of its members the plan reads, 0 for every other
+        survivor — no probe, and a key equal across coder instances.
+        None for a laddered plan (the probed default), or a layer coder
+        without a matrix."""
+        from ..gf.numpy_ref import decode_matrix
+        try:
+            plan, reads, _ = self._repair_plan(set(erasures),
+                                               set(survivors))
+        except ValueError:
+            return None
+        if len(plan) != 1:
+            return None
+        layer = plan[0][0]
+        mat = layer.coder.encode_matrix()
+        if mat is None:
+            return None
+        use = sorted(reads)
+        rows = decode_matrix(np.asarray(mat, np.uint8),
+                             [layer.local_id(p) for p in erasures],
+                             layer.k, [layer.local_id(p) for p in use])
+        R = np.zeros((len(erasures), len(survivors)), np.uint8)
+        for j, p in enumerate(use):
+            R[:, survivors.index(p)] = rows[:, j]
+        return R
 
     def decode_concat(self, chunks: Mapping[int, np.ndarray],
                       object_size: int | None = None) -> np.ndarray:

@@ -57,6 +57,9 @@ class ReedSolomon(ErasureCode):
     def encode_chunks(self, data: np.ndarray) -> np.ndarray:
         return self._encode_host(data)
 
+    def encode_matrix(self):
+        return self.matrix
+
     def delta_matrix(self, touched):
         # exact: the parity-delta matrix IS the coding matrix's
         # touched columns (no probe needed; bit-parity with the probe

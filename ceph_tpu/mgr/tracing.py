@@ -60,6 +60,7 @@ CATEGORY_OF = {
     "ecbackend.write.launch": "encode",
     "ecbackend.write.fetch": "encode",
     "ecbackend.write.txns": "encode",
+    "ecbackend.write.slots": "encode",
     "ecbackend.write.fanout": "wire",
     "ecbackend.read.gather": "encode",
     "ecbackend.read.verify": "encode",

@@ -137,6 +137,10 @@ def ec_perf_counters():
             .add_u64_counter("recover_wire_bytes",
                              "helper bytes pulled for recovery (the "
                              "repair-bytes-on-wire numerator)")
+            .add_u64_counter("recover_helper_reads",
+                             "helper shard reads staged into recover "
+                             "launches, one a helper an object (fused, "
+                             "range and generic paths alike)")
             .add_u64_counter("recover_range_frames_served",
                              "sub-chunk pull frames this daemon served "
                              "as a source (`readv_ranges_host`)")
@@ -288,6 +292,7 @@ class ECBackend(PGBackend):
                 f"permutation of 0..{self.k + self.m - 1}")
         self.data_slots = self.chunk_mapping[:self.k]
         self._perm = np.asarray(self.chunk_mapping)
+        self._row_of_slot = np.argsort(self._perm)
         self._identity_mapping = \
             self.chunk_mapping == list(range(self.k + self.m))
         # pool-wide stripe geometry; round the requested chunk size up
@@ -362,17 +367,18 @@ class ECBackend(PGBackend):
 
     @_functools.cached_property
     def _write_matrix(self) -> tuple[bytes, int] | None:
-        """(matrix bytes, sub-chunks) of the one-launch write: RS's
-        (m, k) matrix over whole rows, or a vector code's encode over
-        its sub-chunks; None for a coder with neither."""
-        from ..ec.rs import ReedSolomon
-        if isinstance(self.coder, ReedSolomon):
-            mat = np.ascontiguousarray(self.coder.matrix, dtype=np.uint8)
-            return mat.tobytes(), 1
+        """(matrix bytes, sub-chunks) of the one-launch write: a vector
+        code's encode over its sub-chunks, or a matrix code's (m, k)
+        matrix over whole rows (`encode_matrix`: RS's coding matrix,
+        LRC's layers composed); None for a coder with neither."""
         got = self.coder.vector_encode_matrix()
-        if got is None:
+        if got is not None:
+            return (np.ascontiguousarray(got[0], np.uint8).tobytes(),
+                    int(got[1]))
+        mat = self.coder.encode_matrix()
+        if mat is None:
             return None
-        return np.ascontiguousarray(got[0], np.uint8).tobytes(), int(got[1])
+        return np.ascontiguousarray(mat, np.uint8).tobytes(), 1
 
     def _fused_write_program(self, sl: int, bucket: int):
         """The one-launch write program for (shard len, bucket), or None
@@ -399,12 +405,14 @@ class ECBackend(PGBackend):
                                                    np.ndarray]:
         """(B, k, sl) data rows -> (slot-ordered (B, n, sl) shards,
         slot-ordered (B, n) hinfo CRCs). For static-matrix coders (RS,
-        and a vector code's sub-chunk encode: `_fused_write_program`)
-        the encode AND both CRC sets run as ONE fused, B-bucketed device
-        launch with a single host fetch — the write path's r01 shape
-        dispatched encode + CRC as separate launches with host
-        round-trips between (the wire tier pays that per client op).
-        Other coders take the generic two-launch path."""
+        LRC's composed layers, and a vector code's sub-chunk encode:
+        `_fused_write_program`) the encode AND both CRC sets run as ONE
+        fused, B-bucketed device launch with a single host fetch — the
+        write path's r01 shape dispatched encode + CRC as separate
+        launches with host round-trips between (the wire tier pays that
+        per client op). There a mapping that is not the identity hands
+        back `_SlotRows`, views of the rows in slot order. Other coders
+        take the generic two-launch path."""
         from ..ec.rs import ReedSolomon
         B = data_shards.shape[0]
         if isinstance(self.coder, ReedSolomon) \
@@ -471,15 +479,17 @@ class ECBackend(PGBackend):
                 with span("ecbackend.write.fetch"):
                     parity, dense_crcs = jax.device_get(
                         (parity_d, crcs_d))
-            dense = np.concatenate(
-                [data_shards, np.asarray(parity)[:B]], axis=1)
+            parity = np.asarray(parity)[:B]
             dense_crcs = np.asarray(dense_crcs)[:B]
-            shards = self._slots_from_dense(dense)
-            if self._identity_mapping:
-                return shards, dense_crcs
-            crcs = np.empty_like(dense_crcs)
-            crcs[:, self._perm] = dense_crcs
-            return shards, crcs
+            if not self._identity_mapping:
+                # the fan-out takes a view of each slot's row: no copy
+                # of the rows into dense, then into slot order
+                with span("ecbackend.write.slots"):
+                    crcs = np.empty_like(dense_crcs)
+                    crcs[:, self._perm] = dense_crcs
+                    return (_SlotRows(data_shards, parity,
+                                      self._row_of_slot), crcs)
+            return np.concatenate([data_shards, parity], axis=1), dense_crcs
         self.perf.inc_many((("encode_launches", 1),
                             ("encode_bytes", int(data_shards.size))))
         with span("ecbackend.write.encode", counters=self.perf,
@@ -2219,6 +2229,31 @@ RECOVERY_FETCH_BYTES = 8 << 20
 RECOVERY_STAGE_BYTES = 128 << 20
 
 
+class _SlotRows:
+    """(B, n, L) rows of a write in slot order, over its data and parity
+    rows and without a copy of either: `rows[b, s]` is a view of the
+    dense row slot s carries (`row_of_slot`, the inverse of the chunk
+    mapping). What the fan-out takes of a mapping that is not the
+    identity; `np.asarray(rows)` makes the copy."""
+
+    __slots__ = ("data", "parity", "row_of_slot")
+
+    def __init__(self, data: np.ndarray, parity: np.ndarray,
+                 row_of_slot: np.ndarray):
+        self.data, self.parity, self.row_of_slot = data, parity, row_of_slot
+
+    def __getitem__(self, key):
+        b, s, *rest = key
+        j, k = int(self.row_of_slot[s]), self.data.shape[1]
+        row = self.data[b, j] if j < k else self.parity[b, j - k]
+        return row[tuple(rest)]
+
+    def __array__(self, dtype=None, copy=None):
+        dense = np.concatenate([self.data, self.parity], axis=1)
+        return dense[:, self.row_of_slot].astype(dtype or np.uint8,
+                                                 copy=False)
+
+
 def _fetch_frames(nb: int, rl: int) -> list[tuple[int, int]]:
     """(first row, rows) of each readv frame that pulls `nb` objects'
     rows of `rl` bytes from one helper: chunks of RECOVERY_FETCH_BYTES,
@@ -2861,7 +2896,8 @@ class RecoveryRunner:
             pre_bad = self._stage(live, sl, rl, stack, exp,
                                   proto.verify)
         self.stats["helper_bytes_on_wire"] += wire
-        self.perf.inc("recover_wire_bytes", wire)
+        self.perf.inc_many((("recover_wire_bytes", wire),
+                            ("recover_helper_reads", B * H)))
         if wire > self.perf.get("recover_grant_bytes_max"):
             self.perf.set("recover_grant_bytes_max", wire)
         with span("recovery.stage"):
@@ -2878,9 +2914,11 @@ class RecoveryRunner:
                     # bytes — match it so padding never "fails"
                     expfold[B:] = _fold_seed_const(rl)
         self.perf.inc("recover_launches")
-        # nbytes: the helper rows this launch decodes, B objects of H
+        # nbytes: the helper rows this launch decodes, B objects of H;
+        # the tags carry the objects and the helper reads to the log
         with span("recovery.launch", counters=self.perf,
-                  key="recover_launch_time", nbytes=wire):
+                  key="recover_launch_time", nbytes=wire,
+                  tags={"objects": B, "recover_helper_reads": B * H}):
             handles = program(stack) if self._host_crc \
                 else program(stack, expfold)
             for h in handles:
@@ -3132,7 +3170,9 @@ class RecoveryRunner:
         self.stats["generic_batches"] += 1
         wire = len(plan.helper) * sl * len(names)
         self.stats["helper_bytes_on_wire"] += wire
-        self.perf.inc("recover_wire_bytes", wire)
+        self.perf.inc_many((("recover_wire_bytes", wire),
+                            ("recover_helper_reads",
+                             len(plan.helper) * len(names))))
         stacks = {s: np.stack([be._store(s).read(
             shard_cid(be.pg, s), n) for n in names])
             for s in plan.helper}

@@ -106,7 +106,7 @@ declare_span_names(
     "ecbackend.write.stripe", "ecbackend.write.encode",
     "ecbackend.write.stage", "ecbackend.write.launch",
     "ecbackend.write.fetch", "ecbackend.write.txns",
-    "ecbackend.write.fanout",
+    "ecbackend.write.slots", "ecbackend.write.fanout",
     "ecbackend.read.gather", "ecbackend.read.verify",
     "ecbackend.read.verify.stage", "ecbackend.read.verify.launch",
     "ecbackend.read.verify.fetch", "ecbackend.read.decode",
@@ -996,6 +996,12 @@ class _RmwFetchOp:
                                   dd.list(Decoder.blob)))
 
 
+class AuthorizeDeferred(ConnectionError):
+    """A store op not sent because this daemon's own osd service ticket
+    is not warm yet (`OSDDaemon._authorize_peer`): it says nothing of
+    the peer, which a caller must not suspect for it."""
+
+
 class RemoteStore:
     """ObjectStore proxy: the MOSDECSubOpWrite/Read role. Every method
     is one MStoreOp frame to the OSD owning the physical store."""
@@ -1785,7 +1791,7 @@ class OSDDaemon:
         if not self._cauth.has_ticket("osd"):
             self.perf.inc("authorize_deferred")
             self._spawn_ticket_refresh()
-            raise ConnectionError(
+            raise AuthorizeDeferred(
                 f"{self.name}: osd service ticket not warm; authorize "
                 f"to {peer} deferred (background refresh kicked)")
         _wire_authorize(self._cauth, self.auth_rpc, peer, "osd",
@@ -2529,28 +2535,31 @@ class OSDDaemon:
             if osd == self.osd_id or osd in skip \
                     or not _valid_osd(osd, n_osds):
                 continue
-            rs = RemoteStore(
+            ask = _AskedOnceMore(RemoteStore(
                 self.rpc, f"osd.{osd}", timeout=1.0,
                 authorize=self._authorize_peer
-                if self.verifier is not None else None)
+                if self.verifier is not None else None))
             # a previous interval may have slotted this peer anywhere:
             # ask for EVERY slot's blob, not just the one our acting
             # assigns it (a slot-addressed miss reads as "no blob" and
             # silently crowns a divergent local log)
             for s in range(len(acting)):
                 try:
-                    base = rs.omap_get(shard_cid(pgid, s),
-                                       "__pg_meta__", PG_META_KEY)
+                    base = ask(shard_cid(pgid, s), "__pg_meta__",
+                               PG_META_KEY)
                     heard.add(osd)
                     try:
-                        delta = rs.omap_get(shard_cid(pgid, s),
-                                            "__pg_meta__",
-                                            PG_META_DELTA_KEY)
+                        delta = ask(shard_cid(pgid, s), "__pg_meta__",
+                                    PG_META_DELTA_KEY)
                     except KeyError:
                         delta = None   # base-only shard (pre-delta)
                     remote_blobs.append((base, delta))
                 except KeyError:
                     heard.add(osd)   # answered: no blob at this slot
+                except AuthorizeDeferred:
+                    # our own cold ticket, no word of the peer: unheard
+                    # this gather (the quorum rule decides), not suspect
+                    break
                 except (ConnectionError, OSError):
                     # unreachable: SUSPECT it (the store-op failure
                     # convention) so the next gather skips it instead
@@ -6676,6 +6685,32 @@ class _WireAuth:
         return self._call("tickets",
                           {"ticket": ticket, "nonce": nonce.hex(),
                            "mac": mac.hex(), "services": services})
+
+
+class _AskedOnceMore:
+    """`omap_get` of one peer for one metadata gather, asked once more
+    the first time an answer does not come: a live peer misses a 1 s
+    probe now and then while every daemon of a boot gathers from every
+    other over cold secure sessions (on 17 OSDs in one process, most
+    boots), and a suspicion of it stands until the next map, writes
+    going round its shard. Once a gather: a slow or partitioned peer
+    costs one probe timeout more, not one a slot."""
+
+    __slots__ = ("rs", "spare")
+
+    def __init__(self, rs: "RemoteStore"):
+        self.rs, self.spare = rs, 1
+
+    def __call__(self, *args) -> bytes:
+        try:
+            return self.rs.omap_get(*args)
+        except AuthorizeDeferred:
+            raise
+        except (ConnectionError, OSError):
+            if not self.spare:
+                raise
+            self.spare = 0
+            return self.rs.omap_get(*args)
 
 
 def _valid_osd(osd: int, n_osds: int) -> bool:

@@ -95,6 +95,13 @@ class AdminSocket:
         self._stopping = True
         if self._listener is not None:
             try:
+                # close() alone leaves a thread blocked in accept()
+                # asleep for good, and with it every object its
+                # commands reach: the daemon and its cluster
+                self._listener.shutdown(socket.SHUT_RDWR)
+            except OSError:
+                pass
+            try:
                 self._listener.close()
             except OSError:
                 pass

@@ -19,7 +19,8 @@ beside it `cpu`, the thread's own CPU seconds (`time.thread_time`,
 read at most once in `_CPU_TRUST_S` a thread) by
 the same rule: a stage whose wall is several times its CPU was waiting,
 for the interpreter or the OS, not working; `tid` and `parent`, the
-enclosing span's name, are the keys a later join needs). A
+enclosing span's name, are the keys a later join needs; a span's
+`tags`, where it has any, ride its record too). A
 `detail=True` span is logged and annotated like any other but takes
 nothing from its parent's self or cpu: the parts of a stage beside the
 whole. While a session is live one `trace-probe` thread also logs the
@@ -166,6 +167,8 @@ class span:
             stack = _tls.stack
             stack.pop()
             parent, more = (stack[-1] if stack else None), {}
+            if self.tags:
+                more["tags"] = self.tags
             if self.detail:
                 more["detail"] = True
             elif parent is not None:

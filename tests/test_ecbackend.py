@@ -363,6 +363,26 @@ class TestNonIdentityChunkMapping:
         assert be.eio_stats["read_eio"] >= 1
         assert be.eio_stats["repaired"] >= 1
 
+    def test_a_fused_write_hands_the_fanout_views_in_slot_order(self):
+        """The layers composed make LRC's write one fused launch, and its
+        rows reach the fan-out in slot order as views of the data and
+        parity rows: no copy into dense order, none into slot order."""
+        be, _ = self._mk()
+        data = np.random.default_rng(4031).integers(0, 256,
+                                                    (2, be.k, 512), np.uint8)
+        shards, crcs = be._encode_shards_with_crcs(data, 512)
+        assert be.perf.get("fused_write_launches") == 1
+        assert be.perf.get("encode_launches") == 0
+        for j, slot in enumerate(be.data_slots):
+            assert np.shares_memory(shards[1, slot, :], data)
+            np.testing.assert_array_equal(shards[1, slot, :], data[1, j])
+        dense = np.concatenate(
+            [data, np.asarray(be.coder.encode_chunks(data))], axis=1)
+        want = be._slots_from_dense(dense)
+        np.testing.assert_array_equal(np.asarray(shards), want)
+        np.testing.assert_array_equal(crcs, be._batched_hinfo_crcs(
+            want.reshape(-1, 512)).reshape(2, be.n))
+
 
 class TestStraySweep:
     def test_repair_removes_unknown_leftovers(self):

@@ -156,3 +156,129 @@ def test_minimum_to_decode_with_cost_is_layer_aware(coder):
     chunks = coder.encode(range(8), obj)
     rec = coder.decode([2], {i: chunks[i] for i in need})
     np.testing.assert_array_equal(rec[2], chunks[2])
+
+
+# -- the static forms the served path fuses -------------------------------
+
+PROFILES = {
+    "k4m2l3": {"plugin": "lrc", "k": "4", "m": "2", "l": "3"},
+    "k8m4l3": {"plugin": "lrc", "k": "8", "m": "4", "l": "3"},
+    "k8m4l4": {"plugin": "lrc", "k": "8", "m": "4", "l": "4"},
+    "doc_low_level": {"plugin": "lrc", "mapping": DOC_MAPPING,
+                      "layers": DOC_LAYERS},
+}
+
+
+@pytest.mark.parametrize("name", sorted(PROFILES))
+def test_the_composed_generator_is_encode_chunks(name):
+    """`encode_matrix`: the layers composed on the host, what the
+    layer-by-layer `encode_chunks` computes, as one (m, k) matrix."""
+    from ceph_tpu.gf.numpy_ref import gf_matmul
+    coder = registry.factory(PROFILES[name])
+    G = coder.encode_matrix()
+    assert G.shape == (coder.m, coder.k) and G.dtype == np.uint8
+    data = np.random.default_rng(4001).integers(0, 256, (3, coder.k, 384),
+                                                np.uint8)
+    want = np.asarray(coder.encode_chunks(data))
+    for b in range(3):
+        np.testing.assert_array_equal(gf_matmul(G, data[b]), want[b])
+
+
+def test_a_layer_without_a_matrix_leaves_no_generator():
+    coder = registry.factory(PROFILES["k4m2l3"])
+    fresh = registry.factory(PROFILES["k4m2l3"])
+    fresh.layers[1].coder.encode_matrix = lambda: None
+    assert fresh.encode_matrix() is None
+    assert coder.encode_matrix() is not None
+
+
+@pytest.fixture(scope="module")
+def k8m4l3():
+    return registry.factory(PROFILES["k8m4l3"])
+
+
+@pytest.mark.parametrize("lost", range(16))
+def test_the_local_batch_decoder_is_decode_chunks(k8m4l3, lost):
+    """A single loss of k=8 m=4 l=3 decodes inside its group: the fused
+    program is that layer's own decode row over the 3 helpers the plan
+    reads, bit for bit what `decode_chunks` rebuilds, under a key equal
+    across coder instances."""
+    coder = k8m4l3
+    survivors = [s for s in range(16) if s != lost]
+    helpers = sorted(coder.minimum_to_decode([lost], survivors))
+    assert len(helpers) == 3 and {h // 4 for h in helpers} == {lost // 4}
+    fn = coder.batch_decoder([lost], helpers)
+    rng = np.random.default_rng(4002 + lost)
+    obj = rng.integers(0, 256, (4, 8 * 256), np.uint8)
+    chunks = coder.encode(range(16), obj)
+    stack = np.stack([chunks[h] for h in helpers], axis=1)   # (B, 3, L)
+    got = np.asarray(fn(stack))[:, 0]
+    want = coder.decode_chunks([lost], {h: chunks[h] for h in helpers})
+    np.testing.assert_array_equal(got, want[lost])
+    np.testing.assert_array_equal(got, chunks[lost])
+    other = registry.factory(PROFILES["k8m4l3"])
+    key = coder.decode_program_key([lost], helpers)
+    assert key is not None and key == other.decode_program_key([lost],
+                                                               helpers)
+
+
+@pytest.mark.parametrize("lost,every_survivor", [([2, 3], True),
+                                                  ([0, 2], False)])
+def test_a_loss_past_the_group(k8m4l3, lost, every_survivor):
+    """Two losses in one group break locality. From every survivor two
+    data rows decode in one step through the global layer, by its own
+    rows; from the fewest helpers (`minimum_to_decode`) the plan ladders
+    through several layers and keeps the probed decoder. Both give the
+    lost rows."""
+    coder = k8m4l3
+    survivors = [s for s in range(16) if s not in lost]
+    if not every_survivor:
+        survivors = sorted(coder.minimum_to_decode(lost, survivors))
+    plan, _, _ = coder._repair_plan(set(lost), set(survivors))
+    static = coder.repair_matrix(tuple(lost), tuple(survivors))
+    assert (len(plan) == 1) == every_survivor == (static is not None)
+    fn = coder.batch_decoder(lost, survivors)
+    obj = np.random.default_rng(4003).integers(0, 256, (2, 8 * 256),
+                                               np.uint8)
+    chunks = coder.encode(range(16), obj)
+    got = np.asarray(fn(np.stack([chunks[s] for s in survivors], axis=1)))
+    for i, p in enumerate(lost):
+        np.testing.assert_array_equal(got[:, i], chunks[p])
+
+
+@pytest.mark.parametrize("k,m,l", [(4, 2, 3), (8, 4, 3), (8, 4, 4)])
+def test_the_benchmarks_reference_is_the_codec(k, m, l):
+    """`bench/reference/lrc_codeword.py`, written apart from the codec,
+    lays out and encodes the same codeword on seeded data, and rebuilds
+    every row from its group."""
+    from bench.reference import lrc_codeword, recovered_pool
+    coder = registry.factory({"plugin": "lrc", "k": str(k), "m": str(m),
+                              "l": str(l)})
+    assert lrc_codeword.mapping(k, m, l) == coder.mapping
+    unit = 64
+    payload = np.random.default_rng(4004 + k + l).integers(
+        0, 256, k * unit * 5, np.uint8).tobytes()
+    data = recovered_pool.data_rows(payload, k, unit)
+    parity = np.asarray(coder.encode_chunks(data[None]))[0]
+    rows = [None] * coder.get_chunk_count()
+    for j, p in enumerate(coder.get_chunk_mapping()):
+        rows[p] = data[j] if j < k else parity[j - k]
+    assert lrc_codeword.check(payload, rows, k, m, l, unit) == []
+    for p in range(coder.get_chunk_count()):
+        np.testing.assert_array_equal(
+            lrc_codeword.rebuilt(rows, p, k, m, l), rows[p])
+
+
+def test_the_rs_write_program_is_unchanged():
+    """An RS pool's fused write keeps its bytes and its arguments: the
+    coding matrix itself, and no `planes`, so its cache key and HLO are
+    what they were before a matrix code could bring its own."""
+    from ceph_tpu.osd.ecbackend import ECBackend
+    be = ECBackend("plugin=jerasure technique=reed_sol_van k=8 m=3", "0.0",
+                   list(range(11)), chunk_size=256)
+    mat = np.ascontiguousarray(be.coder.matrix, np.uint8)
+    assert be._write_matrix == (mat.tobytes(), 1)
+    fn = be._fused_write_program(4096, 2)
+    hits = ECBackend._fused_write_fn.cache_info().hits
+    assert fn is ECBackend._fused_write_fn(mat.tobytes(), 3, 8, 4096, 2)
+    assert ECBackend._fused_write_fn.cache_info().hits == hits + 1

@@ -57,6 +57,34 @@ def _wait_for(pred, timeout, what):
 
 
 class TestAdminSocket:
+    def test_stop_ends_the_accept_thread_and_lets_its_owner_go(
+            self, tmp_path):
+        """A stopped socket's accept thread ends, so what its commands
+        reach (a daemon, and through it its cluster) can be freed: a
+        process that boots a cluster again does not keep the old one."""
+        import gc
+        import threading
+        import weakref
+        from ceph_tpu.utils.admin_socket import AdminSocket
+
+        class Owner:
+            def status(self, args):
+                return {"up": True}
+        owner = Owner()
+        gone = weakref.ref(owner)
+        sock = AdminSocket(str(tmp_path / "osd.99.asok"))
+        sock.register("status", owner.status)
+        sock.start()
+        assert admin_command(sock.path, "status") == {"up": True}
+        del owner
+        sock.stop()
+        _wait_for(lambda: not any(t.name == "osd.99-asok"
+                                  for t in threading.enumerate()),
+                  5.0, "the accept thread to end")
+        del sock
+        gc.collect()
+        assert gone() is None
+
     def test_perf_dump_has_hot_path_counters(self, cluster, client):
         """`ceph daemon osd.N perf dump` over the Unix socket returns
         msgr/op-window/ec counters that actually moved under the I/O

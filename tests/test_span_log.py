@@ -95,6 +95,34 @@ class TestSpanLogUnits:
         assert _mine(t0) == {}
         assert pc.get("lat")["count"] == 1
 
+    def test_a_spans_tags_ride_its_record(self, session):
+        t0 = time.perf_counter()
+        with span("unit.tagged", tags={"objects": 4}):
+            pass
+        with span("unit.plain"):
+            pass
+        got = _mine(t0)
+        assert got["unit.tagged"][0]["tags"] == {"objects": 4}
+        assert "tags" not in got["unit.plain"][0]
+
+    def test_only_a_mapped_code_logs_its_slot_order(self, session):
+        """An LRC write puts its rows in slot order under
+        `ecbackend.write.slots`; an identity-mapped RS write has nothing
+        to put there and logs no such span."""
+        import numpy as np
+
+        import ceph_tpu.osd.standalone  # noqa: F401 — declares the spans
+        from ceph_tpu.osd.ecbackend import ECBackend
+        assert is_span_declared("ecbackend.write.slots")
+        for profile, n, logged in (("plugin=lrc k=4 m=2 l=3", 8, 1),
+                                   ("plugin=jerasure k=4 m=2", 6, 0)):
+            be = ECBackend(profile, "0.0", list(range(n)), chunk_size=256)
+            t0 = time.perf_counter()
+            be._encode_shards_with_crcs(np.zeros((1, 4, 256), np.uint8),
+                                        256)
+            assert len(_mine(t0).get("ecbackend.write.slots", [])) \
+                == logged, profile
+
     def test_self_time_subtracts_children_on_the_same_thread(self, session):
         t0 = time.perf_counter()
         with span("unit.parent"):
@@ -1092,6 +1120,17 @@ class TestBackfillSpans:
         staged = sum(r["nbytes"] for r in got["recovery.launch"])
         assert staged > 0 and staged % (2 * 1536) == 0
         assert sum(g["nbytes"] for g in grants) >= staged
+
+    def test_a_launch_carries_its_objects_and_helper_reads(self,
+                                                          backfill_log):
+        """`recover_helper_reads`, as each launch's record carries its
+        part: k = 2 helper rows an object of this pool."""
+        got, _ = backfill_log
+        tags = [r["tags"] for r in got["recovery.launch"]]
+        objects = sum(t["objects"] for t in tags)
+        assert objects == sum(r["nbytes"] for r in got["recovery.launch"]
+                              ) // (2 * 1536) >= 1
+        assert sum(t["recover_helper_reads"] for t in tags) == 2 * objects
 
     def test_the_reservation_s_wait_is_logged_once_a_pg(self, backfill_log):
         got, _ = backfill_log

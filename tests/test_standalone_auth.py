@@ -308,3 +308,37 @@ class TestAdminSocketCaps:
                                  secret=no_osd_secret)
         with pytest.raises(PermissionError):
             blocked.daemon(osd, "perf dump")
+
+
+class TestMetaGatherSuspicion:
+    """A primary's metadata gather suspects a peer on the peer's silence
+    alone: a probe not sent for this daemon's own cold osd ticket says
+    nothing of the peer, a probe that misses once is asked again, and a
+    peer that never answers is suspected as before."""
+
+    @pytest.mark.parametrize("peer_fault,suspected", [
+        ("cold_ticket", False), ("one_miss", False), ("silent", True)])
+    def test_what_a_gather_suspects(self, cluster, monkeypatch,
+                                    peer_fault, suspected):
+        from ceph_tpu.osd import standalone
+        acting = [int(o) for o in
+                  cluster.client().osdmap.pg_to_up_acting_osds(1, 0)[2]]
+        d, peer = cluster.osds[acting[0]], f"osd.{acting[1]}"
+        real, asked = standalone.RemoteStore.omap_get, []
+
+        def faulty(rs, *args):
+            if rs._peer == peer:
+                asked.append(args)
+                if peer_fault == "cold_ticket":
+                    raise standalone.AuthorizeDeferred("ticket not warm")
+                if peer_fault == "silent" or len(asked) == 1:
+                    raise ConnectionError(f"rpc to {peer} timed out")
+            return real(rs, *args)
+        monkeypatch.setattr(standalone.RemoteStore, "omap_get", faulty)
+        d.suspect.clear()
+        _, _, quorum_ok = d._load_meta(0, acting)
+        assert quorum_ok                   # the other peer answered
+        assert (acting[1] in d.suspect) == suspected
+        # a cold ticket is not asked again; a silent peer once more
+        assert len(asked) == {"cold_ticket": 1, "silent": 2}.get(
+            peer_fault, len(asked))

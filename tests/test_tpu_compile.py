@@ -228,3 +228,39 @@ def test_the_clay_range_repair_compiles_at_the_wire_tiers_grant(
                    ((16,), np.uint32))
     assert _no_gather(out)
     assert out.memory_analysis().temp_size_in_bytes < 1024 * MiB
+
+
+# -- a layered code: LRC k=8 m=4 l=3 (rados_lrc_k8m4l3_17osd_1out) --------
+
+@pytest.fixture(scope="module")
+def lrc():
+    from ceph_tpu.ec.registry import factory
+    return factory("plugin=lrc k=8 m=4 l=3")
+
+
+@pytest.mark.parametrize("bucket", [1, 16])
+def test_the_fused_lrc_write_compiles(one_chip, lrc, bucket):
+    """What a served write of the LRC pool launches: the 8 x 8 generator
+    of the layers composed (64 entries: the unrolled lowering) over the
+    8 data rows, then the crcs of all 16 rows."""
+    from ceph_tpu.osd.ecbackend import ECBackend
+    G = np.ascontiguousarray(lrc.encode_matrix(), np.uint8)
+    assert G.shape == (8, 8)
+    fn = ECBackend._fused_write_fn(G.tobytes(), 8, 8, SHARD, bucket)
+    out = _compile(fn, one_chip, ((bucket, 8, SHARD), np.uint8))
+    assert _no_gather(out)
+    assert out.memory_analysis().temp_size_in_bytes < bucket * 64 * MiB
+
+
+def test_the_lrc_local_repair_compiles_at_the_wire_tiers_grant(
+        one_chip, lrc):
+    """One grant of the LRC pool's backfill: 24 MiB of helper bytes is
+    16 objects of 3 whole rows; lost slot 2 from the 3 other members of
+    its group."""
+    from ceph_tpu.osd.ecbackend import _build_recover_program
+    fn = _build_recover_program(lrc.batch_decoder((2,), (0, 1, 3)),
+                                verify=True, host_crc=False)
+    out = _compile(fn, one_chip, ((16, 3, SHARD), np.uint8),
+                   ((16,), np.uint32))
+    assert _no_gather(out)
+    assert out.memory_analysis().temp_size_in_bytes < 256 * MiB
