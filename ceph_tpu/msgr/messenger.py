@@ -90,6 +90,8 @@ import threading
 import time as _time_mod
 from collections import deque
 
+import numpy as np
+
 from ..csum.reference import ceph_crc32c, ceph_crc32c_iov
 from ..utils.encoding import Decoder, Encoder
 from ..utils.perf_counters import PerfCountersBuilder
@@ -117,6 +119,12 @@ def msgr_perf_counters():
             .add_u64_counter("replayed", "unacked frames replayed")
             .add_u64_counter("tx_compressed", "frames compressed on tx")
             .add_u64_counter("rx_compressed", "frames inflated on rx")
+            .add_u64_counter("rx_frames_direct",
+                             "bulk frames read straight from the socket "
+                             "into a buffer of their own")
+            .add_u64_counter("rx_buffer_reused",
+                             "bulk frames read into the buffer their "
+                             "secure connection kept from the last")
             .add_time_avg("seal_time",
                           "AEAD seal incl. staging (secure mode)",
                           hist=True)
@@ -171,7 +179,27 @@ COMP_ZLIB = 1
 _COMP_IDS = {None: COMP_NONE, "zlib": COMP_ZLIB}
 _COMP_FLAG = 0x8000
 _COMPRESS_MIN = 128          # don't bloat tiny frames
-_DECOMP_MAX = 1 << 26        # decompression-bomb ceiling (= frame cap)
+_FRAME_MAX = 1 << 26         # frame length cap, checked before allocation
+_DECOMP_MAX = _FRAME_MAX     # decompression-bomb ceiling (= frame cap)
+
+# inbound framing: a frame of at least _RX_DIRECT_MIN body bytes is
+# read from the socket straight into one buffer sized to it
+# (recv_into: the kernel's copy, which runs without the GIL) and
+# handed on as a view. Shorter frames land in the connection's
+# staging buffer and are cut out of it with one copy each. A secure
+# connection keeps its bulk ciphertext buffer for the next bulk frame
+# (the AEAD open always returns the plaintext as a new object, so no
+# view of the buffer outlives the frame) up to _RX_KEEP_MAX; a crc
+# connection never reuses one, since its payload views alias the
+# frame buffer.
+_RX_DIRECT_MIN = 64 << 10
+_RX_KEEP_MAX = 16 << 20
+#: the staging buffer holds any short frame whole, with room to spare
+_RX_STAGE = 2 * _RX_DIRECT_MIN
+#: bytes one read event may take off one connection: a hot connection
+#: must not starve the rest of its reactor (epoll is level-triggered,
+#: the remainder fires on the next loop)
+_RX_BUDGET = 1 << 20
 
 _MSG_TYPES: dict[int, type] = {}
 
@@ -189,16 +217,26 @@ class _SecureBox:
         self._rx_prefix = rx_prefix
         self._tx_ctr = 0
 
-    def seal(self, plain: bytes, aad: bytes) -> bytes:
+    def seal_parts(self, plain: bytes, aad: bytes) -> tuple:
+        """(nonce, ciphertext): the sealed body as the two parts the
+        write queue sends, with no copy that joins them."""
         nonce = self._tx_prefix + self._tx_ctr.to_bytes(8, "little")
         self._tx_ctr += 1
-        return nonce + self._gcm.encrypt(nonce, plain, aad)
+        return nonce, self._gcm.encrypt(nonce, plain, aad)
 
-    def open(self, body: bytes, aad: bytes) -> bytes:
+    def seal(self, plain: bytes, aad: bytes) -> bytes:
+        """The sealed body as one buffer: nonce + ciphertext."""
+        return b"".join(self.seal_parts(plain, aad))
+
+    def open(self, body, aad: bytes) -> bytes:
+        """`body` is any buffer; nonce and ciphertext are read as views
+        of it. The plaintext is always a new object, whichever AEAD
+        backend resolved, so the caller may reuse `body` at once."""
         from ..auth.aead import InvalidTag
         if len(body) < _NONCE + _GCM_TAG:
             raise ConnectionError("secure frame too short")
-        nonce, ct = body[:_NONCE], body[_NONCE:]
+        body = memoryview(body)
+        nonce, ct = bytes(body[:_NONCE]), body[_NONCE:]
         if nonce[:4] != self._rx_prefix:
             raise ConnectionError("secure frame nonce from wrong "
                                   "direction")
@@ -316,6 +354,13 @@ def _flatten(payload) -> bytes:
     if isinstance(payload, (list, tuple)):
         return b"".join(payload)
     return bytes(payload)
+
+
+def _rx_alloc(n: int) -> memoryview:
+    """An uninitialised receive buffer: its pages are first touched by
+    the kernel's copy in recv_into, outside the GIL, and not by a
+    zero fill under it."""
+    return memoryview(np.empty(n, np.uint8))
 
 
 def _payload_len(payload) -> int:
@@ -523,7 +568,15 @@ class _Conn:
         # reactor binding (None = standalone blocking writes — the
         # pre-handshake window and the frame-capture test harness)
         self.reactor: _Reactor | None = None
-        self._rx = bytearray()      # unparsed inbound bytes
+        # inbound: the staging buffer for short frames (allocated at
+        # the first read) and the count of unparsed bytes at its front;
+        # a bulk frame in flight (its buffer, the bytes read into it);
+        # a secure conn's kept bulk buffer
+        self._rx: memoryview | None = None
+        self._rx_len = 0
+        self._rx_frame: memoryview | None = None
+        self._rx_got = 0
+        self._rx_keep: memoryview | None = None
         self._wq: deque = deque()   # outbound iovec parts, wire-ready
         self._wq_bytes = 0
         self._wcond = threading.Condition(self.wlock)
@@ -578,11 +631,11 @@ class _Conn:
                            key="seal_time", nbytes=plen):
                     plain = _flatten(
                         [struct.pack("<QH", seq, type_id)] + segs)
-                    sealed = self.box.seal(plain, hdr)
+                    nonce, ct = self.box.seal_parts(plain, hdr)
                 if self.reactor is None:
-                    _sendmsg_all(self.sock, [hdr, sealed])
+                    _sendmsg_all(self.sock, [hdr, nonce, ct])
                 else:
-                    self._enqueue_locked([hdr, sealed])
+                    self._enqueue_locked([hdr, nonce, ct])
             wire = 4 + _NONCE + 10 + plen + _GCM_TAG
             nseg = 1
         if self.perf is not None:
@@ -1447,46 +1500,110 @@ class Messenger:
             self._reactor_reap(peer, conn)
 
     def _conn_read(self, peer: str, conn: _Conn) -> None:
-        # drain with a per-event byte budget: one hot connection must
-        # not starve the rest of this reactor (epoll is level-
-        # triggered, the remainder fires on the next loop)
-        budget = 1 << 20
+        """Drain the socket within the per-event byte budget. Short
+        frames are read into the staging buffer and parsed there; the
+        rest of a bulk frame is read straight into its own buffer."""
+        if conn._rx is None:
+            conn._rx = _rx_alloc(_RX_STAGE)
+        budget = _RX_BUDGET
         while budget > 0 and conn.alive:
+            frame = conn._rx_frame
             try:
-                chunk = conn.sock.recv(1 << 18)
+                if frame is None:
+                    got = conn.sock.recv_into(conn._rx[conn._rx_len:])
+                else:
+                    got = conn.sock.recv_into(
+                        frame[conn._rx_got:],
+                        min(len(frame) - conn._rx_got, budget))
             except (BlockingIOError, InterruptedError):
                 return
             except OSError:
                 raise ConnectionError("recv failed")
-            if not chunk:
+            if not got:
                 raise ConnectionError("peer closed")
-            budget -= len(chunk)
-            rx = conn._rx
-            rx += chunk
-            pos = 0
-            n = len(rx)
-            tail = 4 if conn.box is None else 0
-            while n - pos >= 4:
-                (blen,) = struct.unpack_from("<I", rx, pos)
-                floor = 10 if conn.box is None \
-                    else 10 + _NONCE + _GCM_TAG
-                if blen < floor or blen > (1 << 26):
-                    raise ConnectionError(f"bad frame length {blen}")
-                if n - pos < 4 + blen + tail:
+            budget -= got
+            if frame is None:
+                conn._rx_len += got
+                self._rx_parse(peer, conn)
+            else:
+                conn._rx_got += got
+                if conn._rx_got == len(frame):
+                    conn._rx_frame = None
+                    self._rx_deliver(peer, conn, frame)
+
+    def _rx_parse(self, peer: str, conn: _Conn) -> None:
+        """Deliver every complete short frame in the staging buffer,
+        each cut out with one copy. A bulk frame's bytes at hand move
+        into the frame's own buffer, which the reads that follow fill.
+        What is left (part of one short frame or of a length, at most)
+        moves to the front, so the buffer has room for the rest of it."""
+        rx, n = conn._rx, conn._rx_len
+        tail = 4 if conn.box is None else 0
+        floor = 10 if conn.box is None else 10 + _NONCE + _GCM_TAG
+        pos = 0
+        while n - pos >= 4:
+            (blen,) = struct.unpack_from("<I", rx, pos)
+            if blen < floor or blen > _FRAME_MAX:
+                raise ConnectionError(f"bad frame length {blen}")
+            if blen >= _RX_DIRECT_MIN:
+                frame = self._rx_bulk_buffer(conn, blen + tail)
+                have = min(n - pos - 4, len(frame))
+                frame[:have] = rx[pos + 4:pos + 4 + have]
+                pos += 4 + have
+                if have < len(frame):
+                    conn._rx_frame, conn._rx_got = frame, have
                     break
-                raw_len = bytes(rx[pos:pos + 4])
-                body = bytes(rx[pos + 4:pos + 4 + blen])
-                crc = None
-                if tail:
-                    (crc,) = struct.unpack_from("<I", rx,
-                                                pos + 4 + blen)
-                pos += 4 + blen + tail
-                self._on_frame(peer, conn, raw_len, body, crc)
-            if pos:
-                del rx[:pos]
+                self._rx_deliver(peer, conn, frame)
+                continue
+            if n - pos < 4 + blen + tail:
+                break
+            raw_len = bytes(rx[pos:pos + 4])
+            body = bytes(rx[pos + 4:pos + 4 + blen])
+            crc = None
+            if tail:
+                (crc,) = struct.unpack_from("<I", rx, pos + 4 + blen)
+            pos += 4 + blen + tail
+            self._on_frame(peer, conn, raw_len, body, crc)
+        left = n - pos
+        if pos and left:
+            rx[:left] = bytes(rx[pos:n])
+        conn._rx_len = left
+
+    def _rx_bulk_buffer(self, conn: _Conn, need: int) -> memoryview:
+        """The buffer a bulk frame of `need` wire bytes (after its
+        length) is read into: a secure conn's kept buffer when it is
+        large enough, else a new one, which a secure conn keeps in its
+        place when it is within _RX_KEEP_MAX."""
+        self.perf.inc("rx_frames_direct")
+        if conn.box is None or need > _RX_KEEP_MAX:
+            return _rx_alloc(need)
+        keep = conn._rx_keep
+        if keep is not None and len(keep) >= need:
+            self.perf.inc("rx_buffer_reused")
+            return keep[:need]
+        # rounded up to a whole _RX_DIRECT_MIN: a connection's frames
+        # creep up a few bytes at a time (an op's name, a sub-op's log
+        # entries), and a buffer cut to fit would be outgrown by the
+        # next of them
+        conn._rx_keep = _rx_alloc(-(-need // _RX_DIRECT_MIN)
+                                  * _RX_DIRECT_MIN)
+        return conn._rx_keep[:need]
+
+    def _rx_deliver(self, peer: str, conn: _Conn,
+                    frame: memoryview) -> None:
+        """A bulk frame read whole: hand on views of its buffer, with
+        the length header it came under."""
+        if conn.box is None:
+            blen = len(frame) - 4
+            (crc,) = struct.unpack_from("<I", frame, blen)
+            self._on_frame(peer, conn, struct.pack("<I", blen),
+                           frame[:blen], crc)
+        else:
+            self._on_frame(peer, conn, struct.pack("<I", len(frame)),
+                           frame, None)
 
     def _on_frame(self, peer: str, conn: _Conn, raw_len: bytes,
-                  body: bytes, crc: int | None) -> None:
+                  body, crc: int | None) -> None:
         """One complete wire frame: verify, dedup, ack, dispatch —
         bit-for-bit the blocking read loop's semantics. Raises
         ConnectionError to kill the session (corruption, stale

@@ -346,6 +346,9 @@ class _ChildCluster:
         self.verbose = cfg.get("verbose", False)
         self._mon_names = list(cfg["mon_names"])
         self._osd_ids = list(cfg["osd_ids"])
+        # this process's daemon once it is built: OSDDaemon's heartbeat
+        # waits for it as it waits for the in-process cluster's
+        self.osds: dict | None = None
         self.key_server = None
         self.osd_secrets = {}
         if cfg.get("rotating_osd") is not None:
@@ -391,6 +394,7 @@ def child_main() -> int:
     from .standalone import MOSDBoot, OSDDaemon
     shim = _ChildCluster(cfg)
     daemon = OSDDaemon(cfg["osd_id"], shim)
+    shim.osds = {daemon.osd_id: daemon}
     print(json.dumps({"event": "ready",
                       "addr": list(daemon.msgr.addr)}), flush=True)
 

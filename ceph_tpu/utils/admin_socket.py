@@ -41,6 +41,7 @@ class AdminSocket:
         self._commands: dict[str, tuple] = {}   # cmd -> (fn, help)
         self._listener: socket.socket | None = None
         self._stopping = False
+        self._serving: set[threading.Thread] = set()  # live commands
         self.register("help", self._help,
                       "list registered commands")
 
@@ -109,6 +110,11 @@ class AdminSocket:
             os.unlink(self.path)
         except OSError:
             pass
+        # a command answered is not yet a thread ended: let the last
+        # ones go, and with them the daemon their frames still hold
+        for t in list(self._serving):
+            if t is not threading.current_thread():
+                t.join(1.0)
 
     def _accept_loop(self) -> None:
         while not self._stopping:
@@ -116,9 +122,12 @@ class AdminSocket:
                 conn, _ = self._listener.accept()
             except OSError:
                 return               # closed by stop()
-            threading.Thread(target=self._serve, args=(conn,),
-                             daemon=True,
-                             name=f"{self._owner}-asok-conn").start()
+            t = threading.Thread(target=self._serve, args=(conn,),
+                                 daemon=True,
+                                 name=f"{self._owner}-asok-conn")
+            self._serving = {x for x in self._serving
+                             if x.is_alive()} | {t}
+            t.start()
 
     def _serve(self, conn: socket.socket) -> None:
         try:

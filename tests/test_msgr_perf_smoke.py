@@ -7,6 +7,8 @@ ZERO payload copies between Encoder.blob_ref and sendmsg, secure mode
 stages exactly ONE contiguous buffer per frame (the AEAD input), and
 the Encoder really does carry caller buffers by reference."""
 
+import struct
+
 import pytest
 
 from ceph_tpu.msgr import messenger as M
@@ -100,6 +102,50 @@ class TestZeroCopy:
             # per frame would double it)
             assert n <= flatten_counter.calls < 2 * n, \
                 flatten_counter.calls
+        finally:
+            a.shutdown()
+            b.shutdown()
+
+    def test_sealed_frame_queues_as_header_nonce_ciphertext(
+            self, monkeypatch):
+        """A sealed frame reaches the write queue as [hdr, nonce, ct]:
+        the ciphertext is the very object the cipher returned, never
+        copied again to join it to its nonce, and the cipher's input
+        is still the one staged buffer."""
+        secret = b"0123456789abcdef0123456789abcdef"
+        a, b = pair(secret_a=secret, secret_b=secret)
+        try:
+            got = []
+            b.register_handler(Ping.type_id,
+                               lambda p, m: got.append(m.stamp))
+            a.send("osd.1", Ping(0))
+            assert wait_for(lambda: got == [0])
+            conn = a._conns["osd.1"]
+            plains, cts, queued = [], [], []
+            encrypt = conn.box._gcm.encrypt
+            enqueue = conn._enqueue_locked
+
+            def sealing(nonce, plain, aad):
+                plains.append(plain)
+                cts.append(encrypt(nonce, plain, aad))
+                return cts[-1]
+
+            def queueing(parts):
+                queued.append(list(parts))
+                return enqueue(parts)
+            monkeypatch.setattr(conn.box._gcm, "encrypt", sealing)
+            monkeypatch.setattr(conn, "_enqueue_locked", queueing)
+            a.send("osd.1", Ping(1, note="S" * 65536))
+            assert wait_for(lambda: got == [0, 1])
+            assert len(cts) == 1 and len(queued) == 1, (cts, queued)
+            assert len(queued[0]) == 3
+            hdr, nonce, ct = queued[0]
+            assert ct is cts[0]
+            assert len(nonce) == 12
+            assert struct.unpack("<I", hdr) == (len(nonce) + len(ct),)
+            # the cipher's input: the one staged buffer, whole
+            assert type(plains[0]) is bytes
+            assert len(plains[0]) == len(ct) - 16
         finally:
             a.shutdown()
             b.shutdown()

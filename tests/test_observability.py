@@ -95,6 +95,9 @@ class TestAdminSocket:
         assert perf["msgr"]["bytes_tx"] > 0
         # secure mode: seal/open time accumulated per frame
         assert perf["msgr"]["seal_time"]["avgcount"] > 0
+        # the bulk-frame intake's counters are in every dump
+        assert {"rx_frames_direct", "rx_buffer_reused"} <= \
+            set(perf["msgr"])
         # ack coalescing: far fewer acks than frames received
         assert 0 < perf["msgr"]["acks_tx"] < perf["msgr"]["frames_rx"]
         assert perf["rpc"]["op_send"] > 0
@@ -236,10 +239,17 @@ class TestMgrAggregation:
 
     def test_prometheus_from_aggregated_counters(self, cluster,
                                                  client):
+        def ops(text):
+            return sum(float(line.rsplit(" ", 1)[1])
+                       for line in text.splitlines()
+                       if line.startswith("ceph_tpu_osd_op{"))
+        # the daemons report every mgr_report_interval: the first
+        # exposition with the op counter may predate the fixture's I/O
         text = _wait_for(
             lambda: (t := client.prometheus_text())
-            and 'ceph_tpu_osd_op{daemon="osd.' in t and t,
-            30, "osd counters in exposition")
+            and 'ceph_tpu_osd_op{daemon="osd.' in t and ops(t) >= 8
+            and t,
+            30, "osd counters carrying the I/O in exposition")
         # per-daemon labels over the REAL counters
         assert '# TYPE ceph_tpu_msgr_frames_tx counter' in text
         assert 'ceph_tpu_rpc_op_send{daemon=' in text
@@ -249,11 +259,7 @@ class TestMgrAggregation:
             if not line.startswith("#"):
                 assert len(line.rsplit(" ", 1)) == 2, line
         # and the op counter really carries the I/O we did
-        total_op = sum(
-            float(line.rsplit(" ", 1)[1])
-            for line in text.splitlines()
-            if line.startswith("ceph_tpu_osd_op{"))
-        assert total_op >= 8                    # the writes + reads
+        assert ops(text) >= 8                   # the writes + reads
 
     def test_ceph_cli_live_mode(self, cluster, client, capsys):
         import sys
